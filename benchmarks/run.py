@@ -66,6 +66,8 @@ def main() -> int:
     args = ap.parse_args()
     if args.smoke:
         args.quick = True
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     os.makedirs(OUT, exist_ok=True)
     print("benchmark,metric,value")

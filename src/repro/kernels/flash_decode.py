@@ -58,8 +58,9 @@ namespace — ``kpos = logical_blk * block + lane`` — only the ADDRESS is
 indirected, so the budget allocator's ids flow unchanged down to the grid:
 
 - :func:`flash_decode_paged_kernel` — the table rides in SMEM as a third
-  scalar-prefetch operand and the K/V BlockSpec index maps dereference it
-  (``table[slot, logical_blk]``), streaming pool blocks in place;
+  scalar-prefetch operand; the body dereferences it
+  (``table[slot, logical_blk]``) and copies the pool blocks in place into
+  a double-buffered VMEM tile, one item ahead;
 - :func:`flash_decode_paged_reference` — the jnp twin: ``lax.scan`` over
   the logical list, ``dynamic_slice`` at the table-translated pool index.
 
@@ -74,8 +75,8 @@ tiles to mixed-dtype ``lax.dot_general`` (f32 x int8/fp8, f32
 accumulate) — deliberately no tile convert, which XLA could hoist into a
 full-pool dequantized copy.  ``k_scales=None`` (the default) leaves the
 pre-§2.12 bf16/f32 paths bitwise-untouched.  Scales ride to the Pallas
-kernels as additional BlockSpec'd operands: one (1, 1)-scale tile per
-grid step, table-indirected exactly like its K/V block.
+kernels as flat f32 tables held whole in SMEM; the body reads one scalar
+per grid step at the (table-indirected) index of its K/V block.
 """
 from __future__ import annotations
 
@@ -97,6 +98,14 @@ from repro.kernels.sparse_decode import (
 )
 
 NEG_INF = -1e30
+
+
+def _field(items_ref, i, f):
+    """Field ``f`` of work item ``i`` in the flat ``[L * DEC_FIELDS]``
+    SMEM table.  Scalar-prefetch operands are passed 1D: SMEM pads a 2D
+    int32 array's rows to 128 words, which at a few thousand items
+    (``[L, 6]``) outgrows the 1 MiB SMEM of a v5e."""
+    return items_ref[i * DEC_FIELDS + f]
 
 
 # ---------------------------------------------------------------------------
@@ -147,6 +156,8 @@ def _flash_decode_kernel(
     block_kv: int,
     window: int | None,
     quantized: bool = False,
+    num_kv_heads: int = 0,
+    num_scale_blocks: int = 0,
 ):
     if quantized:
         (ks_ref, vs_ref, o_ref, m_out_ref, l_out_ref,
@@ -154,11 +165,16 @@ def _flash_decode_kernel(
     else:
         o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref = rest
     i = pl.program_id(0)
-    valid = items_ref[i, D_VALID] == 1
-    first = items_ref[i, D_FIRST] == 1
-    last = items_ref[i, D_LAST] == 1
-    kvblk = items_ref[i, D_KVBLK]
-    pos = pos_ref[items_ref[i, D_BATCH]]
+    valid = _field(items_ref, i, D_VALID) == 1
+    first = _field(items_ref, i, D_FIRST) == 1
+    last = _field(items_ref, i, D_LAST) == 1
+    kvblk = _field(items_ref, i, D_KVBLK)
+    slot = _field(items_ref, i, D_BATCH)
+    pos = pos_ref[slot]
+    if quantized:
+        # flat SMEM index of this tile's (slot, kv head, block) scale
+        sidx = ((slot * num_kv_heads + _field(items_ref, i, D_KVHEAD))
+                * num_scale_blocks + kvblk)
 
     @pl.when(first)
     def _init():
@@ -177,7 +193,7 @@ def _flash_decode_kernel(
         if quantized:
             # post-dot dequant: the codes->values scale is linear, so it
             # commutes with the dot; the int8/fp8 tile streamed as-is
-            s = s * ks_ref[0, 0, 0]
+            s = s * ks_ref[sidx]
         kpos = kvblk * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         mask = kpos <= pos
@@ -193,7 +209,7 @@ def _flash_decode_kernel(
             p, vt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if quantized:
-            pv = pv * vs_ref[0, 0, 0]
+            pv = pv * vs_ref[sidx]
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
@@ -247,15 +263,18 @@ def flash_decode_kernel(
     Gp, dp = G + pad_g, dh + dh_pad
     L = items.shape[0]
 
+    nbs = (smax + pad_s) // block_kv
     kernel = functools.partial(
         _flash_decode_kernel, scale=scale_v, block_kv=block_kv,
-        window=window, quantized=quantized)
+        window=window, quantized=quantized, num_kv_heads=hkv,
+        num_scale_blocks=nbs)
 
     def bh_index(i, it, p):
-        return (it[i, D_BATCH], it[i, D_KVHEAD], 0, 0)
+        return (_field(it, i, D_BATCH), _field(it, i, D_KVHEAD), 0, 0)
 
     def tile_index(i, it, p):
-        return (it[i, D_BATCH], it[i, D_KVHEAD], it[i, D_KVBLK], 0)
+        return (_field(it, i, D_BATCH), _field(it, i, D_KVHEAD),
+                _field(it, i, D_KVBLK), 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, Gp, dp), bh_index),
@@ -264,17 +283,15 @@ def flash_decode_kernel(
     ]
     operands = [qp, kp, vp]
     if quantized:
-        # one f32 scale per (slot, kv-head, block): same index map as the
-        # K/V tile it dequantizes, one (1, 1, 1) element per grid step
-        nbs = (smax + pad_s) // block_kv
-        def scale_index(i, it, p):
-            return (it[i, D_BATCH], it[i, D_KVHEAD], it[i, D_KVBLK])
+        # one f32 scale per (slot, kv-head, block), the whole flat table
+        # resident in SMEM and read as a scalar in the body: a (1, 1, 1)
+        # VMEM tile per grid step is not a legal TPU block shape
         for s_arr in (k_scales, v_scales):
             pad_b = nbs - s_arr.shape[2]
-            in_specs.append(pl.BlockSpec((1, 1, 1), scale_index))
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
             operands.append(jnp.pad(
                 s_arr.astype(jnp.float32),
-                ((0, 0), (0, 0), (0, pad_b))))
+                ((0, 0), (0, 0), (0, pad_b))).reshape(-1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -301,7 +318,7 @@ def flash_decode_kernel(
             jax.ShapeDtypeStruct((B, hkv, Gp, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(items, pos.astype(jnp.int32), *operands)
+    )(items.reshape(-1), pos.astype(jnp.int32), *operands)
     return (out[:, :, :G, :dh], m[:, :, :G, 0], l[:, :, :G, 0])
 
 
@@ -428,31 +445,71 @@ def flash_decode_reference(
 
 def _flash_decode_paged_kernel(
     items_ref, tbl_ref, pos_ref,   # SMEM (scalar prefetch)
-    q_ref, k_ref, v_ref,           # VMEM tiles via index maps
+    q_ref, k_hbm, v_hbm,           # q tile via index map; pools stay in HBM
     *rest,                         # [ks_ref, vs_ref,] outs, scratch
     scale: float,
     block_kv: int,
     window: int | None,
     quantized: bool = False,
+    num_kv_heads: int = 0,
+    table_width: int = 0,
 ):
     """Same online-softmax body as :func:`_flash_decode_kernel`, but the
-    K/V tiles arrive from the block POOL via the table-indirected index
-    maps, and an item is additionally invalid when its table entry is
-    unmapped (``table[slot, logical] < 0`` — e.g. a shard that does not own
-    the block under a block-sharded pool)."""
+    K/V tiles come from the block POOL, copied by hand at the
+    table-translated index into two VMEM buffers: item ``i + 1``'s tiles
+    stream in while item ``i`` computes.  An item is additionally invalid
+    when its table entry is unmapped (``table[slot, logical] < 0`` — e.g. a
+    shard that does not own the block under a block-sharded pool); invalid
+    items copy nothing."""
     if quantized:
         (ks_ref, vs_ref, o_ref, m_out_ref, l_out_ref,
-         acc_ref, m_ref, l_ref) = rest
+         acc_ref, m_ref, l_ref, k_buf, v_buf, sem) = rest
     else:
-        o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref = rest
+        (o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref,
+         k_buf, v_buf, sem) = rest
     i = pl.program_id(0)
-    kvblk = items_ref[i, D_KVBLK]
-    slot = items_ref[i, D_BATCH]
-    mapped = tbl_ref[slot, kvblk] >= 0
-    valid = (items_ref[i, D_VALID] == 1) & mapped
-    first = items_ref[i, D_FIRST] == 1
-    last = items_ref[i, D_LAST] == 1
+
+    def tile(j):
+        """Item ``j``: (is streamed, physical block, K/V copies into
+        buffer ``j % 2``)."""
+        slot_j = _field(items_ref, j, D_BATCH)
+        phys_j = tbl_ref[slot_j * table_width + _field(items_ref, j, D_KVBLK)]
+        head_j = _field(items_ref, j, D_KVHEAD)
+        buf = j % 2
+        copies = [pltpu.make_async_copy(
+                      pool.at[jnp.maximum(phys_j, 0), head_j], dst.at[buf],
+                      sem.at[c, buf])
+                  for c, (pool, dst) in enumerate(((k_hbm, k_buf),
+                                                   (v_hbm, v_buf)))]
+        ok = (_field(items_ref, j, D_VALID) == 1) & (phys_j >= 0)
+        return ok, phys_j, copies
+
+    def start(j):
+        ok, _, copies = tile(j)
+
+        @pl.when(ok)
+        def _():
+            for c in copies:
+                c.start()
+
+    @pl.when(i == 0)
+    def _prime():
+        start(0)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _prefetch():
+        start(i + 1)
+
+    valid, phys, copies = tile(i)
+    kvblk = _field(items_ref, i, D_KVBLK)
+    slot = _field(items_ref, i, D_BATCH)
+    first = _field(items_ref, i, D_FIRST) == 1
+    last = _field(items_ref, i, D_LAST) == 1
     pos = pos_ref[slot]
+    if quantized:
+        # flat SMEM index of this tile's (physical block, kv head) scale
+        sidx = (jnp.maximum(phys, 0) * num_kv_heads
+                + _field(items_ref, i, D_KVHEAD))
 
     @pl.when(first)
     def _init():
@@ -462,16 +519,18 @@ def _flash_decode_paged_kernel(
 
     @pl.when(valid)
     def _compute():
+        for c in copies:
+            c.wait()
         qt = q_ref[0, 0].astype(jnp.float32)   # [G, d]
-        kt = k_ref[0, 0].astype(jnp.float32)   # [block_kv, d]
-        vt = v_ref[0, 0].astype(jnp.float32)
+        kt = k_buf[i % 2].astype(jnp.float32)  # [block_kv, d]
+        vt = v_buf[i % 2].astype(jnp.float32)
         s = jax.lax.dot_general(
             qt, kt, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [G, block_kv]
         if quantized:
-            # post-dot dequant: per-(physical block, kv head) scale tile,
+            # post-dot dequant: per-(physical block, kv head) scale,
             # table-indirected exactly like the K tile it belongs to
-            s = s * ks_ref[0, 0]
+            s = s * ks_ref[sidx]
         # positions come from the LOGICAL block id — the physical pool
         # index carries no position information
         kpos = kvblk * block_kv + jax.lax.broadcasted_iota(
@@ -489,7 +548,7 @@ def _flash_decode_paged_kernel(
             p, vt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if quantized:
-            pv = pv * vs_ref[0, 0]
+            pv = pv * vs_ref[sidx]
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
@@ -523,9 +582,10 @@ def flash_decode_paged_kernel(
     v_scales: jnp.ndarray | None = None,
 ):
     """Paged twin of :func:`flash_decode_kernel`: one (slot, kv_head,
-    logical_block) matvec tile per grid step, the K/V BlockSpec index maps
-    dereference the block table in SMEM (scalar-prefetch indirection), so
-    exactly the selected pool blocks move HBM->VMEM — same roofline as the
+    logical_block) matvec tile per grid step, the body dereferences the
+    block table in SMEM (scalar prefetch) and copies the tile from the pool
+    itself, one item ahead, so exactly the selected pool blocks move
+    HBM->VMEM — same roofline as the
     contiguous kernel, token-granular memory."""
     B, hkv, G, dh = q.shape
     assert k_pool.shape[2] == block_kv, "pool block size != block_kv"
@@ -539,35 +599,35 @@ def flash_decode_paged_kernel(
     vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, dh_pad)))
     Gp, dp = G + pad_g, dh + dh_pad
     L = items.shape[0]
+    T = table.shape[1]
 
     kernel = functools.partial(
         _flash_decode_paged_kernel, scale=scale_v, block_kv=block_kv,
-        window=window, quantized=quantized)
+        window=window, quantized=quantized, num_kv_heads=hkv,
+        table_width=T)
 
     def bh_index(i, it, tb, p):
-        return (it[i, D_BATCH], it[i, D_KVHEAD], 0, 0)
+        return (_field(it, i, D_BATCH), _field(it, i, D_KVHEAD), 0, 0)
 
-    def kv_index(i, it, tb, p):
-        # clamp unmapped (-1) entries to pool block 0: the item is masked
-        # invalid in the body, the prefetch just needs a legal address
-        return (jnp.maximum(tb[it[i, D_BATCH], it[i, D_KVBLK]], 0),
-                it[i, D_KVHEAD], 0, 0)
-
+    # the pools stay in HBM and the body copies its tiles itself: a
+    # table-indirected BlockSpec on a pool of a few thousand bf16 blocks
+    # halts a v5e at run time, though it compiles
     in_specs = [
         pl.BlockSpec((1, 1, Gp, dp), bh_index),
-        pl.BlockSpec((1, 1, block_kv, dp), kv_index),
-        pl.BlockSpec((1, 1, block_kv, dp), kv_index),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [qp, kp, vp]
     if quantized:
-        # per-(physical block, kv head) scales, same table indirection as
-        # the K/V pool tiles — one (1, 1) f32 element per grid step
-        def scale_index(i, it, tb, p):
-            return (jnp.maximum(tb[it[i, D_BATCH], it[i, D_KVBLK]], 0),
-                    it[i, D_KVHEAD])
+        # per-(physical block, kv head) scales: the flat [N * Hkv] table
+        # sits whole in SMEM (4 B per pool tile) and the body reads the
+        # entry at the table-translated index — a (1, 1) VMEM tile per grid
+        # step is not a legal TPU block shape.  v5e SMEM is 1 MiB, so the
+        # two tables bound N * Hkv below ~120k tiles: at SmolLM-135M widths
+        # that is a ~40k-block int8 pool, more than 16 GB of HBM can hold.
         for s_arr in (k_scales, v_scales):
-            in_specs.append(pl.BlockSpec((1, 1), scale_index))
-            operands.append(s_arr.astype(jnp.float32))
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+            operands.append(s_arr.astype(jnp.float32).reshape(-1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -582,18 +642,25 @@ def flash_decode_paged_kernel(
             pltpu.VMEM((Gp, dp), jnp.float32),
             pltpu.VMEM((Gp, 1), jnp.float32),
             pltpu.VMEM((Gp, 1), jnp.float32),
+            pltpu.VMEM((2, block_kv, dp), kp.dtype),   # K double buffer
+            pltpu.VMEM((2, block_kv, dp), vp.dtype),   # V double buffer
+            pltpu.SemaphoreType.DMA((2, 2)),           # [K/V, buffer]
         ],
     )
     out, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        # the prefetch of item i + 1 runs under item i: the grid is serial
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         out_shape=[
             jax.ShapeDtypeStruct((B, hkv, Gp, dp), jnp.float32),
             jax.ShapeDtypeStruct((B, hkv, Gp, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, hkv, Gp, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(items, table.astype(jnp.int32), pos.astype(jnp.int32), *operands)
+    )(items.reshape(-1), table.astype(jnp.int32).reshape(-1),
+      pos.astype(jnp.int32), *operands)
     return (out[:, :, :G, :dh], m[:, :, :G, 0], l[:, :, :G, 0])
 
 

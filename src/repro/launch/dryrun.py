@@ -37,7 +37,6 @@ import jax  # noqa: E402
 from repro.configs import ARCHS, SHAPES, cells  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import build_step  # noqa: E402
-from repro.sharding.compat import set_mesh  # noqa: E402
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -138,7 +137,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
             return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
 
         abstract = jax.tree.map(attach, built.abstract, built.in_shardings)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(built.fn).lower(**abstract)
             rec["lower_s"] = round(time.time() - t0, 1)
             t1 = time.time()

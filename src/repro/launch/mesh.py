@@ -13,6 +13,18 @@ to surface as an opaque shard_map shape error three layers down.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    JAX 0.9 defaults new meshes to ``Explicit`` axes, under which the
+    GSPMD-style ``PartitionSpec`` / ``with_sharding_constraint`` code here
+    (and ``jnp.take`` on a sharded embedding) raises ``ShardingTypeError``;
+    ``Auto`` axes keep the compiler's sharding propagation."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _check_factorization(n: int, axes: dict[str, int]) -> None:
@@ -48,7 +60,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """Single-pod (data=16, model=16) = 256 chips; multi-pod adds pod=2."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int | None = None, data: int | None = None):
@@ -70,7 +82,7 @@ def make_host_mesh(model: int | None = None, data: int | None = None):
                 f"device(s); pick a divisor of {n}")
         data = n // model
     _check_factorization(n, {"data": data, "model": model})
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_host_mesh_2d(model: int = 1, seq: int = 1,
@@ -97,4 +109,4 @@ def make_host_mesh_2d(model: int = 1, seq: int = 1,
     _check_factorization(n, {"data": data, "model": model, "seq": seq})
     if num_kv_heads is not None:
         validate_heads_divide(num_kv_heads, model)
-    return jax.make_mesh((data, model, seq), ("data", "model", "seq"))
+    return make_mesh((data, model, seq), ("data", "model", "seq"))

@@ -14,6 +14,7 @@ from repro.configs import ARCHS
 from repro.core.sparsity import synthetic_head_curves
 from repro.launch.steps import _init_fn_for
 from repro.serving import Engine, EngineConfig, SamplingParams
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.logging import get_logger
 
 log = get_logger("serve")
@@ -99,6 +100,7 @@ def main():
         ap.error("--seq-shards must be >= 1")
     if args.checkpoint_every > 0 and not args.checkpoint_dir:
         ap.error("--checkpoint-every needs --checkpoint-dir")
+    enable_compile_cache()
 
     injector = None
     if args.fault_plan:

@@ -37,7 +37,6 @@ from repro.data.synthetic import lm_batch  # noqa: E402
 from repro.launch.mesh import make_host_mesh, make_production_mesh  # noqa: E402
 from repro.launch.steps import _init_fn_for, _loss_fn_for  # noqa: E402
 from repro.sharding import specs as sh  # noqa: E402
-from repro.sharding.compat import set_mesh  # noqa: E402
 from repro.training import (  # noqa: E402
     AdamWConfig,
     CheckpointManager,
@@ -88,7 +87,7 @@ def main():
     loss_fn = _loss_fn_for(run_spec)
     step = make_train_step(loss_fn, tcfg)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = make_train_state(jax.random.PRNGKey(0), init, tcfg)
         pspec = sh.param_specs(jax.eval_shape(lambda: state["params"]),
                                mesh)

@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map
-
 from repro.attention.worklist_jnp import worklist_attention
 from repro.kernels import ops
 
@@ -68,7 +66,7 @@ def hplb_prefill_attention(mesh, *, block_q=128, block_kv=128,
                 block_q=block_q, block_kv=block_kv)
             return jax.vmap(lambda qq, kk, vv: fn(qq, kk, vv))(q_l, k_l, v_l)
 
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh,
             in_specs=(P(bspec, "model", None, None),
                       P(bspec, kv_spec, None, None),
@@ -98,7 +96,7 @@ def hplb_prefill_attention_rows(mesh, *, block_q=128, block_kv=128):
             o = jax.vmap(lambda qq, kk, vv: fn(qq, kk, vv))(q_l, k_l, v_l)
             return jax.lax.psum(o, "model")
 
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh,
             in_specs=(P(bspec, None, None, None),
                       P(bspec, None, None, None),
@@ -143,7 +141,7 @@ def hplb_decode_attention_packed(mesh, *, block_kv=128):
                     q_l, kc_l, vc_l, items_l[0], pos_l, block_kv=block_kv,
                     k_scales=ks_l, v_scales=vs_l)
 
-            return shard_map(
+            return jax.shard_map(
                 island, mesh=mesh,
                 in_specs=(P(bspec, "model", None, None),
                           P(bspec, "model", None, None),
@@ -162,7 +160,7 @@ def hplb_decode_attention_packed(mesh, *, block_kv=128):
             return ops.flash_decode_packed(
                 q_l, kc_l, vc_l, items_l[0], pos_l, block_kv=block_kv)
 
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh,
             in_specs=(P(bspec, "model", None, None),
                       P(bspec, "model", None, None),
@@ -209,7 +207,7 @@ def hplb_repermute_kv_cache(mesh, *, axis="model"):
         def run(x):
             nd = np.asarray(x.ndim)
             spec = P(*((None, None, None, axis) + (None,) * (int(nd) - 4)))
-            return shard_map(
+            return jax.shard_map(
                 island, mesh=mesh,
                 in_specs=(spec, P(None, None)),
                 out_specs=spec,
@@ -249,7 +247,7 @@ def hplb_swap_gather_kv_blocks(mesh, *, axis="model"):
 
         def run(x):
             spec = P(*((None, None, None, axis) + (None,) * (x.ndim - 4)))
-            return shard_map(
+            return jax.shard_map(
                 island, mesh=mesh,
                 in_specs=(spec, P(None)),
                 out_specs=(spec, spec),
@@ -278,7 +276,7 @@ def hplb_swap_scatter_kv_blocks(mesh, *, axis="model"):
 
         def run(x, b):
             spec = P(*((None, None, None, axis) + (None,) * (x.ndim - 4)))
-            return shard_map(
+            return jax.shard_map(
                 island, mesh=mesh,
                 in_specs=(spec, spec, P(None)),
                 out_specs=spec,
@@ -363,7 +361,7 @@ def flash_decode_attention_paged(mesh, *, block_kv=128, seq_axes=("model",),
             # table indexes the local scales shard directly
             in_specs += (P(sspec, None), P(sspec, None))
             args += (k_scales, v_scales)
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh, in_specs=in_specs,
             out_specs=P(bspec, None, None, None),
             check_vma=False,
@@ -442,7 +440,7 @@ def flash_decode_attention_2d(mesh, *, block_kv=128, model_axis="model",
             # pool — blocks over seq, kv heads over model
             in_specs += (P(seq_axis, model_axis), P(seq_axis, model_axis))
             args += (k_scales, v_scales)
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh, in_specs=in_specs,
             out_specs=P(bspec, model_axis, None, None),
             check_vma=False,
@@ -525,7 +523,7 @@ def flash_decode_attention(mesh, *, block_kv=128, seq_axes=("model",),
             # the local scales slice directly
             in_specs += (P(bspec, None, sspec), P(bspec, None, sspec))
             args += (k_scales, v_scales)
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh, in_specs=in_specs,
             out_specs=P(bspec, None, None, None),
             check_vma=False,

@@ -25,8 +25,7 @@ LOGICAL = {
 
 
 def _mesh_axes() -> tuple[str, ...]:
-    from repro.sharding.compat import get_abstract_mesh
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty:
         return ()
     return tuple(m.axis_names)

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map
-
 
 def pipeline_apply(stage_fn, mesh, *, axis: str = "pipe",
                    microbatches: int | None = None):
@@ -73,7 +71,7 @@ def pipeline_apply(stage_fn, mesh, *, axis: str = "pipe",
             return outs.reshape(B, *x.shape[1:])
 
         pspec = jax.tree.map(lambda _: P(axis), stage_params)
-        return shard_map(
+        return jax.shard_map(
             island, mesh=mesh,
             in_specs=(pspec, P()),
             out_specs=P(),

@@ -5,8 +5,11 @@ jax import and must not leak into other tests)."""
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 def _run(script: str, timeout=420):
@@ -14,7 +17,7 @@ def _run(script: str, timeout=420):
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH="src")
     p = subprocess.run([sys.executable, "-c", script], env=env,
-                       cwd="/root/repo", capture_output=True, text=True,
+                       cwd=REPO, capture_output=True, text=True,
                        timeout=timeout)
     assert p.returncode == 0, f"stderr:\n{p.stderr[-3000:]}"
     return p.stdout
@@ -26,13 +29,13 @@ def test_hplb_prefill_island_multidevice_matches_dense():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.attention.worklist_jnp import causal_items
 from repro.attention import flash_attention_ref
 from repro.core.worklist import worklist_from_budgets
 from repro.attention.policies import streaming_policy
 from repro.serving.sharded_attention import hplb_prefill_attention
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, H, Hkv, S, D = 2, 8, 4, 512, 32
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(ks[0], (B, H, S, D))
@@ -45,7 +48,7 @@ wl = worklist_from_budgets(np.full(H, S), num_devices=4, seq_len=S,
                            block=128, policy_fn=full, group_size=2)
 items = np.tile(wl.items[:, None], (1, 3, 1, 1))  # [4, L=3 layers, Lpad, 7]
 attend = hplb_prefill_attention(mesh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     o = jax.jit(lambda q, k, v, it: attend(1, q, k, v, it))(
         q, k, v, jnp.asarray(items))
 r = jax.vmap(lambda a, b, c: flash_attention_ref(a, b, c, causal=True))(q, k, v)
@@ -62,10 +65,10 @@ def test_flash_decode_island_multidevice():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.serving.sharded_attention import flash_decode_attention
 from repro.attention import dense_attention
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, H, Hkv, Smax, D = 2, 8, 4, 1024, 32
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(ks[0], (B, H, 1, D))
@@ -79,7 +82,7 @@ for s in range(n_sh):
         ids[s, h] = np.arange(s * (nblk // n_sh), (s + 1) * (nblk // n_sh))
 pos = 900
 attend = flash_decode_attention(mesh, seq_axes=("model",))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     o = jax.jit(lambda *a: attend(*a, pos))(q, kc, vc, jnp.asarray(ids))
 mask = (jnp.arange(Smax) <= pos)[None, None]
 r = dense_attention(q, kc, vc, mask=mask[:, :, None])
@@ -97,10 +100,10 @@ def test_flash_decode_paged_island_multidevice():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.serving.sharded_attention import flash_decode_attention_paged
 from repro.attention import dense_attention
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, H, Hkv, Smax, D, BLK = 2, 8, 4, 1024, 32, 128
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(ks[0], (B, H, 1, D))
@@ -119,7 +122,7 @@ for b in range(B):
 ids = np.tile(np.arange(T, dtype=np.int32)[None, None], (B, Hkv, 1))
 pos = np.array([900, 700], np.int32)   # PER-SLOT positions, batch-sharded
 attend = flash_decode_attention_paged(mesh, seq_axes=("model",))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     o = jax.jit(lambda *a: attend(*a))(
         q, jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(ids),
         jnp.asarray(perm), jnp.asarray(pos))
@@ -140,17 +143,17 @@ def test_swap_gather_scatter_islands_shard_local():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.serving.sharded_attention import (
     hplb_swap_gather_kv_blocks, hplb_swap_scatter_kv_blocks)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 L, N, Hkv, BLK, D = 2, 9, 4, 16, 8      # N = 8 usable + 1 trash block
 rng = np.random.default_rng(0)
 pool0 = rng.normal(size=(L, 2, N, Hkv, BLK, D)).astype(np.float32)
 ids = np.array([5, 2, 7, 8], np.int32)   # trash-padded (8) swap bucket
 gather = hplb_swap_gather_kv_blocks(mesh)
 scatter = hplb_swap_scatter_kv_blocks(mesh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     pool, blocks = jax.jit(gather)(jnp.asarray(pool0), ids)
     blocks = np.asarray(jax.device_get(blocks))
     # gather == plain take on the unsharded pool, all kv heads present
@@ -177,11 +180,11 @@ def test_hplb_decode_packed_island_multidevice():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.worklist import pack_decode_items, pow2_bucket, extend_packed_items
 from repro.serving.sharded_attention import hplb_decode_attention_packed
 from repro.attention import dense_attention
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, H, Hkv, Smax, D, BLK = 2, 8, 4, 512, 32, 128
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(ks[0], (B, H, 1, D))
@@ -197,7 +200,7 @@ wl = pack_decode_items(ids, num_shards=4, block=BLK,
                        bucket=pow2_bucket(B * nblk))
 pos = np.array([500, 300], np.int32)
 attend = hplb_decode_attention_packed(mesh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     o = jax.jit(lambda *a: attend(*a))(
         q, kc, vc, jnp.asarray(wl.items), jnp.asarray(pos))
 mask = (jnp.arange(Smax)[None] <= pos[:, None])[:, None, None]
@@ -219,7 +222,6 @@ def test_flash_decode_2d_island_multidevice():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
 from repro.launch.mesh import make_host_mesh_2d
 from repro.serving.sharded_attention import flash_decode_attention_2d
 from repro.attention import dense_attention
@@ -248,7 +250,7 @@ for n_seq in (2, 4):
     ids = np.tile(np.arange(T, dtype=np.int32)[None, None], (B, Hkv, 1))
     pos = np.array([900, 700], np.int32)
     attend = flash_decode_attention_2d(mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         o = jax.jit(lambda *a: attend(*a))(
             q, jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(ids),
             jnp.asarray(perm), jnp.asarray(pos))
@@ -269,7 +271,7 @@ def test_gspmd_train_step_multidevice_matches_single():
 import warnings; warnings.filterwarnings("ignore")
 import functools
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.data.synthetic import lm_batch
 from repro.models.transformer import TransformerConfig, init_params, loss_fn
@@ -285,9 +287,9 @@ b = jax.tree.map(jnp.asarray, lm_batch(0, batch=4, seq_len=64))
 # single device
 s1, m1 = jax.jit(step)(state, b)
 # sharded
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 pspec = sh.param_specs(jax.eval_shape(lambda: state["params"]), mesh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     sharded_state = {
         "params": jax.device_put(state["params"], jax.tree.map(
             lambda s: NamedSharding(mesh, s), pspec,
@@ -309,17 +311,17 @@ def test_elastic_checkpoint_reshard():
 import warnings; warnings.filterwarnings("ignore")
 import tempfile
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.training import CheckpointManager
 tree = {"w": jnp.arange(64.0).reshape(8, 8), "b": jnp.ones((8,))}
 d = tempfile.mkdtemp()
-mesh4 = jax.make_mesh((4,), ("model",))
+mesh4 = make_mesh((4,), ("model",))
 t4 = jax.device_put(tree, NamedSharding(mesh4, P("model")))
 cm = CheckpointManager(d, keep=1)
 cm.save(1, t4)
 for n in (8, 2, 1):
-    mesh = jax.make_mesh((n,), ("model",))
+    mesh = make_mesh((n,), ("model",))
     sh = {"w": NamedSharding(mesh, P("model")),
           "b": NamedSharding(mesh, P("model"))}
     _, restored = cm.restore_latest(jax.eval_shape(lambda: tree), sh)
@@ -337,14 +339,14 @@ def test_moe_expert_parallel_multidevice():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.moe import MoEConfig, moe_ffn, moe_init
 cfg = MoEConfig(num_experts=8, experts_per_token=2)
 p = moe_init(jax.random.PRNGKey(0), 32, 64, cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32), jnp.float32)
 y1 = moe_ffn(x, p, cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+mesh = make_mesh((2, 4), ("data", "model"))
+with jax.set_mesh(mesh):
     y2 = jax.jit(lambda x, p: moe_ffn(x, p, cfg))(x, p)
 err = float(jnp.abs(y1.astype(jnp.float32) - y2.astype(jnp.float32)).max())
 assert err < 2e-2, err
@@ -360,17 +362,17 @@ def test_hplb_repermute_kv_cache_island():
     out = _run("""
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
-from repro.sharding.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.serving.sharded_attention import hplb_repermute_kv_cache
 from repro.models.transformer import permute_cache_kv_heads
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 L, B, Hkv, S, D = 2, 2, 8, 64, 16
 cache = jax.random.normal(jax.random.PRNGKey(0), (L, 2, B, Hkv, S, D))
 rng = np.random.default_rng(3)
 # per-layer shuffles that move heads BETWEEN shards (1 head per shard)
 kv_perm = np.stack([rng.permutation(Hkv) for _ in range(L)])
 rep = hplb_repermute_kv_cache(mesh)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = jax.jit(lambda c, p: rep(c, p))(cache, jnp.asarray(kv_perm))
 want = permute_cache_kv_heads(cache, kv_perm)
 err = float(jnp.abs(got - want).max())

@@ -2,11 +2,9 @@
 capable family, sparse paths degrade gracefully, sharding spec sanity."""
 import numpy as np
 import jax
-
-from repro.sharding.compat import abstract_mesh
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 
 class TestTransformerConsistency:
@@ -106,7 +104,7 @@ class TestShardingSpecs:
     def test_divisibility_sanitation(self):
         """Dims not divisible by the mesh axis fall back to replication."""
         from repro.sharding import specs as sh
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         tree = {
             "embed": jax.ShapeDtypeStruct((51865, 512), jnp.bfloat16),
             "layers": {"attn": {
@@ -118,7 +116,7 @@ class TestShardingSpecs:
 
     def test_cache_seq_fallback(self):
         from repro.sharding import specs as sh
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         cache = jax.ShapeDtypeStruct((4, 2, 128, 8, 32768, 128),
                                      jnp.bfloat16)
         spec = sh.cache_specs(cache, mesh)
@@ -128,7 +126,7 @@ class TestShardingSpecs:
 
     def test_cache_long_context_b1(self):
         from repro.sharding import specs as sh
-        mesh = abstract_mesh((16, 16), ("data", "model"))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         cache = jax.ShapeDtypeStruct((4, 2, 1, 8, 524288, 128),
                                      jnp.bfloat16)
         spec = sh.cache_specs(cache, mesh)

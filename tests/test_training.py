@@ -5,6 +5,7 @@ import os
 import signal
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
 import jax
@@ -21,6 +22,8 @@ from repro.training import (
     make_train_state,
     make_train_step,
 )
+
+REPO = Path(__file__).resolve().parents[1]
 
 CFG = TransformerConfig(num_layers=2, d_model=64, num_heads=4,
                         num_kv_heads=2, d_ff=128, vocab_size=260)
@@ -203,12 +206,12 @@ np.save({str(tmp_path)!r} + "/final.npy",
 """
         env = dict(os.environ, CRASH="1", PYTHONPATH="src")
         p1 = subprocess.run([sys.executable, "-c", script], env=env,
-                            cwd="/root/repo", capture_output=True, text=True,
+                            cwd=REPO, capture_output=True, text=True,
                             timeout=300)
         assert p1.returncode != 0  # it crashed (SIGKILL)
         env2 = dict(os.environ, CRASH="0", PYTHONPATH="src")
         p2 = subprocess.run([sys.executable, "-c", script], env=env2,
-                            cwd="/root/repo", capture_output=True, text=True,
+                            cwd=REPO, capture_output=True, text=True,
                             timeout=300)
         assert p2.returncode == 0, p2.stderr[-2000:]
         resumed = np.load(str(tmp_path) + "/final.npy")
