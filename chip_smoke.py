@@ -25,11 +25,12 @@ seed.  Phases, in order; any failure exits non-zero:
 ``(data=1, model=4)`` mesh at Yi-6B attention widths, one KV head per
 chip, against ``ops.flash_decode_packed`` on one device.
 
-Earlier lines report what ran (device, set-up and compile seconds, wall
-time per phase, tokens, requests, decode-grid statistics); they are a
-record of the run, not a speed measurement.  The last line of standard
-output is a JSON object naming the device.  The persistent compile cache
-lives where ``JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache/``.
+Earlier lines report what ran (device, set-up seconds, compile seconds
+per jitted step by name, wall time per phase, tokens, requests,
+decode-grid statistics); they are a record of the run, not a speed
+measurement.  The last line of standard output is a JSON object naming
+the device.  The persistent compile cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache/``.
 """
 from __future__ import annotations
 
@@ -56,42 +57,6 @@ def say(msg: str) -> None:
 def check(cond: bool, msg: str) -> None:
     if not cond:
         raise SmokeFailure(msg)
-
-
-# ---------------------------------------------------------------------------
-# compile accounting (JAX's own monitoring events)
-# ---------------------------------------------------------------------------
-
-class CompileMeter:
-    """Sums backend-compile seconds (a persistent-cache hit is counted at
-    its retrieval time) and counts cache hits and misses."""
-
-    def __init__(self):
-        import jax
-        self.secs = 0.0
-        self.hits = 0
-        self.misses = 0
-
-        def on_duration(event, duration, **kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.secs += duration
-
-        def on_event(event, **kw):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self.misses += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def snapshot(self) -> tuple[float, int, int]:
-        return self.secs, self.hits, self.misses
-
-    def since(self, snap) -> str:
-        s, h, m = snap
-        return (f"compile {self.secs - s:.2f} s "
-                f"(cache hits {self.hits - h}, misses {self.misses - m})")
 
 
 # ---------------------------------------------------------------------------
@@ -474,7 +439,7 @@ def main(argv=None) -> int:
               f"devices, JAX found {len(devices)}", file=sys.stderr)
         return 1
 
-    from repro.utils.compile_cache import enable_compile_cache
+    from repro.utils.compile_cache import CompileMeter, enable_compile_cache
     cache_dir = enable_compile_cache()
     meter = CompileMeter()
     kind = devices[0].device_kind

@@ -41,6 +41,7 @@ from collections import OrderedDict
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.attention.policies import policy_by_name
 from repro.core import quant
@@ -163,9 +164,9 @@ class EngineConfig:
     seq_shards: int = 1
     # -- fault tolerance (DESIGN.md §2.13) --------------------------------
     # per-tick numerical sentinels: after every prefill/decode step the
-    # engine checks the sampled slots' logits for NaN/Inf (a numpy
-    # reduction over the logits copy sampling already synced — no extra
-    # device sync) and quarantines ONLY the poisoned sequence.
+    # engine checks the sampled slots' logits for NaN/Inf (a host copy of
+    # the whole logits array, reduced with numpy) and quarantines ONLY the
+    # poisoned sequence.
     sentinels: bool = True
     # host swap transfers retry with exponential backoff before giving up
     # (give-up surfaces TransferError -> scheduler discard-and-requeue)
@@ -881,20 +882,22 @@ class Engine:
         if fn is None:
             qz = self.quantized
             if self.paged:
-                def run(params, cache, token, pos, table, bids, clen):
+                def telemetry_probe(params, cache, token, pos, table, bids,
+                                    clen):
                     pool, scales = cache if qz else (cache, None)
                     return tfm.decode_telemetry(
                         params, pool, token, pos, self.cfg,
                         block_ids=bids, cache_len=clen, table=table,
                         scales=scales, with_health=True)
             else:
-                def run(params, cache, token, pos, bids, clen):
+                def telemetry_probe(params, cache, token, pos, bids, clen):
                     c, scales = cache if qz else (cache, None)
                     return tfm.decode_telemetry(
                         params, c, token, pos, self.cfg,
                         block_ids=bids, cache_len=clen, scales=scales,
                         with_health=True)
-            fn = jax.jit(run)  # reads the live cache: never donated
+            # reads the live cache: never donated
+            fn = jax.jit(telemetry_probe)
             self._telemetry_jit[nb_width] = fn
         return fn
 
@@ -1062,23 +1065,7 @@ class Engine:
             if not np.array_equal(
                     kv_tbl, np.tile(np.arange(kv_tbl.shape[1], dtype=kv_tbl.dtype),
                                     (kv_tbl.shape[0], 1))):
-                if self._kv_permute_jit is None:
-                    if self.quantized:
-                        # codes AND scales gather in ONE donated jit — a
-                        # block's scale can never go out of sync with its
-                        # codes across an epoch swap
-                        def perm(cache, tbl):
-                            pool, scales = cache
-                            return (tfm.permute_cache_kv_heads(pool, tbl),
-                                    tfm.permute_cache_scales(scales, tbl))
-                        self._kv_permute_jit = jax.jit(
-                            perm,
-                            donate_argnums=(0,) if self._donate else ())
-                    else:
-                        self._kv_permute_jit = jax.jit(
-                            tfm.permute_cache_kv_heads,
-                            donate_argnums=(0,) if self._donate else ())
-                self._set_cache(self._kv_permute_jit(
+                self._set_cache(self._kv_permute_fn()(
                     self.cache, jnp.asarray(kv_tbl)))
                 # fold the gather into the cumulative arrangement: slot h
                 # now holds what slot kv_tbl[l, h] held — swapped-out host
@@ -1109,6 +1096,25 @@ class Engine:
                  "mean imbalance %.3f)", old, self.epoch,
                  self._decode_ticks, not delta.identity,
                  new_plan.mean_imbalance)
+
+    def _kv_permute_fn(self):
+        """Donated gather of the resident cache's kv-head axis for an
+        epoch swap."""
+        if self._kv_permute_jit is None:
+            if self.quantized:
+                # codes AND scales gather in ONE donated jit — a block's
+                # scale can never go out of sync with its codes across an
+                # epoch swap
+                def kv_permute(cache, tbl):
+                    pool, scales = cache
+                    return (tfm.permute_cache_kv_heads(pool, tbl),
+                            tfm.permute_cache_scales(scales, tbl))
+            else:
+                def kv_permute(cache, tbl):
+                    return tfm.permute_cache_kv_heads(cache, tbl)
+            self._kv_permute_jit = jax.jit(
+                kv_permute, donate_argnums=(0,) if self._donate else ())
+        return self._kv_permute_jit
 
     # -- paged-layout plumbing ----------------------------------------------
     @property
@@ -1155,7 +1161,7 @@ class Engine:
                 # quantized: the scales gather rides the SAME ids — the
                 # host copy is (codes, scales), ~cache_dtype_bytes/2 the
                 # bf16 swap volume per block
-                def run(cache, ids):
+                def swap_gather(cache, ids):
                     pool, scales = cache if qz else (cache, None)
                     blocks = jnp.take(pool, ids, axis=2)
                     if qz:
@@ -1166,7 +1172,7 @@ class Engine:
                 pool0 = self.cache[0] if qz else self.cache
                 L, _, _, Hkv, _, Dh = pool0.shape
                 nb = width // self.ecfg.block
-                def run(cache, slot):
+                def swap_gather(cache, slot):
                     c, scales = cache if qz else (cache, None)
                     seq = jax.lax.dynamic_slice(
                         c, (0, 0, slot, 0, 0, 0),
@@ -1176,7 +1182,8 @@ class Engine:
                             scales, (0, 0, slot, 0, 0), (L, 2, 1, Hkv, nb))
                         return (c, scales), (seq, ssc)
                     return c, seq
-            fn = jax.jit(run, donate_argnums=(0,) if self._donate else ())
+            fn = jax.jit(swap_gather,
+                         donate_argnums=(0,) if self._donate else ())
             self._swap_gather_jit[key] = fn
         return fn
 
@@ -1186,7 +1193,7 @@ class Engine:
             kind = key[0]
             qz = self.quantized
             if kind == "paged":
-                def run(cache, blocks, ids):
+                def swap_scatter(cache, blocks, ids):
                     if qz:
                         pool, scales = cache
                         blk, sc = blocks
@@ -1196,7 +1203,7 @@ class Engine:
                     return cache.at[:, :, ids].set(
                         blocks.astype(cache.dtype))
             else:
-                def run(cache, seq, slot):
+                def swap_scatter(cache, seq, slot):
                     if qz:
                         c, scales = cache
                         sq, sc = seq
@@ -1208,7 +1215,8 @@ class Engine:
                     return jax.lax.dynamic_update_slice(
                         cache, seq.astype(cache.dtype),
                         (0, 0, slot, 0, 0, 0))
-            fn = jax.jit(run, donate_argnums=(0,) if self._donate else ())
+            fn = jax.jit(swap_scatter,
+                         donate_argnums=(0,) if self._donate else ())
             self._swap_scatter_jit[key] = fn
         return fn
 
@@ -1381,16 +1389,20 @@ class Engine:
     def _sentinel_check(self, logits, row_slots) -> None:
         """Flag any slot whose last-step logits went non-finite.
         ``row_slots``: (logits row, slot) pairs — decode rows ARE slots,
-        a prefill's single row maps to the sequence's slot.  Runs on the
-        host copy of the logits sampling already forced, so the check
-        adds a numpy reduction — never an extra device sync."""
+        a prefill's single row maps to the sequence's slot.  Sampling
+        brings back only token ids, so the check copies the whole
+        ``[rows, vocab]`` logits array to the host (one device-to-host
+        transfer per step, after the step has finished) and reduces it
+        with numpy."""
         if not self.ecfg.sentinels:
             return
-        finite = np.isfinite(np.asarray(logits)).all(axis=-1)
-        for row, s in row_slots:
-            if not finite[row] and int(s) not in self._quarantine:
-                self._quarantine[int(s)] = "nonfinite_logits"
-                self.fault_stats["sentinel_trips"] += 1
+        with TraceAnnotation("engine.sentinel"):
+            finite = np.isfinite(np.atleast_2d(np.asarray(logits))).all(
+                axis=-1)
+            for row, s in row_slots:
+                if not finite[row] and int(s) not in self._quarantine:
+                    self._quarantine[int(s)] = "nonfinite_logits"
+                    self.fault_stats["sentinel_trips"] += 1
 
     def _poison_gate(self, logits, slot: int):
         """``poison_request`` seam: an injected fault turns THIS prefill's
@@ -1653,7 +1665,7 @@ class Engine:
 
             qz = self.quantized
 
-            def run(params, cache, tokens, slot, last_idx):
+            def prefill(params, cache, tokens, slot, last_idx):
                 logits, seq_cache = tfm.prefill(
                     params, tokens, self.cfg,
                     cache_len=self.ecfg.max_seq_len,
@@ -1675,7 +1687,8 @@ class Engine:
                     (0, 0, slot, 0, 0, 0))
                 return logits, cache
 
-            return jax.jit(run, donate_argnums=(1,) if self._donate else ())
+            return jax.jit(prefill,
+                           donate_argnums=(1,) if self._donate else ())
 
         return self._lru_get(self._prefill_jit, (self.epoch, bucket),
                              build, self.ecfg.prefill_jit_cap)
@@ -1701,7 +1714,7 @@ class Engine:
 
             qz = self.quantized
 
-            def run(params, pool, tokens, table, last_idx):
+            def prefill_paged(params, pool, tokens, table, last_idx):
                 logits, seq_cache = tfm.prefill(
                     params, tokens, self.cfg, cache_len=bucket_pad,
                     sparse_items=items, last_index=last_idx)
@@ -1714,7 +1727,8 @@ class Engine:
                 pool = tfm.scatter_seq_cache_paged(pool, seq_cache, table)
                 return logits, pool
 
-            return jax.jit(run, donate_argnums=(1,) if self._donate else ())
+            return jax.jit(prefill_paged,
+                           donate_argnums=(1,) if self._donate else ())
 
         return self._lru_get(self._prefill_jit, (self.epoch, bucket),
                              build, self.ecfg.prefill_jit_cap)
@@ -1809,33 +1823,33 @@ class Engine:
                         return out[0], (out[1], out[2])
                     return out
 
-                def run(params, pool, tokens, table, off, kv_len, last_idx,
-                        items):
+                def prefill_chunk(params, pool, tokens, table, off, kv_len,
+                                  last_idx, items):
                     return step(params, pool, tokens, table, off, kv_len,
                                 last_idx, items)
 
-                def run_dense(params, pool, tokens, table, off, kv_len,
-                              last_idx):
+                def prefill_chunk_dense(params, pool, tokens, table, off,
+                                        kv_len, last_idx):
                     return step(params, pool, tokens, table, off, kv_len,
                                 last_idx, None)
             else:
-                def run(params, cache, tokens, slot, off, kv_len, last_idx,
-                        items):
+                def prefill_chunk(params, cache, tokens, slot, off, kv_len,
+                                  last_idx, items):
                     return tfm.prefill_chunk(
                         params, cache, tokens, slot, off, self.cfg,
                         kv_len=kv_len, sparse_items=items,
                         last_index=last_idx)
 
-                def run_dense(params, cache, tokens, slot, off, kv_len,
-                              last_idx):
+                def prefill_chunk_dense(params, cache, tokens, slot, off,
+                                        kv_len, last_idx):
                     return tfm.prefill_chunk(
                         params, cache, tokens, slot, off, self.cfg,
                         kv_len=kv_len, sparse_items=None,
                         last_index=last_idx)
 
             donate = (1,) if self._donate else ()
-            return (jax.jit(run, donate_argnums=donate) if sparse
-                    else jax.jit(run_dense, donate_argnums=donate))
+            return (jax.jit(prefill_chunk, donate_argnums=donate) if sparse
+                    else jax.jit(prefill_chunk_dense, donate_argnums=donate))
 
         return self._lru_get(self._prefill_chunk_jit, bucket, build,
                              self.ecfg.chunk_jit_cap)
@@ -1863,10 +1877,10 @@ class Engine:
                         return out[0], (out[1], out[2])
                     return out
 
-                def run(params, pool, token, pos, table, bids, act):
+                def decode_padded(params, pool, token, pos, table, bids, act):
                     return step(params, pool, token, pos, table, bids, act)
 
-                def run_dense(params, pool, token, pos, table, act):
+                def decode_dense(params, pool, token, pos, table, act):
                     return step(params, pool, token, pos, table, None, act)
             else:
                 def step(params, cache, token, pos, bids, act):
@@ -1879,16 +1893,16 @@ class Engine:
                         return out[0], (out[1], out[2])
                     return out
 
-                def run(params, cache, token, pos, bids, act):
+                def decode_padded(params, cache, token, pos, bids, act):
                     return step(params, cache, token, pos, bids, act)
 
-                def run_dense(params, cache, token, pos, act):
+                def decode_dense(params, cache, token, pos, act):
                     return step(params, cache, token, pos, None, act)
 
             donate = (1,) if self._donate else ()
-            self._decode_jit = (jax.jit(run, donate_argnums=donate) if sparse
-                                else jax.jit(run_dense,
-                                             donate_argnums=donate))
+            self._decode_jit = (
+                jax.jit(decode_padded, donate_argnums=donate) if sparse
+                else jax.jit(decode_dense, donate_argnums=donate))
         return self._decode_jit
 
     def _decode_packed_fn(self, flat_len):
@@ -1907,7 +1921,8 @@ class Engine:
                 S = self.ecfg.seq_shards
                 ss = self.kv.stripe_size if S > 1 else None
 
-                def run(params, cache, token, pos, table, items, act):
+                def decode_packed(params, cache, token, pos, table, items,
+                                  act):
                     pool, scales = cache if qz else (cache, None)
                     out = tfm.decode_step_paged(
                         params, pool, token, pos, table, self.cfg,
@@ -1918,7 +1933,7 @@ class Engine:
                         return out[0], (out[1], out[2])
                     return out
             else:
-                def run(params, cache, token, pos, items, act):
+                def decode_packed(params, cache, token, pos, items, act):
                     c, scales = cache if qz else (cache, None)
                     out = tfm.decode_step(
                         params, c, token, pos, self.cfg,
@@ -1927,7 +1942,8 @@ class Engine:
                     if qz:
                         return out[0], (out[1], out[2])
                     return out
-            fn = jax.jit(run, donate_argnums=(1,) if self._donate else ())
+            fn = jax.jit(decode_packed,
+                         donate_argnums=(1,) if self._donate else ())
             self._decode_packed_jit[flat_len] = fn
         return fn
 
@@ -1937,27 +1953,25 @@ class Engine:
         """Prefill one sequence into its cache (the slot's row under the
         contiguous layout; the sequence's pool blocks under the paged
         layout); returns the first sampled token."""
-        tokens = np.atleast_2d(np.asarray(tokens, np.int32))
-        S = tokens.shape[-1]
-        bucket = self._prefill_bucket(S)
-        if bucket > S:
-            tokens = np.pad(tokens, ((0, 0), (0, bucket - S)))
-        if self.paged:
-            run = self._prefill_paged_fn(bucket)
-            table = jnp.asarray(self._table_for_slot(slot))
-            logits, cache = run(self.params, self.cache,
-                                jnp.asarray(tokens), table, S - 1)
-        else:
-            run = self._prefill_fn(bucket)
-            logits, cache = run(self.params, self.cache,
-                                jnp.asarray(tokens), slot, S - 1)
-        self._set_cache(cache)
-        logits = self._poison_gate(logits, slot)
-        self._rng, sub = jax.random.split(self._rng)
-        tok = int(sample(logits, sub, sampling)[0])
-        self._sentinel_check(np.atleast_2d(np.asarray(logits)),
-                             [(0, slot)])
-        return tok
+        with TraceAnnotation("engine.prefill.inputs"):
+            tokens = np.atleast_2d(np.asarray(tokens, np.int32))
+            S = tokens.shape[-1]
+            bucket = self._prefill_bucket(S)
+            if bucket > S:
+                tokens = np.pad(tokens, ((0, 0), (0, bucket - S)))
+            table = self._table_for_slot(slot) if self.paged else None
+        with TraceAnnotation("engine.prefill.dispatch"):
+            if self.paged:
+                run = self._prefill_paged_fn(bucket)
+                logits, cache = run(self.params, self.cache,
+                                    jnp.asarray(tokens), jnp.asarray(table),
+                                    S - 1)
+            else:
+                run = self._prefill_fn(bucket)
+                logits, cache = run(self.params, self.cache,
+                                    jnp.asarray(tokens), slot, S - 1)
+            self._set_cache(cache)
+        return self._sample_first(logits, slot, sampling)
 
     def prefill_chunk_into_slot(self, tokens: np.ndarray, slot: int,
                                 q_offset: int, prompt_len: int,
@@ -1971,42 +1985,49 @@ class Engine:
         first sampled token when ``is_final`` (logits read at the chunk's
         last real row), else None.
         """
-        tokens = np.asarray(tokens, np.int32)
-        c = tokens.shape[-1]
-        bucket = self._chunk_bucket(c, q_offset)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :c] = tokens
-        run = self._prefill_chunk_fn(bucket)
         sparse = self.ecfg.attention == "sparse"
-        items = (jnp.asarray(self._chunk_worklists(prompt_len, q_offset,
-                                                   bucket))
-                 if sparse else None)
-        if self.paged:
-            # chunks scatter straight into the sequence's pool blocks —
-            # no staging cache, no merge, and decode never observes a
-            # mid-prefill sequence because its blocks are disjoint
-            table = jnp.asarray(self._table_for_slot(slot))
-            args = (self.params, self.cache, jnp.asarray(toks), table,
-                    q_offset, q_offset + c, c - 1)
-            logits, cache = run(*args, items) if sparse else run(*args)
-            self._set_cache(cache)
-        else:
-            if self._staging is None:
-                self._staging = tfm.init_cache(self.cfg, 1,
-                                               self.ecfg.max_seq_len)
-            args = (self.params, self._staging, jnp.asarray(toks), 0,
-                    q_offset, q_offset + c, c - 1)
-            logits, self._staging = (run(*args, items) if sparse
-                                     else run(*args))
+        with TraceAnnotation("engine.prefill.inputs"):
+            tokens = np.asarray(tokens, np.int32)
+            c = tokens.shape[-1]
+            bucket = self._chunk_bucket(c, q_offset)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :c] = tokens
+            data = ([self._chunk_worklists(prompt_len, q_offset, bucket)]
+                    if sparse else [])
+            table = self._table_for_slot(slot) if self.paged else None
+        with TraceAnnotation("engine.prefill.dispatch"):
+            run = self._prefill_chunk_fn(bucket)
+            data = [jnp.asarray(d) for d in data]
+            if self.paged:
+                # chunks scatter straight into the sequence's pool blocks —
+                # no staging cache, no merge, and decode never observes a
+                # mid-prefill sequence because its blocks are disjoint
+                logits, cache = run(self.params, self.cache,
+                                    jnp.asarray(toks), jnp.asarray(table),
+                                    q_offset, q_offset + c, c - 1, *data)
+                self._set_cache(cache)
+            else:
+                if self._staging is None:
+                    self._staging = tfm.init_cache(self.cfg, 1,
+                                                   self.ecfg.max_seq_len)
+                logits, self._staging = run(
+                    self.params, self._staging, jnp.asarray(toks), 0,
+                    q_offset, q_offset + c, c - 1, *data)
+                if is_final:
+                    self._set_cache(self._merge_staging(slot))
         if not is_final:
             return None
-        if not self.paged:
-            self._set_cache(self._merge_staging(slot))
+        return self._sample_first(logits, slot, sampling)
+
+    def _sample_first(self, logits, slot: int,
+                      sampling: SamplingParams) -> int:
+        """A prefill's first token: the ``poison_request`` seam, sampling,
+        the wait for the token, and the sentinel over its logits."""
         logits = self._poison_gate(logits, slot)
-        self._rng, sub = jax.random.split(self._rng)
-        tok = int(sample(logits, sub, sampling)[0])
-        self._sentinel_check(np.atleast_2d(np.asarray(logits)),
-                             [(0, slot)])
+        with TraceAnnotation("engine.prefill.wait"):
+            self._rng, sub = jax.random.split(self._rng)
+            tok = int(sample(logits, sub, sampling)[0])
+        self._sentinel_check(logits, [(0, slot)])
         return tok
 
     def _merge_staging(self, slot: int):
@@ -2023,7 +2044,7 @@ class Engine:
                 # prefill's insert would have produced
                 blk, kvd = self.ecfg.block, self.ecfg.kv_dtype
 
-                def merge(cache, staging, slot):
+                def merge_staging(cache, staging, slot):
                     c, scales = cache
                     codes, sc = quant.quantize_seq_cache(staging, blk, kvd)
                     c = jax.lax.dynamic_update_slice(
@@ -2032,112 +2053,114 @@ class Engine:
                         scales, sc, (0, 0, slot, 0, 0))
                     return c, scales
             else:
-                def merge(cache, staging, slot):
+                def merge_staging(cache, staging, slot):
                     return jax.lax.dynamic_update_slice(
                         cache, staging.astype(cache.dtype),
                         (0, 0, slot, 0, 0, 0))
             self._merge_jit = jax.jit(
-                merge, donate_argnums=(0,) if self._donate else ())
+                merge_staging, donate_argnums=(0,) if self._donate else ())
         return self._merge_jit(self.cache, self._staging, slot)
 
     def decode_slots(self, slots, tokens, positions,
                      sampling: SamplingParams = SamplingParams()):
-        """Advance all slots one step; returns sampled tokens for `slots`."""
-        tok_all = np.zeros((self.ecfg.num_slots,), np.int32)
-        pos_all = np.zeros((self.ecfg.num_slots,), np.int32)
-        act_all = np.zeros((self.ecfg.num_slots,), bool)
-        tok_all[list(slots)] = tokens
-        pos_all[list(slots)] = positions
-        act_all[list(slots)] = True  # padded slots must not write KV
-        self._decode_ticks += 1
-        self._ticks_since_replan += 1
-        extra = []
-        table = None
-        if self.paged:
-            # per-slot block tables (data): -1 rows for unbound slots
-            # route their writes into the trash block
-            table = np.full((self.ecfg.num_slots, self.kv.table_width), -1,
-                            np.int32)
-            for s in slots:
-                table[s] = self._table_for_slot(s)
-        # kv_corrupt seam fires BEFORE the probe dispatch and the step, so
-        # both observe the corrupted block — detection is the test
-        self._maybe_corrupt(slots)
-        if self.paged:
-            extra = [jnp.asarray(table)]
-        packed = (self.ecfg.attention == "sparse"
-                  and self.ecfg.decode_worklist == "packed")
-        probe = (self.ecfg.attention == "sparse"
-                 and self.ecfg.telemetry_every > 0
-                 and self._decode_ticks % self.ecfg.telemetry_every == 0)
+        """Advance all slots one step; returns sampled tokens for `slots`.
+
+        Each host phase of the step is a profiler span
+        (``engine.decode.*``, ``engine.sentinel``), so a device trace
+        shows which one the chip waits on between two decode programs."""
+        with TraceAnnotation("engine.decode.inputs"):
+            tok_all = np.zeros((self.ecfg.num_slots,), np.int32)
+            pos_all = np.zeros((self.ecfg.num_slots,), np.int32)
+            act_all = np.zeros((self.ecfg.num_slots,), bool)
+            tok_all[list(slots)] = tokens
+            pos_all[list(slots)] = positions
+            act_all[list(slots)] = True  # padded slots must not write KV
+            self._decode_ticks += 1
+            self._ticks_since_replan += 1
+            table = None
+            if self.paged:
+                # per-slot block tables (data): -1 rows for unbound slots
+                # route their writes into the trash block
+                table = np.full((self.ecfg.num_slots, self.kv.table_width),
+                                -1, np.int32)
+                for s in slots:
+                    table[s] = self._table_for_slot(s)
+            # kv_corrupt seam fires BEFORE the probe dispatch and the step,
+            # so both observe the corrupted block — detection is the test
+            self._maybe_corrupt(slots)
+            sparse = self.ecfg.attention == "sparse"
+            packed = sparse and self.ecfg.decode_worklist == "packed"
+            probe = (sparse and self.ecfg.telemetry_every > 0
+                     and self._decode_ticks % self.ecfg.telemetry_every == 0)
+            striped = self.paged and self.ecfg.seq_shards > 1
         pending_probe = None
         if probe:
             # online recovery telemetry (DESIGN.md §2.9): probe the
             # PRE-STEP resident cache with this tick's selections — the
             # probe is dispatched before the donating decode step, so
             # stream order guarantees it reads the live buffer
-            blk = self.ecfg.block
-            per_slot = [self._decode_ids_for_nblocks(
-                (int(p) + 1 + blk - 1) // blk) for p in pos_all]
-            pending_probe = self._dispatch_telemetry(
-                slots, tok_all, pos_all, np.stack(per_slot, axis=1),
-                table=table)
-        striped = self.paged and self.ecfg.seq_shards > 1
-        if packed:
-            # cost-packed ragged worklist: grid length is this tick's true
-            # selected-block count (bucketed), not B x Hkv x max-budget
-            stripe_of = self._stripe_of_table(table) if striped else None
-            items, stats = self._plan_for(self._nb_sig(pos_all), stripe_of,
-                                          table=table)
-            run = self._decode_packed_fn(
-                items.shape[1:3] if striped else items.shape[1])
+            with TraceAnnotation("engine.decode.probe"):
+                pending_probe = self._dispatch_telemetry(
+                    slots, tok_all, pos_all, self._slot_block_ids(pos_all),
+                    table=table)
+        data = []
+        if sparse:
+            with TraceAnnotation("engine.decode.plan"):
+                if packed:
+                    # cost-packed ragged worklist: grid length is this
+                    # tick's true selected-block count (bucketed), not
+                    # B x Hkv x max-budget
+                    stripe_of = (self._stripe_of_table(table) if striped
+                                 else None)
+                    items, stats = self._plan_for(self._nb_sig(pos_all),
+                                                  stripe_of, table=table)
+                    data = [items]
+                else:
+                    # padded baseline: per-slot position-aware selection,
+                    # refreshed at block boundaries
+                    data = [self._slot_block_ids(pos_all)]
+                    stats = self._padded_tick_stats(data[0])
+        with TraceAnnotation("engine.decode.dispatch"):
+            if packed:
+                shape = data[0].shape
+                run = self._decode_packed_fn(shape[1:3] if striped
+                                             else shape[1])
+            else:
+                run = self._decode_fn()
+            args = ([tok_all, pos_all] + ([table] if self.paged else [])
+                    + data + [act_all])
             logits, cache = run(self.params, self.cache,
-                                jnp.asarray(tok_all),
-                                jnp.asarray(pos_all),
-                                *extra,
-                                jnp.asarray(items),
-                                jnp.asarray(act_all))
-            self._record_tick(stats)
-        elif self.ecfg.attention == "sparse":
-            # padded baseline: per-slot position-aware selection, refreshed
-            # at block boundaries (ids are a function of the block count)
-            run = self._decode_fn()
-            blk = self.ecfg.block
-            per_slot = [self._decode_ids_for_nblocks((int(p) + 1 + blk - 1)
-                                                     // blk)
-                        for p in pos_all]
-            bids = np.stack(per_slot, axis=1)  # [L, B, Hkv, nb_cap]
-            logits, cache = run(self.params, self.cache,
-                                jnp.asarray(tok_all),
-                                jnp.asarray(pos_all),
-                                *extra,
-                                jnp.asarray(bids),
-                                jnp.asarray(act_all))
-            self._record_tick(self._padded_tick_stats(bids))
-        else:
-            run = self._decode_fn()
-            logits, cache = run(self.params, self.cache,
-                                jnp.asarray(tok_all),
-                                jnp.asarray(pos_all),
-                                *extra,
-                                jnp.asarray(act_all))
-        self._set_cache(cache)
-        if striped:
-            # one flash-decoding (out, m, l) combine per layer — on the 2D
-            # mesh this is the single collective along the seq axis
-            self.decode_stats["merge_collectives"] += self.cfg.num_layers
+                                *map(jnp.asarray, args))
+            self._set_cache(cache)
+            if sparse:
+                self._record_tick(stats)
+            if striped:
+                # one flash-decoding (out, m, l) combine per layer — on the
+                # 2D mesh this is the single collective along the seq axis
+                self.decode_stats["merge_collectives"] += self.cfg.num_layers
         if packed:
             # the device step above is dispatched asynchronously; build the
             # NEXT tick's plan now, before sampling forces a sync — host
             # planning overlaps the in-flight device work
-            self._prefetch_next_plan()
+            with TraceAnnotation("engine.decode.prefetch"):
+                self._prefetch_next_plan()
         if pending_probe is not None:
-            self._fold_telemetry(pending_probe)
-        self._rng, sub = jax.random.split(self._rng)
-        toks = sample(logits, sub, sampling)
-        out = np.asarray(toks)[list(slots)]
+            with TraceAnnotation("engine.decode.probe"):
+                self._fold_telemetry(pending_probe)
+        with TraceAnnotation("engine.decode.sample"):
+            self._rng, sub = jax.random.split(self._rng)
+            toks = sample(logits, sub, sampling)
+        with TraceAnnotation("engine.decode.wait"):
+            out = np.asarray(toks)[list(slots)]
         self._sentinel_check(logits, [(s, s) for s in slots])
         return out
+
+    def _slot_block_ids(self, pos_all: np.ndarray) -> np.ndarray:
+        """``[L, B, Hkv, nb_cap]`` block selections of every slot at its
+        position (ids are a function of the block count)."""
+        blk = self.ecfg.block
+        return np.stack([self._decode_ids_for_nblocks(
+            (int(p) + 1 + blk - 1) // blk) for p in pos_all], axis=1)
 
     def _padded_tick_stats(self, bids: np.ndarray) -> dict:
         """Bubble telemetry of a PADDED-path tick: real vs padded grid
@@ -2257,11 +2280,12 @@ class Engine:
         """Per-tick policy hook (:meth:`serve` wires it; external loops
         can too): replan policy, invariant audits (periodic + forced at
         swap boundaries), and checkpointing at safe points."""
-        self._maybe_replan(batcher)
-        if self.ecfg.audit_every > 0:
-            activity = (self.swap_stats["swapped_out"]
-                        + self.swap_stats["swapped_in"] + self.replans)
-            boundary = activity != self._last_audit_activity
-            self._last_audit_activity = activity
-            self.maybe_audit(boundary=boundary)
-        self._maybe_checkpoint(batcher)
+        with TraceAnnotation("engine.on_tick"):
+            self._maybe_replan(batcher)
+            if self.ecfg.audit_every > 0:
+                activity = (self.swap_stats["swapped_out"]
+                            + self.swap_stats["swapped_in"] + self.replans)
+                boundary = activity != self._last_audit_activity
+                self._last_audit_activity = activity
+                self.maybe_audit(boundary=boundary)
+            self._maybe_checkpoint(batcher)

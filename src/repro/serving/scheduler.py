@@ -76,6 +76,7 @@ from collections import deque
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.faults import TransferError
 from repro.serving.kv_cache import BlockAllocator
@@ -182,7 +183,9 @@ class SchedulerStats:
     rejected: int = 0
     failed: int = 0                     # quarantined mid-flight (§2.13)
     swap_discards: int = 0              # transfer gave up -> discard+requeue
+    ticks: int = 0
     decode_steps: int = 0
+    decode_rows: int = 0                # active rows summed over decode steps
     prefill_tokens: int = 0
     prefill_chunks: int = 0
     preempted: int = 0
@@ -785,17 +788,19 @@ class ContinuousBatcher:
             # offset is a block boundary (work-list slicing relies on it);
             # chunk == budget >= block here, so flooring keeps chunk >= block
             chunk = (chunk // self.block) * self.block
-        toks = req.prompt[None, req.prefill_pos:req.prefill_pos + chunk]
-        t0 = self._clock()
-        first = prefill_chunk_fn(toks, self._slot_of[req.rid],
-                                 req.prefill_pos, final, len(req.prompt))
-        self._observe_prefill(self._clock() - t0, chunk)
-        req.prefill_pos += chunk
-        self.stats.prefill_tokens += chunk
-        self.stats.prefill_chunks += 1
-        if final:
-            self.prefilling = None
-            self._finish_prefill(req, first, finished)
+        with TraceAnnotation("sched.prefill_step", rid=req.rid,
+                             q_offset=req.prefill_pos, tokens=chunk):
+            toks = req.prompt[None, req.prefill_pos:req.prefill_pos + chunk]
+            t0 = self._clock()
+            first = prefill_chunk_fn(toks, self._slot_of[req.rid],
+                                     req.prefill_pos, final, len(req.prompt))
+            self._observe_prefill(self._clock() - t0, chunk)
+            req.prefill_pos += chunk
+            self.stats.prefill_tokens += chunk
+            self.stats.prefill_chunks += 1
+            if final:
+                self.prefilling = None
+                self._finish_prefill(req, first, finished)
 
     def _finish_prefill(self, req: Request, first, finished: list[Request]):
         """Prefill done: record the first sampled token and either retire
@@ -845,47 +850,57 @@ class ContinuousBatcher:
              decode_fn: Callable) -> list[Request]:
         """One scheduler iteration; returns requests finished this tick
         (completed, rejected AND failed —
-        ``completed + rejected + failed == submitted``)."""
+        ``completed + rejected + failed == submitted``).  The tick and each
+        of its phases are profiler spans (``sched.*``)."""
         finished: list[Request] = []
-        self._admit(prefill_chunk_fn, finished)
-        if self.token_budget is not None:
-            self._prefill_step(prefill_chunk_fn, finished)
-        if self.active:
-            rids = sorted(self.active)
-            slots = [self._slot_of[r] for r in rids]
-            tokens = np.array([self.active[r].generated[-1] for r in rids],
-                              np.int32)
-            positions = np.array([self.lengths[r] - 1 for r in rids],
-                                 np.int32)
-            # account the token each decode writes BEFORE the device step —
-            # a boundary-crossing write needs its block mapped (the paged
-            # engine reads the table this call may have just grown)
-            for r in rids:
-                self.alloc.append_token(r)
-            t0 = self._clock()
-            nxt = decode_fn(slots, tokens, positions)
-            self._observe_decode(self._clock() - t0)
-            self.stats.decode_steps += 1
-            bad = self._sentinel()
-            done_now = []
-            for r, t in zip(rids, np.asarray(nxt)):
-                req = self.active[r]
-                slot = self._slot_of[r]
-                if slot in bad:
-                    # sentinel tripped on this slot: its sampled token is
-                    # garbage — quarantine instead of recording it.  The
-                    # other slots' tokens came off the same device step
-                    # untouched (blocks are per-sequence), so they record
-                    # normally.
-                    self._fail(req, bad.pop(slot), finished)
-                    continue
-                self.lengths[r] += 1
-                if self._record_token(req, int(t)):
-                    done_now.append(req)
-            for req in done_now:
-                self._retire(req)
-                finished.append(req)
+        with TraceAnnotation("sched.tick"):
+            self.stats.ticks += 1
+            with TraceAnnotation("sched.admit"):
+                self._admit(prefill_chunk_fn, finished)
+            if self.token_budget is not None:
+                self._prefill_step(prefill_chunk_fn, finished)
+            if self.active:
+                with TraceAnnotation("sched.decode", rows=len(self.active)):
+                    self._decode_step(decode_fn, finished)
         return finished
+
+    def _decode_step(self, decode_fn: Callable, finished: list[Request]):
+        """One decode step over every active sequence: account each
+        sequence's next token, run the step, drain the quarantine, record
+        the tokens and retire the sequences that finished."""
+        rids = sorted(self.active)
+        slots = [self._slot_of[r] for r in rids]
+        tokens = np.array([self.active[r].generated[-1] for r in rids],
+                          np.int32)
+        positions = np.array([self.lengths[r] - 1 for r in rids], np.int32)
+        # account the token each decode writes BEFORE the device step — a
+        # boundary-crossing write needs its block mapped (the paged engine
+        # reads the table this call may have just grown)
+        for r in rids:
+            self.alloc.append_token(r)
+        t0 = self._clock()
+        nxt = decode_fn(slots, tokens, positions)
+        self._observe_decode(self._clock() - t0)
+        self.stats.decode_steps += 1
+        self.stats.decode_rows += len(rids)
+        bad = self._sentinel()
+        done_now = []
+        for r, t in zip(rids, np.asarray(nxt)):
+            req = self.active[r]
+            slot = self._slot_of[r]
+            if slot in bad:
+                # sentinel tripped on this slot: its sampled token is
+                # garbage — quarantine instead of recording it.  The other
+                # slots' tokens came off the same device step untouched
+                # (blocks are per-sequence), so they record normally.
+                self._fail(req, bad.pop(slot), finished)
+                continue
+            self.lengths[r] += 1
+            if self._record_token(req, int(t)):
+                done_now.append(req)
+        for req in done_now:
+            self._retire(req)
+            finished.append(req)
 
     def run(self, prefill_chunk_fn, decode_fn, max_ticks: int = 100_000,
             on_tick: Callable[[], None] | None = None):
