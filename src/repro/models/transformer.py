@@ -752,6 +752,34 @@ def prefill_chunk(params, cache, tokens, slot, q_offset,
     return logits, new_cache
 
 
+def _paged_layer_loop(layer, cfg: TransformerConfig, params, x, pool,
+                      scales, sel):
+    """Run ``layer(x, pool, scales, lp, l, window, items_l) -> (x, pool,
+    scales)`` over every layer with the whole paged pool (and its scales)
+    as the loop's carry: each layer scatters its K/V into ``pool[l]`` and
+    reads that layer back, so under donation the pool is updated in place
+    and never sliced out, stacked or rebuilt per layer.  ``sel`` ([L, ...]
+    per-layer selections or None) is scanned with the layer params; the
+    scan path is for uniform configs, whose every layer has layer 0's
+    window.  Returns ``(x, pool, scales)``."""
+    if cfg.loop_mode == "scan":
+        window = _window_of(cfg, 0)
+
+        def body(carry, scan_in):
+            lp, l, items_l = scan_in
+            return layer(*carry, lp, l, window, items_l), None
+        carry, _ = jax.lax.scan(
+            body, (x, pool, scales),
+            (params["layers"], jnp.arange(cfg.num_layers),
+             None if sel is None else jnp.asarray(sel)))
+        return carry
+    for l in range(cfg.num_layers):
+        items_l = None if sel is None else jnp.asarray(sel[l])
+        x, pool, scales = layer(x, pool, scales, params["layers"][l], l,
+                                _window_of(cfg, l), items_l)
+    return x, pool, scales
+
+
 def prefill_chunk_paged(params, pool, tokens, table, q_offset,
                         cfg: TransformerConfig, *,
                         kv_len=None, sparse_items=None, last_index=None,
@@ -772,6 +800,11 @@ def prefill_chunk_paged(params, pool, tokens, table, q_offset,
     dense chunks gather the table's blocks into a contiguous [Smax] view —
     O(one sequence), exactly the staging traffic of the contiguous path.
     Returns (logits [1, V] at chunk-local ``last_index``, new pool).
+
+    The whole pool is the layer loop's carry: layer ``l`` scatters its
+    blocks into ``pool[l]`` and reads ``pool[l]`` back for its attention,
+    so under donation the pool is written in place — no per-layer copy of
+    it is stacked into a new pool.
 
     Quantized pool (DESIGN.md §2.12): pass ``scales [L, 2, N, Hkv]`` f32 +
     the storage ``kv_dtype`` — the chunk's block tiles quantize at scatter
@@ -797,29 +830,30 @@ def prefill_chunk_paged(params, pool, tokens, table, q_offset,
     x = jnp.take(params["embed"], tokens, axis=0)
     x = constrain(x, "batch", None, None)
 
-    def layer(x, lp, layer_pool, layer_scales, l, items_l):
+    def layer(x, pool, scales, lp, li, window, items_l):
         h = common.rmsnorm(x, lp["ln1"])
         q, k, v = _qkv(h, lp["attn"], cfg, positions)
         q = constrain(q, "batch", "model", None, None)
-        # block-scatter the chunk's K/V through the table
+        # block-scatter the chunk's K/V through the table into layer li of
+        # the whole pool, then read that layer back: the write comes first
+        # so the read sees it and the scatter stays in place
         gsl = jax.lax.dynamic_slice(tbl, (ob,), (nblk,))
         gids = jnp.where(gsl >= 0, gsl, trash)
         as_blocks = lambda t: jnp.moveaxis(
             t[0].reshape(hkv, nblk, block, dh), 1, 0)
         if not qz:
             ks = vs = None
-            kc = layer_pool[0].at[gids].set(
-                as_blocks(k).astype(layer_pool.dtype))
-            vc = layer_pool[1].at[gids].set(
-                as_blocks(v).astype(layer_pool.dtype))
+            pool = pool.at[li, 0, gids].set(as_blocks(k).astype(pool.dtype))
+            pool = pool.at[li, 1, gids].set(as_blocks(v).astype(pool.dtype))
         else:
             kcodes, ksc = quant.quantize_pool_blocks(as_blocks(k), kv_dtype)
             vcodes, vsc = quant.quantize_pool_blocks(as_blocks(v), kv_dtype)
-            kc = layer_pool[0].at[gids].set(kcodes)
-            vc = layer_pool[1].at[gids].set(vcodes)
-            ks = layer_scales[0].at[gids].set(ksc)
-            vs = layer_scales[1].at[gids].set(vsc)
-        window = _window_of(cfg, l)
+            pool = pool.at[li, 0, gids].set(kcodes)
+            pool = pool.at[li, 1, gids].set(vcodes)
+            scales = scales.at[li, 0, gids].set(ksc)
+            scales = scales.at[li, 1, gids].set(vsc)
+            ks, vs = scales[li, 0], scales[li, 1]
+        kc, vc = pool[li, 0], pool[li, 1]
         if items_l is not None:
             o = worklist_attention_paged(
                 q[0], kc, vc, items_l, tbl,
@@ -854,40 +888,10 @@ def prefill_chunk_paged(params, pool, tokens, table, q_offset,
         x = x + jnp.einsum("bsf,fd->bsd", o, lp["attn"]["wo"])
         h2 = common.rmsnorm(x, lp["ln2"])
         x = x + _ffn(h2, lp, cfg)
-        if qz:
-            return x, jnp.stack([kc, vc]), jnp.stack([ks, vs])
-        return x, jnp.stack([kc, vc])
+        return x, pool, scales
 
-    if cfg.loop_mode == "scan":
-        xs = [params["layers"], pool]
-        if qz:
-            xs.append(scales)
-        if sparse_items is not None:
-            xs.append(jnp.asarray(sparse_items))
-
-        def body(x, scan_in):
-            it = iter(scan_in)
-            lp, layer_pool = next(it), next(it)
-            layer_scales = next(it) if qz else None
-            items_l = next(it) if sparse_items is not None else None
-            out = layer(x, lp, layer_pool, layer_scales, 0, items_l)
-            return out[0], out[1:]
-        x, ys = jax.lax.scan(body, x, tuple(xs))
-        new_pool = ys[0]
-        new_scales = ys[1] if qz else None
-    else:
-        new_layers, new_scale_layers = [], []
-        for l in range(cfg.num_layers):
-            items_l = (None if sparse_items is None
-                       else jnp.asarray(sparse_items[l]))
-            out = layer(x, params["layers"][l], pool[l],
-                        scales[l] if qz else None, l, items_l)
-            x = out[0]
-            new_layers.append(out[1])
-            if qz:
-                new_scale_layers.append(out[2])
-        new_pool = jnp.stack(new_layers)
-        new_scales = jnp.stack(new_scale_layers) if qz else None
+    x, pool, scales = _paged_layer_loop(layer, cfg, params, x, pool, scales,
+                                        sparse_items)
     if last_index is None:
         x_last = x[:, -1:, :]
     else:
@@ -895,8 +899,8 @@ def prefill_chunk_paged(params, pool, tokens, table, q_offset,
             x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
     logits = _logits(x_last, params, cfg)[:, 0]
     if qz:
-        return logits, new_pool, new_scales
-    return logits, new_pool
+        return logits, pool, scales
+    return logits, pool
 
 
 def _merge_stripe_partials(parts, B, hkv, dh, dtype):
@@ -925,10 +929,13 @@ def decode_step_paged(params, pool, token, pos, table,
 
     token [B] int32; pos scalar OR [B] int32; pool [L, 2, N, Hkv, block,
     Dh]; ``table [B, T]`` int32 per-slot block tables (logical -> pool
-    block, -1 = unmapped/free slot).  Each row's K/V is a SINGLE-BLOCK
-    ``dynamic_update_slice`` into its current block; rows that are
-    inactive (``active`` False) or unmapped write the trash block N-1, so
-    the batched step never needs a read-modify-write mask.  ``block_ids``
+    block, -1 = unmapped/free slot).  The whole pool is the layer loop's
+    carry: layer ``l`` scatters each row's K/V token into its current
+    block of ``pool[l]``, then reads ``pool[l]`` for its attention, so
+    under donation the pool is written in place and never rebuilt.  Rows
+    that are inactive (``active`` False) or unmapped write the trash block
+    N-1, so the batched step never needs a read-modify-write mask.
+    ``block_ids``
     ([L, Hkv, nb] or [L, B, Hkv, nb], LOGICAL, -1 pad) select the blocks
     the budgeted flash-decode streams from the pool through the table;
     ``packed_items`` (mutually exclusive): ``[L, Lb, DEC_FIELDS]``
@@ -979,7 +986,7 @@ def decode_step_paged(params, pool, token, pos, table,
     act = (jnp.ones((B,), bool) if active is None
            else jnp.asarray(active))
 
-    def layer(x, lp, layer_pool, layer_scales, l, items_l):
+    def layer(x, pool, scales, lp, li, window, items_l):
         h = common.rmsnorm(x, lp["ln1"])
         ap = lp["attn"]
         q = common.split_heads(jnp.einsum("bsd,df->bsf", h, ap["wq"]),
@@ -992,10 +999,12 @@ def decode_step_paged(params, pool, token, pos, table,
         q = jax.vmap(rope)(q, pos_arr)
         k = jax.vmap(rope)(k, pos_arr)
 
-        # one vectorized scatter per tensor: row b lands at
-        # (pool block, row offset) = (table[b, pos//block], pos % block);
-        # inactive/unmapped rows collapse onto the trash block (their
-        # values are junk by contract, so duplicate trash hits are fine)
+        # one vectorized scatter per tensor into layer li of the whole
+        # pool: row b lands at (pool block, row offset) = (table[b,
+        # pos//block], pos % block); inactive/unmapped rows collapse onto
+        # the trash block (their values are junk by contract, so duplicate
+        # trash hits are fine).  The layer's K/V are read back after the
+        # write, so the scatter stays in place on the carried pool.
         phys = jnp.take_along_axis(tbl, (pos_arr // block)[:, None],
                                    axis=1)[:, 0]
         gids = jnp.where(act & (phys >= 0), phys, trash)       # [B]
@@ -1005,29 +1014,29 @@ def decode_step_paged(params, pool, token, pos, table,
         if not qz:
             ks = vs = None
 
-            def write(c, new):
-                return c.at[gids[:, None], heads[None, :],
-                            offs[:, None]].set(
-                    new[:, :, 0, :].astype(c.dtype))
+            def write(pool, kv, new):
+                return pool.at[li, kv, gids[:, None], heads[None, :],
+                               offs[:, None]].set(
+                    new[:, :, 0, :].astype(pool.dtype))
 
-            kc = write(layer_pool[0], k)
-            vc = write(layer_pool[1], v)
+            pool = write(write(pool, 0, k), 1, v)
         else:
             # quantized append: gather each row's current block tile +
             # scale, requantize with the new token, scatter the FULL tile
             # back (same B-blocks-per-layer traffic class as the gathers
             # the attention itself performs; inactive rows hit the trash
             # block, whose codes/scale are junk by contract)
-            def rmw(c, sc, tok):
-                cur = jnp.take(c, gids, axis=0)          # [B, Hkv, blk, Dh]
-                cur_s = jnp.take(sc, gids, axis=0)       # [B, Hkv]
+            def rmw(pool, scales, kv, tok):
+                cur = pool[li, kv, gids]                 # [B, Hkv, blk, Dh]
+                cur_s = scales[li, kv, gids]             # [B, Hkv]
                 new_c, new_s = quant.insert_token_requant(
                     cur, cur_s, tok[:, :, 0, :], offs, kv_dtype)
-                return c.at[gids].set(new_c), sc.at[gids].set(new_s)
+                return (pool.at[li, kv, gids].set(new_c),
+                        scales.at[li, kv, gids].set(new_s))
 
-            kc, ks = rmw(layer_pool[0], layer_scales[0], k)
-            vc, vs = rmw(layer_pool[1], layer_scales[1], v)
-        window = _window_of(cfg, l)
+            pool, scales = rmw(*rmw(pool, scales, 0, k), 1, v)
+            ks, vs = scales[li, 0], scales[li, 1]
+        kc, vc = pool[li, 0], pool[li, 1]
 
         def stripe_table(s):
             # entries another stripe owns become -1 (masked): each block
@@ -1101,43 +1110,14 @@ def decode_step_paged(params, pool, token, pos, table,
         x = x + jnp.einsum("bsf,fd->bsd", o, lp["attn"]["wo"])
         h2 = common.rmsnorm(x, lp["ln2"])
         x = x + _ffn(h2, lp, cfg)
-        if qz:
-            return x, jnp.stack([kc, vc]), jnp.stack([ks, vs])
-        return x, jnp.stack([kc, vc])
+        return x, pool, scales
 
-    if cfg.loop_mode == "scan":
-        xs = [params["layers"], pool]
-        if qz:
-            xs.append(scales)
-        if sel is not None:
-            xs.append(jnp.asarray(sel))
-
-        def body(x, scan_in):
-            it = iter(scan_in)
-            lp, layer_pool = next(it), next(it)
-            layer_scales = next(it) if qz else None
-            items_l = next(it) if sel is not None else None
-            out = layer(x, lp, layer_pool, layer_scales, 0, items_l)
-            return out[0], out[1:]
-        x, ys = jax.lax.scan(body, x, tuple(xs))
-        new_pool = ys[0]
-        new_scales = ys[1] if qz else None
-    else:
-        new_layers, new_scale_layers = [], []
-        for l in range(cfg.num_layers):
-            items_l = None if sel is None else jnp.asarray(sel[l])
-            out = layer(x, params["layers"][l], pool[l],
-                        scales[l] if qz else None, l, items_l)
-            x = out[0]
-            new_layers.append(out[1])
-            if qz:
-                new_scale_layers.append(out[2])
-        new_pool = jnp.stack(new_layers)
-        new_scales = jnp.stack(new_scale_layers) if qz else None
+    x, pool, scales = _paged_layer_loop(layer, cfg, params, x, pool, scales,
+                                        sel)
     logits = _logits(x, params, cfg)[:, 0]
     if qz:
-        return logits, new_pool, new_scales
-    return logits, new_pool
+        return logits, pool, scales
+    return logits, pool
 
 
 # ---------------------------------------------------------------------------

@@ -5,11 +5,16 @@ off the (8, 128) tiling, or more SMEM/VMEM than a kernel may hold.  These
 cases compile the paged and contiguous flash-decode kernels at the widths
 the engine serves, so such a refusal fails here rather than on the chip.
 
+The paged decode and chunk steps are compiled whole, with the pool
+donated, to hold them to an in-place pool: no temporary or copy the size
+of the pool.
+
 The topology is described inside a module fixture: only the worker that
 runs this file loads the TPU compiler, and every worker collects the same
 tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,11 +22,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import quant
+from repro.kernels import ops
 from repro.kernels.flash_decode import (
     flash_decode_kernel,
     flash_decode_paged_kernel,
 )
 from repro.kernels.sparse_decode import DEC_FIELDS
+from repro.models import transformer as tfm
 
 BLOCK = 128
 SLOTS = 8
@@ -125,3 +132,76 @@ def test_contiguous_int8_compiles(one_chip):
                  s((ITEMS, DEC_FIELDS), jnp.int32), s((SLOTS,), jnp.int32),
                  k_scales=scales, v_scales=scales).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+# SmolLM widths at 4 layers, the cell's 1024-block pool plus the trash block
+STEP_CFG = tfm.TransformerConfig(num_layers=4, d_model=576, num_heads=9,
+                                 num_kv_heads=3, head_dim=64, d_ff=1536,
+                                 vocab_size=49152, block_q=BLOCK,
+                                 block_kv=BLOCK, layer_loop="scan")
+STEP_BLOCKS = 1025
+
+
+@pytest.fixture(scope="module")
+def paged_steps(one_chip):
+    """The packed decode step and the sparse 256-token chunk step, each
+    compiled with the pool donated: ``(pool shape, layer bytes, decode,
+    chunk)``."""
+    cfg = STEP_CFG
+    s = lambda shape, dt: _shape(one_chip, shape, dt)
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+    shape = (cfg.num_layers, 2, STEP_BLOCKS, cfg.num_kv_heads, BLOCK,
+             cfg.head_dim_)
+    pool = s(shape, jnp.bfloat16)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    T = MAX_SEQ // BLOCK
+    on_tpu = ops._on_tpu
+    ops._on_tpu = lambda: True       # the dispatch sees the CPU here
+    try:
+        decode = jax.jit(lambda p, c, tok, pos, tbl, items, act:
+                         tfm.decode_step_paged(
+                             p, c, tok, pos, tbl, cfg, packed_items=items,
+                             cache_len=pos + 1, active=act),
+                         donate_argnums=(1,)).lower(
+            params, pool, i32(SLOTS), i32(SLOTS), i32(SLOTS, T),
+            i32(cfg.num_layers, ITEMS, DEC_FIELDS),
+            s((SLOTS,), jnp.bool_)).compile()
+        chunk = jax.jit(lambda p, c, tok, tbl, off, kv_len, last, items:
+                        tfm.prefill_chunk_paged(
+                            p, c, tok, tbl, off, cfg, kv_len=kv_len,
+                            sparse_items=items, last_index=last),
+                        donate_argnums=(1,)).lower(
+            params, pool, i32(1, 256), i32(T), i32(), i32(), i32(),
+            i32(cfg.num_layers, 512, 7)).compile()
+    finally:
+        ops._on_tpu = on_tpu
+    layer_bytes = 2 * STEP_BLOCKS * cfg.num_kv_heads * BLOCK * cfg.head_dim_ * 2
+    return shape, layer_bytes, decode, chunk
+
+
+def _pool_copies(compiled, shape):
+    """HLO instructions that copy a value of the whole pool's shape."""
+    dims = ",".join(map(str, shape))
+    return [ln.strip() for ln in compiled.as_text().splitlines()
+            if re.search(rf"= bf16\[{dims}\]\S* copy(-start)?\(", ln)]
+
+
+def test_paged_decode_step_updates_pool_in_place(paged_steps):
+    """The pool is the layer loop's carry: the step holds about two layers
+    of temporaries (the kernel operand's head_dim pads), not a second
+    pool, and never copies the pool whole."""
+    shape, layer_bytes, decode, _ = paged_steps
+    ma = decode.memory_analysis()
+    assert ma.alias_size_in_bytes >= layer_bytes * shape[0]
+    assert ma.temp_size_in_bytes < 2.5 * layer_bytes, ma.temp_size_in_bytes
+    assert "tpu_custom_call" in decode.as_text()
+    assert not _pool_copies(decode, shape)
+
+
+def test_paged_chunk_step_updates_pool_in_place(paged_steps):
+    shape, layer_bytes, _, chunk = paged_steps
+    ma = chunk.memory_analysis()
+    assert ma.alias_size_in_bytes >= layer_bytes * shape[0]
+    assert ma.temp_size_in_bytes < layer_bytes, ma.temp_size_in_bytes
+    assert not _pool_copies(chunk, shape)
