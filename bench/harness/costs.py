@@ -4,21 +4,14 @@ Counted as the algorithm needs them, not as the program happens to run
 them: only active decode rows, only real chunk tokens, only the keys a
 query reads under its selection and causality.  A multiply-add is two
 operations.  Bytes are those of the selected KV tiles at the unpadded
-head size.
+head size.  The weights a token passes through are the family's count
+(``harness.families``).
 """
 from __future__ import annotations
 
 import numpy as np
 
-from harness.model import dims
-
-
-def matmul_params(cfg: dict) -> tuple[int, int]:
-    """(per-token weights of all layers, weights of the output head)."""
-    m = dims(cfg)
-    d, Dh = m["d"], m["Dh"]
-    attn = d * Dh * (m["H"] + 2 * m["Hkv"]) + m["H"] * Dh * d
-    return m["L"] * (attn + 3 * d * m["ff"]), d * m["V"]
+from harness import families
 
 
 def decode_attention_keys(sel, positions) -> np.ndarray:
@@ -42,8 +35,8 @@ def decode_attention_keys(sel, positions) -> np.ndarray:
 
 def decode_step_flops(cfg: dict, sel, positions) -> float:
     """One decode step over ``positions`` (one per active row)."""
-    m = dims(cfg)
-    w, head = matmul_params(cfg)
+    fam = families.load(cfg)
+    m, (w, head) = fam.dims(cfg), fam.matmul_params(cfg)
     G = m["H"] // m["Hkv"]
     keys = decode_attention_keys(sel, positions).sum()
     return 2.0 * len(positions) * (w + head) + 4.0 * G * m["Dh"] * keys
@@ -73,8 +66,8 @@ def chunk_flops(cfg: dict, sel, q_offset: int, length: int,
                 final: bool) -> float:
     """One prefill chunk of ``length`` real tokens at ``q_offset``; a final
     chunk also computes one row of logits."""
-    m = dims(cfg)
-    w, head = matmul_params(cfg)
+    fam = families.load(cfg)
+    m, (w, head) = fam.dims(cfg), fam.matmul_params(cfg)
     keys = chunk_attention_keys(sel, q_offset, length)
     return (2.0 * length * w + (2.0 * head if final else 0.0)
             + 4.0 * m["Dh"] * keys)
@@ -84,10 +77,11 @@ def decode_attention_bytes(cfg: dict, real_items: int, block: int,
                            kv_bytes: int = 2) -> float:
     """Bytes of K and V the packed decode kernel has to read for
     ``real_items`` (row, KV head, block) tiles."""
-    return float(real_items) * block * dims(cfg)["Dh"] * 2 * kv_bytes
+    Dh = families.load(cfg).dims(cfg)["Dh"]
+    return float(real_items) * block * Dh * 2 * kv_bytes
 
 
 def decode_attention_flops(cfg: dict, real_items: int, block: int) -> float:
     """Upper bound of the decode kernel's operations: every tile whole."""
-    m = dims(cfg)
+    m = families.load(cfg).dims(cfg)
     return 4.0 * real_items * block * m["Dh"] * (m["H"] // m["Hkv"])

@@ -20,8 +20,6 @@ import functools
 
 import numpy as np
 
-from harness.model import dims
-
 ROWS = 256
 PAD = 4096
 
@@ -81,12 +79,12 @@ def _layer_fn(S, d, H, Hkv, Dh, ff, theta, eps, block):
 
 
 @functools.lru_cache(maxsize=None)
-def _gap_fn(n, eps, tied):
+def _head_fn(n, k, eps, tied):
     import jax
     import jax.numpy as jnp
     hp = jax.lax.Precision.HIGHEST
 
-    def run(x, ln_f, head, start, served):
+    def run(x, ln_f, head, start, served, ids):
         xs = jax.lax.dynamic_slice_in_dim(x, start, n)
         xs = xs * jax.lax.rsqrt(jnp.mean(xs * xs, -1, keepdims=True) + eps)
         xs = xs * ln_f
@@ -95,16 +93,17 @@ def _gap_fn(n, eps, tied):
                   else jnp.matmul(xs, w, precision=hp))
         best = logits.max(-1)
         got = jnp.take_along_axis(logits, served[:, None], 1)[:, 0]
-        return best - got
+        return best - got, jnp.take_along_axis(logits, ids, 1)
 
     return jax.jit(run)
 
 
 class Reference:
-    """The reference for one configuration, its weights and selection."""
+    """The reference for one configuration's sizes (``dims``), its weights
+    and selection."""
 
-    def __init__(self, cfg: dict, weights, selection):
-        self.m = dims(cfg)
+    def __init__(self, m: dict, weights, selection):
+        self.m = m
         self.w = weights
         self.sel = selection
         self._masks: dict[tuple[int, int], tuple] = {}
@@ -115,9 +114,12 @@ class Reference:
             self._masks[key] = self.sel.masks(layer, nqb)
         return self._masks[key]
 
-    def gaps(self, prompt: np.ndarray, served: list[int]) -> np.ndarray:
-        """For each served token, how far its reference logit lies below
-        the reference's best at that position."""
+    def compare(self, prompt: np.ndarray, served: list[int],
+                ids: np.ndarray | None = None):
+        """The reference at each served token's position: how far the
+        served token's logit lies below the best (``[n]``), and the
+        float32 logits at ``ids`` (``[n, k]`` vocabulary ids, one row per
+        served token; None gives ``[n, 0]``)."""
         import jax
         import jax.numpy as jnp
         m = self.m
@@ -140,7 +142,11 @@ class Reference:
             x = fn(x, lw, jnp.asarray(pm), jnp.asarray(dm), P)
         served_pad = np.zeros(nrow, np.int32)
         served_pad[:n] = served
+        ids = np.zeros((n, 0), np.int32) if ids is None else ids
+        ids_pad = np.zeros((nrow, ids.shape[1]), np.int32)
+        ids_pad[:n] = ids
         head = self.w["embed"] if m["tied"] else self.w["lm_head"]
-        g = _gap_fn(nrow, m["eps"], m["tied"])(
-            x, self.w["ln_f"], head, P - 1, jnp.asarray(served_pad))
-        return np.asarray(g)[:n]
+        g, z = _head_fn(nrow, ids.shape[1], m["eps"], m["tied"])(
+            x, self.w["ln_f"], head, P - 1, jnp.asarray(served_pad),
+            jnp.asarray(ids_pad))
+        return np.asarray(g)[:n], np.asarray(z)[:n]

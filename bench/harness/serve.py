@@ -129,11 +129,12 @@ class Driver:
         }
 
 
-def warm_steps(eng) -> list[int]:
+def warm_steps(eng, on_logits=None) -> list[int]:
     """Compiles and runs once every packed decode program the engine can
     pick (each item bucket up to its cap) and both prefill chunk buckets,
-    on inactive rows whose writes land in the pool's trash block.  Returns
-    the decode buckets."""
+    on inactive rows whose writes land in the pool's trash block, and
+    ``on_logits`` on each program's logits (the harness's own programs
+    over them compile here too).  Returns the decode buckets."""
     import jax.numpy as jnp
     from repro.core.worklist import pow2_bucket
     from repro.kernels.sparse_decode import DEC_FIELDS
@@ -152,7 +153,7 @@ def warm_steps(eng) -> list[int]:
         logits, cache = fn(eng.params, eng.cache, zeros, zeros, table, items,
                            idle)
         eng._set_cache(cache)
-        np.asarray(logits[:1, :1])
+        _settle(logits, on_logits)
     chunk = ec.prefill_chunk_tokens
     for b in sorted({ec.block, chunk}):
         fn = eng._prefill_chunk_fn(b)
@@ -161,5 +162,10 @@ def warm_steps(eng) -> list[int]:
         logits, cache = fn(eng.params, eng.cache, jnp.zeros((1, b), jnp.int32),
                            row, 0, b, b - 1, items)
         eng._set_cache(cache)
-        np.asarray(logits[:1, :1])
+        _settle(logits, on_logits)
     return buckets
+
+
+def _settle(logits, on_logits) -> None:
+    import jax
+    jax.block_until_ready(on_logits(logits) if on_logits else logits)

@@ -8,10 +8,10 @@ decode call).  The device planes (``/device:TPU:<n>``) hold one event per
 program run on the ``XLA Modules`` line and one per operation on the
 ``XLA Ops`` line.
 
-Programs are told apart by what they hold: a program that runs the Pallas
-paged decode kernel (an operation whose name holds ``KERNEL_TAG``) is a
-decode step; another program of the same name (the engine jits every step
-as ``run``, so each is ``jit_run(<fingerprint>)``) is a prefill chunk.
+A program that runs the Pallas paged decode kernel (an operation whose
+name holds ``KERNEL_TAG``) is a decode step; a program whose name starts
+with ``CHUNK_TAG`` (the engine names its chunk steps ``jit_prefill_chunk``
+and ``jit_prefill_chunk_dense``) is a prefill chunk.
 
 Operations nest (a ``while`` loop holds the ops of its body), so the
 breakdown ranks operations by self time, their own length less that of the
@@ -25,6 +25,7 @@ import os
 import re
 
 KERNEL_TAG = "flash_decode"
+CHUNK_TAG = "jit_prefill_chunk"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 SPANS = {"bench.prefill": "prefill call", "bench.decode": "decode call",
          "bench.tick": "schedule", "bench.window": "harness"}
@@ -108,11 +109,10 @@ def reduce_planes(planes: dict) -> dict | None:
                 i = bisect.bisect_right(starts, s) - 1
                 if i >= 0 and s < mods[i][1] + mods[i][2]:
                     decode_idx.add(i)
-        decode_names = {_base(mods[i][0]) for i in decode_idx}
         for i, (name, _, d) in enumerate(mods):
             if i in decode_idx:
                 decode_ms.append(d / 1e6)
-            elif _base(name) in decode_names:
+            elif _base(name).startswith(CHUNK_TAG):
                 prefill_ms.append(d / 1e6)
     if not busy:
         return None

@@ -5,7 +5,7 @@ from harness import costs
 
 LAYER = "whole step"
 UNIT = "%"
-MOVES = "ttft_p50_ms"
+MOVES = "itl_p95_ms"
 
 
 def read(v):

@@ -1,8 +1,10 @@
 """Device time of one prefill chunk (128 or 256 tokens): the mean length of
-the other step programs of the decode step's name, from the trace."""
+the programs named ``jit_prefill_chunk*`` in the trace.  Most ticks of the
+served cells hold a chunk, and a tick with one sets the tail of the gaps
+between tokens."""
 LAYER = "model step"
 UNIT = "ms"
-MOVES = "ttft_p50_ms"
+MOVES = "itl_p95_ms"
 
 
 def read(v):

@@ -31,7 +31,7 @@ def rehearse(cell) -> dict:
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from harness import model
+    from harness import families, model
     from repro.kernels import ops
     from repro.kernels.sparse_decode import DEC_FIELDS
     from repro.models import transformer as tfm
@@ -42,8 +42,9 @@ def rehearse(cell) -> dict:
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     cfg, traffic = cell.config, cell.traffic
-    m = model.dims(cfg)
-    tcfg = model.transformer_config(cfg)
+    fam = families.load(cfg)
+    m = fam.dims(cfg)
+    tcfg = fam.program_config(cfg)
     ecfg = model.engine_config(cfg, traffic)
     import dataclasses
     tcfg = dataclasses.replace(tcfg, block_q=ecfg.block, block_kv=ecfg.block)
@@ -52,9 +53,7 @@ def rehearse(cell) -> dict:
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
 
     params = jax.tree.map(sds, jax.eval_shape(
-        lambda: model._weights_fn(m["L"], m["d"], m["H"], m["Hkv"], m["Dh"],
-                                  m["ff"], m["V"], m["tied"])(
-            jax.random.PRNGKey(0))))
+        lambda: fam.weights_fn(cfg)(jax.random.PRNGKey(0))))
     N = ecfg.num_kv_blocks + 1
     pool = jax.ShapeDtypeStruct((m["L"], 2, N, m["Hkv"], ecfg.block, m["Dh"]),
                                 jnp.bfloat16, sharding=one)

@@ -10,19 +10,31 @@ on the device from the seed, builds the ``Engine``, compiles every step
 program the cell can pick, brings the traffic to its steady state, and
 measures ``--seconds`` of it through the engine's batcher.  With ``--trace
 1`` the first seconds of the window are traced and the cell's per-layer
-metrics are printed instead of its end-to-end ones.  After the window the
-served tokens of a seeded sample of finished requests are checked against
-the plain reference (``bench/harness/reference.py``).
+metrics are printed instead of its end-to-end ones.
+
+After the window a seeded sample of finished requests is checked against
+the plain reference of the configuration's family
+(``bench/harness/families``).  In a cell whose check sets logit limits
+the basis is ``program_logits``: at every served token's position the
+program's own logits, read by the tap (``bench/harness/tap.py``) at a
+seeded set of ids and the row's top 8, are compared with the reference's
+float32 logits there (``logit_err``, ``logit_err_mean``); a served token
+whose logits the tap did not get counts under ``logit_rows_missing``, and
+any such token makes the run not correct.  The served token's gap below
+the reference's best (``gap_max``) guards against gross token errors.  A
+cell that sets no logit limits is decided on ``gap_max`` alone (basis
+``served_tokens``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` with
-``--trace 1``), and last ``check``, each compared number with its limit.
-Anything but a TPU, or fewer chips than the cell asks for, exits 1 and
-prints no result.
+``--trace 1``), ``basis``, and last ``check``, each compared number with
+its limit.  Anything but a TPU, or fewer chips than the cell asks for,
+exits 1 and prints no result.
 
 ``--control fp8`` runs the cell with the engine's fp8 KV pool, the step
-below the configuration's bfloat16: the comparison has to fail there.  The
-benchmark's own runs never pass it.
+below the configuration's bfloat16, and ``--fault token`` alters every
+row's token on one decode call in seven where it is produced: the comparison has to fail
+under both.  The benchmark's own runs never pass them.
 """
 from __future__ import annotations
 
@@ -39,6 +51,8 @@ import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
 
+import numpy as np  # noqa: E402
+
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
@@ -46,6 +60,23 @@ sys.path.insert(0, str(BENCH))
 from harness import spec  # noqa: E402
 
 CONTROLS = {"fp8": {"kv_dtype": "fp8"}}
+
+
+def alter_tokens(drv) -> None:
+    """Fault: every seventh decode call returns every row's token moved by
+    one id, so each request that decodes seven tokens holds one."""
+    inner, calls = drv._decode, [0]
+
+    def decode(slots, toks, pos):
+        out = np.array(inner(slots, toks, pos))
+        calls[0] += 1
+        if calls[0] % 7 == 0:
+            out = (out + 1) % drv.mix.vocab
+        return out
+    drv._decode = decode
+
+
+FAULTS = {"token": alter_tokens}
 
 
 def log(msg: str) -> None:
@@ -108,8 +139,18 @@ def sample_finished(records, t0, t1, n, seed):
     return [done[0]] + [rest[i] for i in sorted(pick)]
 
 
+def logit_err_rows(z_prog, z_ref) -> np.ndarray:
+    """Per position: the RMS over the compared ids of the program's error,
+    over the RMS spread of the reference's logits about their mean."""
+    err = np.sqrt(np.mean((z_prog - z_ref) ** 2, axis=1))
+    spread = np.sqrt(np.mean((z_ref - z_ref.mean(1, keepdims=True)) ** 2,
+                             axis=1))
+    return err / spread
+
+
 def run(cell, seed: int, seconds: float, trace: bool, *,
         require_tpu: bool = True, control: str | None = None,
+        fault: str | None = None,
         t_start: float = T_START, driver_hook=None,
         peaks: dict | None = None,
         keep_trace: str | None = None) -> dict | None:
@@ -119,11 +160,10 @@ def run(cell, seed: int, seconds: float, trace: bool, *,
     if devices is None:
         return None
     import jax
-    import numpy as np
-    from harness import model, plan, stats
+    from harness import families, model, plan, stats
     from harness.meter import CompileMeter
-    from harness.reference import Reference
     from harness.serve import Driver, warm_steps
+    from harness.tap import LogitTap, gather, logit_ids
     from harness.traffic import Mix
     from repro.serving import Engine
 
@@ -133,21 +173,31 @@ def run(cell, seed: int, seconds: float, trace: bool, *,
     kind = dev.device_kind
     peaks = peaks if peaks is not None else spec.peaks(kind)
     cfg, traffic = cell.config, cell.traffic
-    m = model.dims(cfg)
+    fam = families.load(cfg)
+    m = fam.dims(cfg)
     log(f"[device] {dev.platform} {kind!r} x{len(devices)}, jax "
         f"{jax.__version__}, compile cache {cache_dir}")
 
     marks = {}
     snap = meter.snapshot()
-    tcfg = model.transformer_config(cfg)
+    tcfg = fam.program_config(cfg)
     ecfg = model.engine_config(cfg, traffic, CONTROLS.get(control))
-    eng = Engine(tcfg, model.make_weights(cfg, seed, layers_on_host=True), ecfg,
-                 profile=model.head_profile(cfg))
+    eng = Engine(tcfg, fam.make_weights(cfg, seed, layers_on_host=True), ecfg,
+                 profile=model.head_profile(m["L"], m["H"],
+                                            cfg["engine_profile_seed"]))
     marks["engine"] = time.monotonic()
-    buckets = warm_steps(eng)
+    chk = traffic["check"]
+    on_logits = "logit_err_limit" in chk
+    ids = logit_ids(seed, m["V"])
+    ids_dev = jax.numpy.asarray(ids)
+    buckets = warm_steps(eng, on_logits=(lambda lg: gather(lg, ids_dev))
+                         if on_logits else None)
     marks["warm"] = time.monotonic()
     mix = Mix(traffic, seed, m["V"])
     drv = Driver(eng, mix)
+    tap = LogitTap(eng, drv.batcher, drv.reqs, ids) if on_logits else None
+    if fault is not None:
+        FAULTS[fault](drv)
     if driver_hook is not None:
         driver_hook(drv)
     if mix.closed:
@@ -244,7 +294,6 @@ def run(cell, seed: int, seconds: float, trace: bool, *,
     # correctness: wait past the close (up to a minute, unmeasured) until
     # finished requests hold enough served tokens; free the program's
     # state, then the reference
-    chk = traffic["check"]
     t_done = t1
     while (sum(len(r.tokens) for r in sample_finished(records, t0, t_done,
                                                       10 ** 9, seed))
@@ -255,32 +304,68 @@ def run(cell, seed: int, seconds: float, trace: bool, *,
         log(f"[reference] waited {t_done - t1:.3f} s past the close for "
             "finished requests")
     sample = sample_finished(records, t0, t_done, int(chk["samples"]), seed)
-    del drv, eng
+    basis = "program_logits" if on_logits else "served_tokens"
+    prog, missing = {}, 0
+    if on_logits:
+        prog = {r.rid: tap.request(r.rid, len(r.tokens)) for r in sample}
+        missing = sum(tap.missing(r.rid, len(r.tokens)) for r in sample)
+        if missing:
+            log(f"[tap] {missing} served tokens have no program logits"
+                + (f" ({tap.reason})" if tap.reason else "")
+                + ": the run is not correct")
+    del drv, eng, tap
     gc.collect()
     t_ref = time.monotonic()
-    ref = Reference(cfg, model.make_weights(cfg, seed), sel)
-    gaps = []
+    ref = fam.Reference(m, fam.make_weights(cfg, seed), sel)
+    gaps, errs = [], []
     for r in sample:
-        gaps.append(ref.gaps(r.prompt, r.tokens))
+        if prog.get(r.rid) is not None:
+            vals, top_i, top_v = prog[r.rid]
+            n = len(r.tokens)
+            cmp_ids = np.concatenate([np.broadcast_to(ids, (n, len(ids))),
+                                      top_i], axis=1)
+            g, z_ref = ref.compare(r.prompt, r.tokens, cmp_ids)
+            errs.append(logit_err_rows(np.concatenate([vals, top_v], 1),
+                                       z_ref))
+        else:
+            g, _ = ref.compare(r.prompt, r.tokens)
+        gaps.append(g)
     gaps = np.concatenate(gaps) if gaps else np.zeros(0)
+    errs = np.concatenate(errs) if errs else np.zeros(0)
     n_tok = len(gaps)
-    finite = bool(np.isfinite(gaps).all())
-    read = {"gap_max": float(gaps.max()) if n_tok else None,
-            "gap_mean": float(gaps.mean()) if n_tok else None}
-    check = {"gap_max": {"value": read["gap_max"] if finite else float("inf"),
-                         "limit": float(chk["gap_max_limit"])}}
+    finite = bool(np.isfinite(gaps).all() and np.isfinite(errs).all())
+    worst = lambda v, f: (float(f(v)) if finite else float("inf"))
+    check = {}
+    if on_logits:
+        got = len(errs) > 0
+        check["logit_err"] = {"value": worst(errs, np.max) if got else None,
+                              "limit": float(chk["logit_err_limit"])}
+        check["logit_err_mean"] = {
+            "value": worst(errs, np.mean) if got else None,
+            "limit": float(chk["logit_err_mean_limit"])}
+        check["logit_rows_missing"] = {"value": missing, "limit": 0}
+    check["gap_max"] = {"value": worst(gaps, np.max) if n_tok else None,
+                        "limit": float(chk["gap_max_limit"])}
     check["served_tokens_compared"] = {"value": n_tok,
                                        "limit": int(chk["min_tokens"])}
     check["failed"] = {"value": failed, "limit": 0}
-    ok = (n_tok >= int(chk["min_tokens"]) and failed == 0 and finite
-          and check["gap_max"]["value"] <= check["gap_max"]["limit"])
+    ok = (n_tok >= int(chk["min_tokens"]) and finite
+          and all(v["value"] is not None and v["value"] <= v["limit"]
+                  for k, v in check.items()
+                  if k != "served_tokens_compared"))
     result["correct"] = bool(ok)
-    log(f"[readings] gap_max {read['gap_max']!r} gap_mean "
-        f"{read['gap_mean']!r} flips {int((gaps > 0).sum())}/{n_tok}")
+    log(f"[readings] basis {basis}; gap_max {check['gap_max']['value']!r} "
+        f"gap_mean {float(gaps.mean()) if n_tok else None!r} flips "
+        f"{int((gaps > 0).sum())}/{n_tok}" + (
+            f"; logit_err {check['logit_err']['value']!r} mean "
+            f"{check['logit_err_mean']['value']!r} median "
+            f"{float(np.median(errs))!r}" if len(errs) else ""))
+    result["basis"] = basis
     result["check"] = check
     log(f"[reference] {len(sample)} requests, {n_tok} served tokens, "
         f"{time.monotonic() - t_ref:.3f} s" + (f" (control {control})"
-                                                 if control else ""))
+                                                 if control else "")
+        + (f" (fault {fault})" if fault else ""))
     for k, v in check.items():
         log(f"check {k}: {v['value']} (limit {v['limit']})")
     return result
@@ -293,6 +378,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--control", choices=sorted(CONTROLS), default=None)
+    ap.add_argument("--fault", choices=sorted(FAULTS), default=None)
     ap.add_argument("--keep-trace", default=None,
                     help="copy the profiler trace of a --trace 1 run here")
     args = ap.parse_args(argv)
@@ -302,7 +388,8 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(ROOT / "src"))
     cell = spec.load_cell(args.workload)
     result = run(cell, args.seed, args.seconds, bool(args.trace),
-                 control=args.control, keep_trace=args.keep_trace)
+                 control=args.control, fault=args.fault,
+                 keep_trace=args.keep_trace)
     if result is None:
         return 1
     print(json.dumps(result), flush=True)

@@ -23,9 +23,7 @@ reduction of ``harness/trace.py`` this reads:
   path during which the device has nothing queued;
 - ``tick_self_ms``: each ``sched.tick`` less the time covered by the
   ``engine.*`` spans inside it: the scheduler's own host work;
-- ``bench_tick_ms``: the length of each ``bench.tick`` span;
-- ``prefill_chunk_ms`` also counts programs named ``jit_prefill_chunk``,
-  beside the same-name rule of ``harness/trace.py``.
+- ``bench_tick_ms``: the length of each ``bench.tick`` span.
 
 Prints one JSON object.  ``--fixture`` writes the first ``--fixture-ms``
 of the window as a test fixture (``bench/tests/data``).
@@ -46,7 +44,6 @@ sys.path.insert(0, str(BENCH))
 from harness import trace  # noqa: E402
 
 PROGRAM = ("sched.", "engine.")
-CHUNK_PROGRAMS = ("jit_prefill_chunk", "jit_prefill_chunk_dense")
 PREFILL_SPANS = ("sched.prefill_step", "engine.prefill.")
 
 
@@ -112,9 +109,6 @@ def reduce(planes: dict) -> dict | None:
             for x, y in zip(cuts, cuts[1:]):
                 what = trace._doing(spans, (x + y) / 2)
                 by_span[what] = by_span.get(what, 0.0) + y - x
-        out["prefill_chunk_ms"] += [
-            d / 1e6 for n, s, d in lines.get("XLA Modules", [])
-            if trace._base(n) in CHUNK_PROGRAMS and w0 <= s and s + d <= w1]
     gaps.sort(key=lambda g: -g[0])
     out["idle_gaps"] = [[what, t / 1e9] for t, what in gaps[:10]]
     out["idle_by_span"] = {k: v / n_dev / 1e9 for k, v in sorted(

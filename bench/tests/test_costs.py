@@ -5,7 +5,7 @@ import json
 
 import numpy as np
 
-from harness import costs, plan
+from harness import costs, families, plan
 from conftest import BENCH
 
 CFG = json.loads((BENCH / "configs/smollm2-135m.json").read_text())
@@ -18,7 +18,7 @@ def two_block_selection():
 
 def test_matmul_params():
     per_layer = 576 * 64 * (9 + 2 * 3) + 9 * 64 * 576 + 3 * 576 * 1536
-    assert costs.matmul_params(CFG) == (30 * per_layer, 576 * 49152)
+    assert families.load(CFG).matmul_params(CFG) == (30 * per_layer, 576 * 49152)
     assert per_layer == 3538944
 
 

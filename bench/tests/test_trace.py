@@ -22,7 +22,8 @@ def test_hand_made_trace():
             ("bench.tick", 0.0, 50 * MS), ("bench.decode", 20 * MS, 30 * MS),
             ("bench.tick", 50 * MS, 50 * MS),
             ("bench.prefill", 52 * MS, 8 * MS)]
-    modules = [("jit_run", 25 * MS, 10 * MS), ("jit_run", 60 * MS, 20 * MS),
+    modules = [("jit_decode_packed(123)", 25 * MS, 10 * MS),
+               ("jit_prefill_chunk(456)", 60 * MS, 20 * MS),
                ("jit_argmax", 36 * MS, 1 * MS)]
     ops = [("fusion.1", 25 * MS, 4 * MS),
            ("_flash_decode_paged_kernel", 29 * MS, 6 * MS),
@@ -43,6 +44,25 @@ def test_hand_made_trace():
                     20: "schedule"}
     # ranked by self time: fusion.2 less the fusion.3 inside it
     assert r["device_ops"][0] == ["fusion.2", pytest.approx(0.018)]
+
+
+def test_chunk_programs_by_name():
+    # the engine's step names: decode by its kernel, chunks (sparse and
+    # dense) by name; the tap's gather and a merge are neither
+    host = [("bench.window", 0.0, 100 * MS)]
+    modules = [("jit_decode_packed(9)", 0.0, 10 * MS),
+               ("jit_logit_tap", 11 * MS, 1 * MS),
+               ("jit_prefill_chunk(7)", 20 * MS, 13 * MS),
+               ("jit_prefill_chunk_dense(8)", 40 * MS, 5 * MS),
+               ("jit_merge_staging", 50 * MS, 2 * MS),
+               ("jit_decode_padded(3)", 60 * MS, 4 * MS)]
+    ops = [("_flash_decode_paged_kernel", 1 * MS, 8 * MS),
+           ("fusion.1", 11 * MS, 1 * MS), ("while.46", 20 * MS, 13 * MS),
+           ("fusion.2", 40 * MS, 5 * MS), ("copy.1", 50 * MS, 2 * MS),
+           ("fusion.3", 60 * MS, 4 * MS)]
+    r = trace.reduce_planes(planes(ops, modules, host))
+    assert r["decode_step_ms"] == [pytest.approx(10.0)]
+    assert r["prefill_chunk_ms"] == [pytest.approx(13.0), pytest.approx(5.0)]
 
 
 def test_no_window_span_reads_nothing():
