@@ -156,10 +156,10 @@ def batched_worklist_attention(q, k, v, items, **kw):
 @functools.partial(jax.jit, static_argnames=("block_q", "block_kv", "scale"))
 def worklist_attention_paged(
     q: jnp.ndarray,       # [H, Sq, D]
-    k_pool: jnp.ndarray,  # [N, Hkv, block_kv, D]  device block pool
-    v_pool: jnp.ndarray,
-    items: jnp.ndarray,   # [L, ITEM_FIELDS] int32 (kv_blk LOGICAL)
+    pool: jnp.ndarray,    # [L, 2, N, Hkv, block_kv, D]  paged K/V cache
+    items: jnp.ndarray,   # [P, ITEM_FIELDS] int32 (kv_blk LOGICAL)
     table: jnp.ndarray,   # [T] int32 logical kv block -> pool block (-1)
+    layer: jnp.ndarray | int,
     *,
     block_q: int = 128,
     block_kv: int = 128,
@@ -170,21 +170,24 @@ def worklist_attention_paged(
     v_scales: jnp.ndarray | None = None,
 ):
     """Paged twin of :func:`worklist_attention` (DESIGN.md §2.7): the K/V
-    tiles come from a device block POOL through the sequence's block table
-    instead of a contiguous per-sequence cache.  Item ``kv_blk`` stays in
-    the LOGICAL namespace (positions and masks derive from it); only the
-    slice ADDRESS is table-indirected, so tile values, masks, and the
-    accumulation order — hence the bit pattern of the output — match the
-    contiguous executor on equal cache contents.  ``kv_len`` masks
-    positions past the resident prefix, which also guarantees every
-    contributing logical block is mapped; unmapped (-1) entries are
-    clamped to pool block 0 and masked out.  With a quantized pool
-    (§2.12) pass ``k_scales``/``v_scales [N, Hkv]`` f32 — the chunked
-    prefill's reads of PAST resident blocks dequantize post-dot, same as
-    the decode executors.
+    tiles come from layer ``layer`` of the device block POOL through the
+    sequence's block table instead of a contiguous per-sequence cache.
+    Item ``kv_blk`` stays in the LOGICAL namespace (positions and masks
+    derive from it); only the tile ADDRESS is table-indirected, so tile
+    values, masks, and the accumulation order — hence the bit pattern of
+    the output — match the contiguous executor on equal cache contents.
+    ``kv_len`` masks positions past the resident prefix, which also
+    guarantees every contributing logical block is mapped; unmapped (-1)
+    entries are clamped to pool block 0 and masked out.  With a quantized
+    pool (§2.12) pass ``k_scales``/``v_scales [N, Hkv]`` f32 (layer
+    ``layer``'s) — the chunked prefill's reads of PAST resident blocks
+    dequantize post-dot, same as the decode executors.
+
+    Every item's K and V tile is gathered from the whole pool before the
+    scan, an O(items) temporary: no layer of the pool is sliced out.
     """
     hq, sq, dh = q.shape
-    assert k_pool.shape[2] == block_kv, "pool block size != block_kv"
+    assert pool.shape[4] == block_kv, "pool block size != block_kv"
     scale_v = (dh ** -0.5) if scale is None else scale
     quantized = k_scales is not None
     pad_q = (-sq) % block_q
@@ -196,12 +199,20 @@ def worklist_attention_paged(
         ksf = k_scales.astype(jnp.float32)
         vsf = v_scales.astype(jnp.float32)
 
+    # every item's pool block; an unmapped one reads block 0, masked below
+    phys_all = tbl[jnp.maximum(items[:, F_KVBLK], 0)]
+    safe_all = jnp.maximum(phys_all, 0)
+    kvh_all = items[:, F_KVHEAD]
+    k_tiles = pool[layer, 0, safe_all, kvh_all]
+    v_tiles = pool[layer, 1, safe_all, kvh_all]
+
     out0 = jnp.zeros((hq, sqp, dh), jnp.float32)
     acc0 = jnp.zeros((block_q, dh), jnp.float32)
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
 
-    def step(carry, it):
+    def step(carry, scan_in):
+        it, phys, kt, vt = scan_in
         out, acc, m, l = carry
         head, qblk, kvblk = it[F_HEAD], it[F_QBLK], it[F_KVBLK]
         kvh = it[F_KVHEAD]
@@ -213,15 +224,10 @@ def worklist_attention_paged(
         m = jnp.where(first, jnp.full_like(m, -jnp.inf), m)
         l = jnp.where(first, jnp.zeros_like(l), l)
 
-        phys = tbl[jnp.maximum(kvblk, 0)]
         mapped = phys >= 0
         safe = jnp.maximum(phys, 0)
         qt = jax.lax.dynamic_slice(
             qp, (head, qblk * block_q, 0), (1, block_q, dh))[0]
-        kt = jax.lax.dynamic_slice(
-            k_pool, (safe, kvh, 0, 0), (1, 1, block_kv, dh))[0, 0]
-        vt = jax.lax.dynamic_slice(
-            v_pool, (safe, kvh, 0, 0), (1, 1, block_kv, dh))[0, 0]
         if not quantized:
             kt = kt.astype(jnp.float32)
             vt = vt.astype(jnp.float32)
@@ -265,7 +271,8 @@ def worklist_attention_paged(
             out, tile[None], (head, qblk * block_q, 0))
         return (out, acc, m, l), None
 
-    (out, _, _, _), _ = jax.lax.scan(step, (out0, acc0, m0, l0), items)
+    (out, _, _, _), _ = jax.lax.scan(
+        step, (out0, acc0, m0, l0), (items, phys_all, k_tiles, v_tiles))
     return out[:, :sq, :].astype(q.dtype)
 
 

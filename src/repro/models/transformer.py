@@ -796,7 +796,7 @@ def prefill_chunk_paged(params, pool, tokens, table, q_offset,
     ``q_offset`` / ``kv_len`` / ``last_index`` are traced scalars and the
     table is data, so one compile per chunk bucket serves every sequence,
     offset, and block placement.  Sparse items execute via
-    ``worklist_attention_paged`` (per-block pool slices, zero gather);
+    ``worklist_attention_paged`` (each item's tile gathered from the pool);
     dense chunks gather the table's blocks into a contiguous [Smax] view —
     O(one sequence), exactly the staging traffic of the contiguous path.
     Returns (logits [1, V] at chunk-local ``last_index``, new pool).
@@ -836,47 +836,56 @@ def prefill_chunk_paged(params, pool, tokens, table, q_offset,
         q = constrain(q, "batch", "model", None, None)
         # block-scatter the chunk's K/V through the table into layer li of
         # the whole pool, then read that layer back: the write comes first
-        # so the read sees it and the scatter stays in place
+        # so the read sees it and the scatter stays in place.  One index
+        # per (block, kv head) tile keeps the write a scatter even for a
+        # one-block chunk: as one whole block XLA turns it into a
+        # bounds-checked update that reads the pool back, and at head_dim
+        # 128 it then lays the carried pool out to suit the chunk's K,
+        # copying the whole pool into and out of the layer loop.
         gsl = jax.lax.dynamic_slice(tbl, (ob,), (nblk,))
-        gids = jnp.where(gsl >= 0, gsl, trash)
+        gids = jnp.where(gsl >= 0, gsl, trash)[:, None]
+        heads = jnp.arange(hkv)[None, :]
         as_blocks = lambda t: jnp.moveaxis(
             t[0].reshape(hkv, nblk, block, dh), 1, 0)
         if not qz:
             ks = vs = None
-            pool = pool.at[li, 0, gids].set(as_blocks(k).astype(pool.dtype))
-            pool = pool.at[li, 1, gids].set(as_blocks(v).astype(pool.dtype))
+            pool = pool.at[li, 0, gids, heads].set(
+                as_blocks(k).astype(pool.dtype))
+            pool = pool.at[li, 1, gids, heads].set(
+                as_blocks(v).astype(pool.dtype))
         else:
             kcodes, ksc = quant.quantize_pool_blocks(as_blocks(k), kv_dtype)
             vcodes, vsc = quant.quantize_pool_blocks(as_blocks(v), kv_dtype)
-            pool = pool.at[li, 0, gids].set(kcodes)
-            pool = pool.at[li, 1, gids].set(vcodes)
-            scales = scales.at[li, 0, gids].set(ksc)
-            scales = scales.at[li, 1, gids].set(vsc)
+            pool = pool.at[li, 0, gids, heads].set(kcodes)
+            pool = pool.at[li, 1, gids, heads].set(vcodes)
+            scales = scales.at[li, 0, gids, heads].set(ksc)
+            scales = scales.at[li, 1, gids, heads].set(vsc)
             ks, vs = scales[li, 0], scales[li, 1]
-        kc, vc = pool[li, 0], pool[li, 1]
         if items_l is not None:
+            # the tiles are gathered from layer li of the whole pool: a
+            # slice of the layer would be a temporary the size of one
+            # layer's K and V
             o = worklist_attention_paged(
-                q[0], kc, vc, items_l, tbl,
+                q[0], pool, items_l, tbl, li,
                 block_q=cfg.block_q, block_kv=block,
                 q_offset=q_offset, kv_len=kv_len,
                 k_scales=ks, v_scales=vs)[None]
         else:
+            rows = jnp.maximum(tbl, 0)
             if qz:
                 # dequantized per-sequence view: gather codes AND scales
                 # through the table, one broadcast multiply (O(sequence) —
                 # the same staging traffic the dense chunk already pays)
-                view = lambda c, s: (
-                    jnp.moveaxis(jnp.take(c, jnp.maximum(tbl, 0), axis=0),
-                                 0, 1).astype(jnp.float32)
-                    * jnp.moveaxis(jnp.take(s, jnp.maximum(tbl, 0), axis=0),
+                view = lambda kv, s: (
+                    jnp.moveaxis(pool[li, kv, rows], 0, 1).astype(jnp.float32)
+                    * jnp.moveaxis(jnp.take(s, rows, axis=0),
                                    0, 1)[:, :, None, None]
                 ).reshape(hkv, T * block, dh)
-                kview, vview = view(kc, ks), view(vc, vs)
+                kview, vview = view(0, ks), view(1, vs)
             else:
-                view = lambda c: jnp.moveaxis(
-                    jnp.take(c, jnp.maximum(tbl, 0), axis=0), 0, 1
-                ).reshape(hkv, T * block, dh)
-                kview, vview = view(kc), view(vc)
+                view = lambda kv: jnp.moveaxis(
+                    pool[li, kv, rows], 0, 1).reshape(hkv, T * block, dh)
+                kview, vview = view(0), view(1)
             kpos = jnp.arange(T * block)
             valid = ((kpos[None, :] <= positions[:, None])
                      & (kpos[None, :] < kv_len))          # [C, T*block]

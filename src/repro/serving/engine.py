@@ -1792,7 +1792,8 @@ class Engine:
         return got
 
     def _prefill_chunk_fn(self, bucket: int):
-        """Jitted chunked-prefill step for one chunk compile bucket.
+        """Jitted chunked-prefill step for one chunk compile bucket, named
+        ``prefill_chunk_b<bucket>`` (``prefill_chunk_dense_b<bucket>``).
 
         The slot cache threads through and is donated (same zero-copy
         contract as monolithic prefill); ``slot`` / ``q_offset`` / ``kv_len``
@@ -1847,9 +1848,10 @@ class Engine:
                         kv_len=kv_len, sparse_items=None,
                         last_index=last_idx)
 
-            donate = (1,) if self._donate else ()
-            return (jax.jit(prefill_chunk, donate_argnums=donate) if sparse
-                    else jax.jit(prefill_chunk_dense, donate_argnums=donate))
+            # a name per bucket, so a trace tells the buckets apart
+            fn = prefill_chunk if sparse else prefill_chunk_dense
+            fn.__name__ = fn.__qualname__ = f"{fn.__name__}_b{bucket}"
+            return jax.jit(fn, donate_argnums=(1,) if self._donate else ())
 
         return self._lru_get(self._prefill_chunk_jit, bucket, build,
                              self.ecfg.chunk_jit_cap)

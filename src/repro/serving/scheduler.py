@@ -12,8 +12,9 @@ prefill CHUNK plus the full decode batch:
   partial, so every chunk's cache offset stays block-aligned for the
   work-list slicing in the engine);
 - the chunk size adapts to the decode load: ``max(block, token_budget -
-  num_active_decodes)`` tokens, so a long-context arrival is amortized over
-  many ticks and decodes keep stepping;
+  num_active_decodes)`` tokens, the decodes rounded to the nearest whole
+  block, so a long-context arrival is amortized over many ticks and
+  decodes keep stepping;
 - ``token_budget=None`` degrades to the old monolithic behavior (one
   whole-prompt chunk at admission) — kept as the benchmark baseline.
 
@@ -775,12 +776,16 @@ class ContinuousBatcher:
 
     def _prefill_step(self, prefill_chunk_fn, finished: list[Request]):
         """Run at most one prefill chunk, sized to the tick's leftover
-        token budget (decodes reserve one token each)."""
+        token budget (decodes reserve one token each, counted in whole
+        blocks)."""
         req = self.prefilling
         if req is None:
             return
         remaining = len(req.prompt) - req.prefill_pos
-        budget = max(self.block, self.token_budget - len(self.active))
+        # decodes claim the budget in whole blocks, rounded to the nearest,
+        # so a few decoding rows leave a two-block chunk whole
+        taken = (len(self.active) + self.block // 2) // self.block * self.block
+        budget = max(self.block, self.token_budget - taken)
         chunk = min(remaining, budget)
         final = chunk == remaining
         if not final:

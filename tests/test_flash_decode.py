@@ -322,8 +322,9 @@ class TestPagedParity:
         base = worklist_attention(qx, kx, vx, jnp.asarray(items),
                                   block_q=BLK, block_kv=BLK,
                                   q_offset=0, kv_len=S)
-        paged = worklist_attention_paged(qx, kp, vp, jnp.asarray(items),
-                                         tbl[0], block_q=BLK, block_kv=BLK,
+        pool = jnp.stack([kp, vp])[None]      # one layer: [1, 2, N, ...]
+        paged = worklist_attention_paged(qx, pool, jnp.asarray(items),
+                                         tbl[0], 0, block_q=BLK, block_kv=BLK,
                                          q_offset=0, kv_len=S)
         assert np.array_equal(np.asarray(base), np.asarray(paged))
 

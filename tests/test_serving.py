@@ -475,8 +475,36 @@ class TestScheduler:
             pos += c
         assert chunks[-1][2] and b.stats.prefill_chunks == len(chunks)
 
+    @pytest.mark.parametrize("rows,size", [(0, 256), (1, 256), (6, 256),
+                                           (63, 256), (64, 128)])
+    def test_decoding_rows_take_whole_blocks_of_the_budget(self, rows, size):
+        """With ``rows`` sequences decoding, a long prompt's non-final
+        chunks are the budget less the rows rounded to the nearest block:
+        a few rows leave a 256-token chunk whole, 64 take a block."""
+        chunks = []
 
-class TestBlockAllocator:
+        def prefill(toks, slot, q_offset, is_final, prompt_len):
+            if prompt_len > 1:
+                chunks.append((toks.shape[-1], is_final))
+            return 1 if is_final else None
+
+        def decode(slots, toks, pos):
+            return np.ones(len(slots), np.int32)
+
+        b = ContinuousBatcher(num_slots=rows + 1, num_blocks=4 * rows + 16,
+                              max_seq_len=2048, block=128, token_budget=256)
+        for r in range(rows):
+            b.submit(Request(rid=r, prompt=np.arange(1),
+                             sampling=SamplingParams(max_tokens=400)))
+        while len(b.active) < rows:
+            b.tick(prefill, decode)
+        b.submit(Request(rid=rows, prompt=np.arange(1000),
+                         sampling=SamplingParams(max_tokens=1)))
+        while b.pending or b.prefilling is not None:
+            b.tick(prefill, decode)
+        assert len(b.active) == rows
+        assert sum(c for c, _ in chunks) == 1000
+        assert {c for c, final in chunks if not final} == {size}
     def test_admit_free_cycle(self):
         a = BlockAllocator(num_blocks=10, block=128)
         a.admit(1, 500)   # 4 blocks

@@ -134,50 +134,67 @@ def test_contiguous_int8_compiles(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-# SmolLM widths at 4 layers, the cell's 1024-block pool plus the trash block
-STEP_CFG = tfm.TransformerConfig(num_layers=4, d_model=576, num_heads=9,
-                                 num_kv_heads=3, head_dim=64, d_ff=1536,
-                                 vocab_size=49152, block_q=BLOCK,
-                                 block_kv=BLOCK, layer_loop="scan")
+# The steps at two widths, each with a 1024-block pool plus the trash
+# block: SmolLM-135M at 4 layers (head_dim 64, the kernel's padded path)
+# and Yi-6B at 2 layers (head_dim 128 unpadded, 32 query heads over 4 KV
+# heads, untied head), where XLA may lay the pool out either way round
+STEP_CFGS = {
+    "smollm": tfm.TransformerConfig(num_layers=4, d_model=576, num_heads=9,
+                                    num_kv_heads=3, head_dim=64, d_ff=1536,
+                                    vocab_size=49152, block_q=BLOCK,
+                                    block_kv=BLOCK, layer_loop="scan"),
+    "yi6b": tfm.TransformerConfig(num_layers=2, d_model=4096, num_heads=32,
+                                  num_kv_heads=4, head_dim=128, d_ff=11008,
+                                  vocab_size=64000, tie_embeddings=False,
+                                  block_q=BLOCK, block_kv=BLOCK,
+                                  layer_loop="scan"),
+}
 STEP_BLOCKS = 1025
 
 
 @pytest.fixture(scope="module")
-def paged_steps(one_chip):
-    """The packed decode step and the sparse 256-token chunk step, each
-    compiled with the pool donated: ``(pool shape, layer bytes, decode,
-    chunk)``."""
-    cfg = STEP_CFG
-    s = lambda shape, dt: _shape(one_chip, shape, dt)
-    params = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
-        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
-    shape = (cfg.num_layers, 2, STEP_BLOCKS, cfg.num_kv_heads, BLOCK,
-             cfg.head_dim_)
-    pool = s(shape, jnp.bfloat16)
-    i32 = lambda *shape: s(shape, jnp.int32)
-    T = MAX_SEQ // BLOCK
-    on_tpu = ops._on_tpu
-    ops._on_tpu = lambda: True       # the dispatch sees the CPU here
-    try:
-        decode = jax.jit(lambda p, c, tok, pos, tbl, items, act:
-                         tfm.decode_step_paged(
-                             p, c, tok, pos, tbl, cfg, packed_items=items,
-                             cache_len=pos + 1, active=act),
-                         donate_argnums=(1,)).lower(
-            params, pool, i32(SLOTS), i32(SLOTS), i32(SLOTS, T),
-            i32(cfg.num_layers, ITEMS, DEC_FIELDS),
-            s((SLOTS,), jnp.bool_)).compile()
-        chunk = jax.jit(lambda p, c, tok, tbl, off, kv_len, last, items:
-                        tfm.prefill_chunk_paged(
-                            p, c, tok, tbl, off, cfg, kv_len=kv_len,
-                            sparse_items=items, last_index=last),
-                        donate_argnums=(1,)).lower(
-            params, pool, i32(1, 256), i32(T), i32(), i32(), i32(),
-            i32(cfg.num_layers, 512, 7)).compile()
-    finally:
-        ops._on_tpu = on_tpu
-    layer_bytes = 2 * STEP_BLOCKS * cfg.num_kv_heads * BLOCK * cfg.head_dim_ * 2
-    return shape, layer_bytes, decode, chunk
+def paged_step(one_chip):
+    """``compile(width, step, chunk=None) -> (pool shape, layer bytes,
+    compiled)``: the packed decode step (``step="decode"``) or the sparse
+    chunk step of ``chunk`` tokens, compiled with the pool donated."""
+    def compile_step(width, step, chunk=None):
+        cfg = STEP_CFGS[width]
+        s = lambda shape, dt: _shape(one_chip, shape, dt)
+        params = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+        shape = (cfg.num_layers, 2, STEP_BLOCKS, cfg.num_kv_heads, BLOCK,
+                 cfg.head_dim_)
+        pool = s(shape, jnp.bfloat16)
+        i32 = lambda *shape: s(shape, jnp.int32)
+        T = MAX_SEQ // BLOCK
+        on_tpu = ops._on_tpu
+        ops._on_tpu = lambda: True       # the dispatch sees the CPU here
+        try:
+            if step == "decode":
+                compiled = jax.jit(lambda p, c, tok, pos, tbl, items, act:
+                                   tfm.decode_step_paged(
+                                       p, c, tok, pos, tbl, cfg,
+                                       packed_items=items,
+                                       cache_len=pos + 1, active=act),
+                                   donate_argnums=(1,)).lower(
+                    params, pool, i32(SLOTS), i32(SLOTS), i32(SLOTS, T),
+                    i32(cfg.num_layers, ITEMS, DEC_FIELDS),
+                    s((SLOTS,), jnp.bool_)).compile()
+            else:
+                compiled = jax.jit(lambda p, c, tok, tbl, off, kv_len, last,
+                                   items: tfm.prefill_chunk_paged(
+                                       p, c, tok, tbl, off, cfg,
+                                       kv_len=kv_len, sparse_items=items,
+                                       last_index=last),
+                                   donate_argnums=(1,)).lower(
+                    params, pool, i32(1, chunk), i32(T), i32(), i32(), i32(),
+                    i32(cfg.num_layers, 512, 7)).compile()
+        finally:
+            ops._on_tpu = on_tpu
+        layer_bytes = (2 * STEP_BLOCKS * cfg.num_kv_heads * BLOCK
+                       * cfg.head_dim_ * 2)
+        return shape, layer_bytes, compiled
+    return compile_step
 
 
 def _pool_copies(compiled, shape):
@@ -187,11 +204,12 @@ def _pool_copies(compiled, shape):
             if re.search(rf"= bf16\[{dims}\]\S* copy(-start)?\(", ln)]
 
 
-def test_paged_decode_step_updates_pool_in_place(paged_steps):
+@pytest.mark.parametrize("width", list(STEP_CFGS))
+def test_paged_decode_step_updates_pool_in_place(paged_step, width):
     """The pool is the layer loop's carry: the step holds about two layers
-    of temporaries (the kernel operand's head_dim pads), not a second
-    pool, and never copies the pool whole."""
-    shape, layer_bytes, decode, _ = paged_steps
+    of temporaries (at head_dim 64, the kernel operand's pads), not a
+    second pool, and never copies the pool whole."""
+    shape, layer_bytes, decode = paged_step(width, "decode")
     ma = decode.memory_analysis()
     assert ma.alias_size_in_bytes >= layer_bytes * shape[0]
     assert ma.temp_size_in_bytes < 2.5 * layer_bytes, ma.temp_size_in_bytes
@@ -199,9 +217,15 @@ def test_paged_decode_step_updates_pool_in_place(paged_steps):
     assert not _pool_copies(decode, shape)
 
 
-def test_paged_chunk_step_updates_pool_in_place(paged_steps):
-    shape, layer_bytes, _, chunk = paged_steps
-    ma = chunk.memory_analysis()
+@pytest.mark.parametrize("width", list(STEP_CFGS))
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_paged_chunk_step_updates_pool_in_place(paged_step, width, chunk):
+    """Every chunk bucket writes the pool in place at both widths: at
+    head_dim 128 a one-block chunk once made XLA lay the carried pool out
+    to suit the chunk's K, copying the whole pool into and out of the
+    layer loop."""
+    shape, layer_bytes, chunk_step = paged_step(width, "chunk", chunk)
+    ma = chunk_step.memory_analysis()
     assert ma.alias_size_in_bytes >= layer_bytes * shape[0]
     assert ma.temp_size_in_bytes < layer_bytes, ma.temp_size_in_bytes
-    assert not _pool_copies(chunk, shape)
+    assert not _pool_copies(chunk_step, shape)

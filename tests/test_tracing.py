@@ -8,7 +8,9 @@ match counts taken around the step closures; and greedy tokens do not
 change when the profiler is on.
 """
 import glob
+import importlib.util
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -143,8 +145,8 @@ def _bids(eng):
     return jnp.asarray(np.stack([ids] * eng.ecfg.num_slots, axis=1))
 
 
-def _chunk_args(eng):
-    b = eng.ecfg.block
+def _chunk_args(eng, b=None):
+    b = b or eng.ecfg.block
     row = jnp.full((eng.kv.table_width,), -1, jnp.int32)
     return (eng.params, eng.cache, jnp.zeros((1, b), jnp.int32), row, 0, b,
             b - 1)
@@ -189,8 +191,21 @@ def prefill_chunk(eng):
     return eng._prefill_chunk_fn(b), _chunk_args(eng) + (items,)
 
 
+def prefill_chunk_b256(eng):
+    items = jnp.asarray(eng._chunk_worklists(256, 0, 256))
+    return eng._prefill_chunk_fn(256), _chunk_args(eng, 256) + (items,)
+
+
 def prefill_chunk_dense(eng):
     return eng._prefill_chunk_fn(eng.ecfg.block), _chunk_args(eng)
+
+
+def prefill_chunk_contiguous(eng):
+    b = eng.ecfg.block
+    items = jnp.asarray(eng._chunk_worklists(b, 0, b))
+    return eng._prefill_chunk_fn(b), (eng.params, eng.cache,
+                                      jnp.zeros((1, b), jnp.int32), 0, 0, b,
+                                      b - 1, items)
 
 
 def merge_staging(eng):
@@ -232,7 +247,9 @@ STEPS = {
     prefill: {"cache_layout": "contiguous"},
     prefill_paged: {},
     prefill_chunk: {},
+    prefill_chunk_b256: {},
     prefill_chunk_dense: {"attention": "dense"},
+    prefill_chunk_contiguous: {"cache_layout": "contiguous"},
     merge_staging: {"cache_layout": "contiguous"},
     kv_permute: {},
     swap_gather: {},
@@ -253,11 +270,35 @@ def engines(params):
     return get
 
 
+# each chunk bucket (128 or 256 tokens here) is a program of its own name
+PROGRAM = {
+    prefill_chunk: "jit_prefill_chunk_b128",
+    prefill_chunk_b256: "jit_prefill_chunk_b256",
+    prefill_chunk_dense: "jit_prefill_chunk_dense_b128",
+    prefill_chunk_contiguous: "jit_prefill_chunk_b128",
+}
+
+
 @pytest.mark.parametrize("step", list(STEPS), ids=lambda f: f.__name__)
 def test_step_lowers_under_its_name(engines, step):
     fn, args = step(engines(STEPS[step]))
     text = fn.lower(*args).as_text()
-    assert f"module @jit_{step.__name__} " in text
+    assert f"module @{PROGRAM.get(step, 'jit_' + step.__name__)} " in text
+
+
+def _bench_trace():
+    path = Path(__file__).resolve().parents[1] / "bench/harness/trace.py"
+    spec = importlib.util.spec_from_file_location("bench_trace", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("step", list(PROGRAM), ids=lambda f: f.__name__)
+def test_chunk_program_counted_as_a_chunk(step):
+    """The benchmark's trace reader counts a program as a prefill chunk by
+    the prefix of its name; every bucket's name keeps it."""
+    assert PROGRAM[step].startswith(_bench_trace().CHUNK_TAG)
 
 
 # -- counters -----------------------------------------------------------------
