@@ -445,14 +445,15 @@ def flash_decode_reference(
 
 def _flash_decode_paged_kernel(
     items_ref, tbl_ref, pos_ref,   # SMEM (scalar prefetch)
-    q_ref, k_hbm, v_hbm,           # q tile via index map; pools stay in HBM
-    *rest,                         # [ks_ref, vs_ref,] outs, scratch
+    *rest,                         # [layer_ref,] q_ref, pool(s) in HBM,
+                                   # [ks_ref, vs_ref,] outs, scratch
     scale: float,
     block_kv: int,
     window: int | None,
     quantized: bool = False,
     num_kv_heads: int = 0,
     table_width: int = 0,
+    layered: bool = False,
 ):
     """Same online-softmax body as :func:`_flash_decode_kernel`, but the
     K/V tiles come from the block POOL, copied by hand at the
@@ -460,7 +461,17 @@ def _flash_decode_paged_kernel(
     stream in while item ``i`` computes.  An item is additionally invalid
     when its table entry is unmapped (``table[slot, logical] < 0`` — e.g. a
     shard that does not own the block under a block-sharded pool); invalid
-    items copy nothing."""
+    items copy nothing.  ``layered``: one whole ``[L, 2, N, Hkv, block,
+    Dh]`` pool in HBM, and the tiles are copied from layer
+    ``layer_ref[0]`` of it (a fourth scalar-prefetch argument)."""
+    if layered:
+        layer_ref, q_ref, pool_hbm, *rest = rest
+        kv_src = lambda phys, head: [pool_hbm.at[layer_ref[0], c, phys, head]
+                                     for c in (0, 1)]
+    else:
+        q_ref, k_hbm, v_hbm, *rest = rest
+        kv_src = lambda phys, head: [k_hbm.at[phys, head],
+                                     v_hbm.at[phys, head]]
     if quantized:
         (ks_ref, vs_ref, o_ref, m_out_ref, l_out_ref,
          acc_ref, m_ref, l_ref, k_buf, v_buf, sem) = rest
@@ -476,11 +487,10 @@ def _flash_decode_paged_kernel(
         phys_j = tbl_ref[slot_j * table_width + _field(items_ref, j, D_KVBLK)]
         head_j = _field(items_ref, j, D_KVHEAD)
         buf = j % 2
-        copies = [pltpu.make_async_copy(
-                      pool.at[jnp.maximum(phys_j, 0), head_j], dst.at[buf],
-                      sem.at[c, buf])
-                  for c, (pool, dst) in enumerate(((k_hbm, k_buf),
-                                                   (v_hbm, v_buf)))]
+        copies = [pltpu.make_async_copy(src, dst.at[buf], sem.at[c, buf])
+                  for c, (src, dst) in enumerate(zip(
+                      kv_src(jnp.maximum(phys_j, 0), head_j),
+                      (k_buf, v_buf)))]
         ok = (_field(items_ref, j, D_VALID) == 1) & (phys_j >= 0)
         return ok, phys_j, copies
 
@@ -568,8 +578,9 @@ def _flash_decode_paged_kernel(
 )
 def flash_decode_paged_kernel(
     q: jnp.ndarray,        # [B, Hkv, G, D]  (GQA-grouped query rows)
-    k_pool: jnp.ndarray,   # [N, Hkv, block_kv, D]  device block pool
-    v_pool: jnp.ndarray,
+    k_pool: jnp.ndarray,   # [N, Hkv, block_kv, D], or the whole
+                           # [L, 2, N, Hkv, block_kv, D] pool with ``layer``
+    v_pool: jnp.ndarray | None,   # [N, Hkv, block_kv, D]; None with layer
     items: jnp.ndarray,    # [L, DEC_FIELDS] int32, D_KVBLK LOGICAL
     table: jnp.ndarray,    # [B, T] int32 logical -> pool block (-1 unmapped)
     pos: jnp.ndarray,      # [B] int32 per-slot last position (inclusive)
@@ -580,23 +591,38 @@ def flash_decode_paged_kernel(
     interpret: bool = False,
     k_scales: jnp.ndarray | None = None,   # [N, Hkv] f32, PHYSICAL index
     v_scales: jnp.ndarray | None = None,
+    layer: jnp.ndarray | int | None = None,
 ):
     """Paged twin of :func:`flash_decode_kernel`: one (slot, kv_head,
     logical_block) matvec tile per grid step, the body dereferences the
     block table in SMEM (scalar prefetch) and copies the tile from the pool
     itself, one item ahead, so exactly the selected pool blocks move
     HBM->VMEM — same roofline as the
-    contiguous kernel, token-granular memory."""
+    contiguous kernel, token-granular memory.
+
+    Two operand forms.  Without ``layer``: one layer's K and V pools
+    ``[N, Hkv, block_kv, D]``, head_dim padded to 128 lanes here.  With
+    ``layer`` (an int32 scalar, traced or not): ``k_pool`` is the whole
+    ``[L, 2, N, Hkv, block_kv, D]`` pool, ``v_pool`` None, and the tiles
+    are copied from ``k_pool[layer, 0 / 1]`` as it lies — no per-layer
+    slice of the pool is made, which requires whole 128-lane tiles
+    (``D % 128 == 0``).  The scales stay one layer's ``[N, Hkv]``."""
     B, hkv, G, dh = q.shape
-    assert k_pool.shape[2] == block_kv, "pool block size != block_kv"
+    assert k_pool.shape[-2] == block_kv, "pool block size != block_kv"
     scale_v = float(dh ** -0.5) if scale is None else float(scale)
     quantized = k_scales is not None
+    layered = layer is not None
 
     pad_g = (-G) % 8        # sublane alignment
     dh_pad = (-dh) % 128    # lane alignment
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad_g), (0, dh_pad)))
-    kp = jnp.pad(k_pool, ((0, 0), (0, 0), (0, 0), (0, dh_pad)))
-    vp = jnp.pad(v_pool, ((0, 0), (0, 0), (0, 0), (0, dh_pad)))
+    if layered:
+        assert v_pool is None and k_pool.ndim == 6, "whole pool, V in it"
+        assert dh_pad == 0, "the whole-pool form needs head_dim % 128 == 0"
+        pools = [k_pool]
+    else:
+        pools = [jnp.pad(p, ((0, 0), (0, 0), (0, 0), (0, dh_pad)))
+                 for p in (k_pool, v_pool)]
     Gp, dp = G + pad_g, dh + dh_pad
     L = items.shape[0]
     T = table.shape[1]
@@ -604,20 +630,17 @@ def flash_decode_paged_kernel(
     kernel = functools.partial(
         _flash_decode_paged_kernel, scale=scale_v, block_kv=block_kv,
         window=window, quantized=quantized, num_kv_heads=hkv,
-        table_width=T)
+        table_width=T, layered=layered)
 
-    def bh_index(i, it, tb, p):
+    def bh_index(i, it, *_):
         return (_field(it, i, D_BATCH), _field(it, i, D_KVHEAD), 0, 0)
 
     # the pools stay in HBM and the body copies its tiles itself: a
     # table-indirected BlockSpec on a pool of a few thousand bf16 blocks
     # halts a v5e at run time, though it compiles
-    in_specs = [
-        pl.BlockSpec((1, 1, Gp, dp), bh_index),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    operands = [qp, kp, vp]
+    in_specs = ([pl.BlockSpec((1, 1, Gp, dp), bh_index)]
+                + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools))
+    operands = [qp, *pools]
     if quantized:
         # per-(physical block, kv head) scales: the flat [N * Hkv] table
         # sits whole in SMEM (4 B per pool tile) and the body reads the
@@ -629,8 +652,12 @@ def flash_decode_paged_kernel(
             in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
             operands.append(s_arr.astype(jnp.float32).reshape(-1))
 
+    prefetch = [items.reshape(-1), table.astype(jnp.int32).reshape(-1),
+                pos.astype(jnp.int32)]
+    if layered:
+        prefetch.append(jnp.asarray(layer, jnp.int32).reshape(1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(L,),
         in_specs=in_specs,
         out_specs=[
@@ -642,8 +669,8 @@ def flash_decode_paged_kernel(
             pltpu.VMEM((Gp, dp), jnp.float32),
             pltpu.VMEM((Gp, 1), jnp.float32),
             pltpu.VMEM((Gp, 1), jnp.float32),
-            pltpu.VMEM((2, block_kv, dp), kp.dtype),   # K double buffer
-            pltpu.VMEM((2, block_kv, dp), vp.dtype),   # V double buffer
+            pltpu.VMEM((2, block_kv, dp), pools[0].dtype),   # K buffers
+            pltpu.VMEM((2, block_kv, dp), pools[-1].dtype),  # V buffers
             pltpu.SemaphoreType.DMA((2, 2)),           # [K/V, buffer]
         ],
     )
@@ -659,8 +686,7 @@ def flash_decode_paged_kernel(
             jax.ShapeDtypeStruct((B, hkv, Gp, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(items.reshape(-1), table.astype(jnp.int32).reshape(-1),
-      pos.astype(jnp.int32), *operands)
+    )(*prefetch, *operands)
     return (out[:, :, :G, :dh], m[:, :, :G, 0], l[:, :, :G, 0])
 
 

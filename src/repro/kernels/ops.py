@@ -103,14 +103,25 @@ def flash_decode(q, k_cache, v_cache, block_ids, pos, *, block_kv=128,
     return out.astype(q.dtype)
 
 
+def _layer_pools(k_pool, v_pool, layer):
+    """The reference executors' per-layer K and V pools: with ``layer``,
+    ``k_pool`` is the whole ``[L, 2, N, Hkv, block, D]`` pool."""
+    if layer is None:
+        return k_pool, v_pool
+    return k_pool[layer, 0], k_pool[layer, 1]
+
+
 def flash_decode_paged(q, k_pool, v_pool, block_ids, table, pos, *,
                        block_kv=128, scale=None, window=None, partials=False,
                        use_kernel=None, interpret=None, k_scales=None,
-                       v_scales=None):
+                       v_scales=None, layer=None):
     """Paged fused flash-decode: stream selected blocks from the pool.
 
     q ``[B, H, 1, D]`` (serving layout — GQA grouping happens here);
-    pools ``[N, Hkv, block_kv, D]``; ``block_ids [B, Hkv, nb]`` int32
+    pools ``[N, Hkv, block_kv, D]``, or with ``layer`` the whole ``[L, 2,
+    N, Hkv, block_kv, D]`` pool as ``k_pool`` and ``v_pool`` None (the
+    kernel then reads layer ``layer``'s tiles from it in place; head_dim
+    a multiple of 128); ``block_ids [B, Hkv, nb]`` int32
     LOGICAL selected blocks (-1 pad, trailing); ``table [B, T]`` int32
     logical -> pool-global translation (-1 = unmapped, masked); ``pos [B]``
     per-slot last position.  With a quantized pool pass ``k_scales``/
@@ -121,7 +132,7 @@ def flash_decode_paged(q, k_pool, v_pool, block_ids, table, pos, *,
     reference with the identical zero-copy access pattern.
     """
     B, H, _, dh = q.shape
-    hkv = k_pool.shape[1]
+    hkv = k_pool.shape[-3]
     G = H // hkv
     qg = q.reshape(B, hkv, G, dh)
     if use_kernel is None:
@@ -133,8 +144,10 @@ def flash_decode_paged(q, k_pool, v_pool, block_ids, table, pos, *,
         out, m, l = _flash_decode_paged_kernel(
             qg, k_pool, v_pool, items, jnp.asarray(table), jnp.asarray(pos),
             block_kv=block_kv, scale=scale, window=window,
-            interpret=interpret, k_scales=k_scales, v_scales=v_scales)
+            interpret=interpret, k_scales=k_scales, v_scales=v_scales,
+            layer=layer)
     else:
+        k_pool, v_pool = _layer_pools(k_pool, v_pool, layer)
         out, m, l = _flash_decode_paged_ref(
             qg, k_pool, v_pool, jnp.asarray(block_ids), jnp.asarray(table),
             jnp.asarray(pos), block_kv=block_kv, scale=scale, window=window,
@@ -188,12 +201,14 @@ def flash_decode_packed(q, k_cache, v_cache, items, pos, *, block_kv=128,
 def flash_decode_packed_paged(q, k_pool, v_pool, items, table, pos, *,
                               block_kv=128, scale=None, window=None,
                               partials=False, use_kernel=None,
-                              interpret=None, k_scales=None, v_scales=None):
+                              interpret=None, k_scales=None, v_scales=None,
+                              layer=None):
     """Paged twin of :func:`flash_decode_packed`: the packed items' LOGICAL
     kv blocks translate to pool blocks through ``table [B, T]`` (-1 =
-    unmapped, masked); same contract otherwise."""
+    unmapped, masked); pool operands and ``layer`` as
+    :func:`flash_decode_paged`; same contract otherwise."""
     B, H, _, dh = q.shape
-    hkv = k_pool.shape[1]
+    hkv = k_pool.shape[-3]
     G = H // hkv
     qg = q.reshape(B, hkv, G, dh)
     if use_kernel is None:
@@ -204,8 +219,10 @@ def flash_decode_packed_paged(q, k_pool, v_pool, items, table, pos, *,
         out, m, l = _flash_decode_paged_kernel(
             qg, k_pool, v_pool, jnp.asarray(items), jnp.asarray(table),
             jnp.asarray(pos), block_kv=block_kv, scale=scale, window=window,
-            interpret=interpret, k_scales=k_scales, v_scales=v_scales)
+            interpret=interpret, k_scales=k_scales, v_scales=v_scales,
+            layer=layer)
     else:
+        k_pool, v_pool = _layer_pools(k_pool, v_pool, layer)
         out, m, l = _packed_decode_paged_ref(
             qg, k_pool, v_pool, jnp.asarray(items), jnp.asarray(table),
             jnp.asarray(pos), block_kv=block_kv, scale=scale, window=window,

@@ -953,6 +953,12 @@ def decode_step_paged(params, pool, token, pos, table,
     prefix (a gathered contiguous view — the contiguous baseline's math
     bit-for-bit).  Returns (logits [B, V], new pool).
 
+    The decode kernel's operand follows head_dim: at a multiple of 128 it
+    gets the whole carried pool and the layer index and copies its tiles
+    from ``pool[l]`` as it lies; at 64 it gets ``pool[l, 0]`` and
+    ``pool[l, 1]``, sliced out per layer and padded to 128 lanes (ROADMAP
+    S7b).  The two forms compute the same tiles bit for bit.
+
     Sequence striping (DESIGN.md §2.11): ``seq_stripes > 1`` emulates the
     2D head x sequence mesh on one device — the pool's usable blocks are
     owned in contiguous ``stripe_size`` ranges by ``seq_stripes`` virtual
@@ -1045,7 +1051,13 @@ def decode_step_paged(params, pool, token, pos, table,
 
             pool, scales = rmw(*rmw(pool, scales, 0, k), 1, v)
             ks, vs = scales[li, 0], scales[li, 1]
-        kc, vc = pool[li, 0], pool[li, 1]
+        # the kernel's pool operand: at a head_dim of whole 128-lane tiles
+        # it copies layer li's tiles from the carried pool itself; at 64 it
+        # takes the layer's K and V, padded (ROADMAP S7b)
+        if dh % 128 == 0:
+            kc, vc, at = pool, None, {"layer": li}
+        else:
+            kc, vc, at = pool[li, 0], pool[li, 1], {}
 
         def stripe_table(s):
             # entries another stripe owns become -1 (masked): each block
@@ -1061,13 +1073,14 @@ def decode_step_paged(params, pool, token, pos, table,
                 # the single-device twin of the island's psum over 'seq'
                 parts = [kernel_ops.flash_decode_packed_paged(
                     q, kc, vc, items_l[s], tbl, pos_arr, block_kv=block,
-                    window=window, partials=True, k_scales=ks, v_scales=vs)
+                    window=window, partials=True, k_scales=ks, v_scales=vs,
+                    **at)
                     for s in range(seq_stripes)]
                 o = _merge_stripe_partials(parts, B, hkv, dh, q.dtype)
             else:
                 o = kernel_ops.flash_decode_packed_paged(
                     q, kc, vc, items_l, tbl, pos_arr, block_kv=block,
-                    window=window, k_scales=ks, v_scales=vs)
+                    window=window, k_scales=ks, v_scales=vs, **at)
         elif items_l is not None:
             ids_b = (jnp.broadcast_to(items_l[None], (B,) + items_l.shape)
                      if items_l.ndim == 2 else items_l)
@@ -1075,13 +1088,13 @@ def decode_step_paged(params, pool, token, pos, table,
                 parts = [kernel_ops.flash_decode_paged(
                     q, kc, vc, ids_b, stripe_table(s), pos_arr,
                     block_kv=block, window=window, partials=True,
-                    k_scales=ks, v_scales=vs)
+                    k_scales=ks, v_scales=vs, **at)
                     for s in range(seq_stripes)]
                 o = _merge_stripe_partials(parts, B, hkv, dh, q.dtype)
             else:
                 o = kernel_ops.flash_decode_paged(
                     q, kc, vc, ids_b, tbl, pos_arr, block_kv=block,
-                    window=window, k_scales=ks, v_scales=vs)
+                    window=window, k_scales=ks, v_scales=vs, **at)
         elif seq_stripes > 1:
             # dense under striping: every resident logical block selected,
             # each stripe streams only its own via the masked table
@@ -1090,10 +1103,11 @@ def decode_step_paged(params, pool, token, pos, table,
             parts = [kernel_ops.flash_decode_paged(
                 q, kc, vc, ids_all, stripe_table(s), pos_arr,
                 block_kv=block, window=window, partials=True,
-                k_scales=ks, v_scales=vs)
+                k_scales=ks, v_scales=vs, **at)
                 for s in range(seq_stripes)]
             o = _merge_stripe_partials(parts, B, hkv, dh, q.dtype)
         else:
+            kc, vc = pool[li, 0], pool[li, 1]
             if qz:
                 # dequantized per-row view: gather codes AND scales
                 # through the table, one broadcast multiply — the dense

@@ -29,6 +29,7 @@ from repro.kernels.flash_decode import (
     flash_decode_reference,
     merge_partials,
 )
+from repro.kernels import ops
 from repro.kernels.ops import flash_decode
 from repro.kernels.ref import gather_decode_reference, gather_output_sizes
 
@@ -424,6 +425,92 @@ class TestPackedExecutor:
             block_kv=BLK, interpret=True)
         np.testing.assert_allclose(np.asarray(kpo), ref, atol=2e-5,
                                    rtol=2e-5)
+
+
+def _whole_pool_case(dtype, L=3, seed=61):
+    """A ``[L, 2, N, Hkv, BLK, 128]`` pool (int8 codes with ``[L, 2, N,
+    Hkv]`` scales, or bf16), queries, selections and a table in which
+    logical block 1 is unmapped and slot 1's last block is the trash block
+    N-1, as an inactive row's is."""
+    B, Hkv, G, Smax, D = 2, 2, 4, 384, 128
+    q, _, _, ids, pos = _rand_case(B, Hkv, G, Smax, D, jnp.bfloat16,
+                                    seed=seed)
+    T = Smax // BLK
+    N = B * T + 3
+    rng = np.random.default_rng(seed + 2)
+    shape = (L, 2, N, Hkv, BLK, D)
+    scales = None
+    if dtype == "int8":
+        pool = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        scales = jnp.asarray(rng.uniform(0.005, 0.02, shape[:4]),
+                             jnp.float32)
+    else:
+        pool = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    tbl = rng.permutation(N - 1)[:B * T].reshape(B, T).astype(np.int32)
+    tbl[:, 1] = -1
+    tbl[1, -1] = N - 1
+    return q.reshape(B, Hkv * G, 1, D), pool, scales, ids, tbl, pos
+
+
+class TestWholePoolOperand:
+    """The paged kernel reading layer ``l``'s tiles from the whole pool at
+    a layer index computes what it computes from that layer's K and V
+    pools, bit for bit: the same tiles in the same item order."""
+
+    @pytest.mark.parametrize("use_kernel", [True, False],
+                             ids=["kernel", "reference"])
+    @pytest.mark.parametrize("entry", ["paged", "packed"])
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("dtype", ["bf16", "int8"])
+    def test_layer_index_matches_layer_operand_bitwise(self, dtype, layer,
+                                                       entry, use_kernel):
+        q, pool, scales, ids, tbl, pos = _whole_pool_case(dtype)
+        kw = dict(block_kv=BLK, partials=True, use_kernel=use_kernel,
+                  interpret=True)
+        if scales is not None:
+            kw.update(k_scales=scales[layer, 0], v_scales=scales[layer, 1])
+        if entry == "paged":
+            fn, sel = ops.flash_decode_paged, jnp.asarray(ids)
+        else:
+            fn = ops.flash_decode_packed_paged
+            sel = jnp.asarray(pack_decode_items(ids, block=BLK).flat())
+        args = (sel, jnp.asarray(tbl), jnp.asarray(pos))
+        per_layer = fn(q, pool[layer, 0], pool[layer, 1], *args, **kw)
+        whole = fn(q, pool, None, *args, layer=jnp.int32(layer), **kw)
+        assert float(jnp.abs(per_layer[2]).max()) > 0.0
+        for a, b in zip(per_layer, whole):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("head_dim,whole", [(64, False), (128, True)])
+    def test_decode_step_operand_follows_head_dim(self, monkeypatch,
+                                                  head_dim, whole):
+        """``decode_step_paged`` hands the kernel the whole pool and the
+        layer index only where head_dim is whole 128-lane tiles; at 64 it
+        keeps the per-layer K and V operands."""
+        from repro.models import transformer as tfm
+
+        seen = []
+        real = ops.flash_decode_packed_paged
+
+        def spy(q, k_pool, v_pool, *a, **k):
+            seen.append((k_pool.ndim, v_pool is None, "layer" in k))
+            return real(q, k_pool, v_pool, *a, **k)
+
+        monkeypatch.setattr(ops, "flash_decode_packed_paged", spy)
+        cfg = tfm.TransformerConfig(num_layers=2, d_model=64, num_heads=4,
+                                    num_kv_heads=2, head_dim=head_dim,
+                                    d_ff=128, vocab_size=64, block_q=8,
+                                    block_kv=8, layer_loop="scan")
+        params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+        pool = jnp.zeros((2, 2, 5, 2, 8, head_dim), jnp.bfloat16)
+        ids = np.zeros((2, 2, 1), np.int32)
+        items = pack_decode_items(ids, block=8).flat()
+        logits, _ = tfm.decode_step_paged(
+            params, pool, jnp.zeros((2,), jnp.int32), jnp.int32(3),
+            jnp.asarray([[0, -1], [1, -1]], jnp.int32), cfg,
+            packed_items=jnp.asarray(np.stack([items, items])))
+        assert np.isfinite(np.asarray(logits)).all()
+        assert seen == [(6, True, True) if whole else (4, False, False)]
 
 
 class TestPackedGreedyParity:

@@ -206,15 +206,29 @@ def _pool_copies(compiled, shape):
 
 @pytest.mark.parametrize("width", list(STEP_CFGS))
 def test_paged_decode_step_updates_pool_in_place(paged_step, width):
-    """The pool is the layer loop's carry: the step holds about two layers
-    of temporaries (at head_dim 64, the kernel operand's pads), not a
-    second pool, and never copies the pool whole."""
+    """The pool is the layer loop's carry: the step holds at most about
+    two layers of temporaries, not a second pool, and never copies the
+    pool whole.  Two layers is what head_dim 64 needs: its kernel still
+    takes layer ``l``'s K and V sliced out of the pool and padded to 128
+    lanes."""
     shape, layer_bytes, decode = paged_step(width, "decode")
     ma = decode.memory_analysis()
     assert ma.alias_size_in_bytes >= layer_bytes * shape[0]
     assert ma.temp_size_in_bytes < 2.5 * layer_bytes, ma.temp_size_in_bytes
     assert "tpu_custom_call" in decode.as_text()
     assert not _pool_copies(decode, shape)
+
+
+def test_paged_decode_step_reads_layer_in_place(paged_step):
+    """At head_dim 128 the kernel copies its tiles from the carried pool
+    at the layer index: the step slices no layer's K or V out of the pool
+    and holds well under one layer of temporaries."""
+    shape, layer_bytes, decode = paged_step("yi6b", "decode")
+    temp = decode.memory_analysis().temp_size_in_bytes
+    assert temp < layer_bytes / 64, temp
+    layer_of_pool = rf"= bf16\[1,1,{shape[2]},[^\]]*\]\S* dynamic-slice\("
+    assert not [ln for ln in decode.as_text().splitlines()
+                if re.search(layer_of_pool, ln)]
 
 
 @pytest.mark.parametrize("width", list(STEP_CFGS))
