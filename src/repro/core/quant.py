@@ -137,16 +137,6 @@ def insert_token_requant(blk: jnp.ndarray, scale: jnp.ndarray,
     return codes, new_scale
 
 
-def quantize_seq_cache(cache: jnp.ndarray, block: int, kv_dtype: str):
-    """Quantize a contiguous cache ``[L, 2, B, Hkv, Smax, Dh]`` (Smax a
-    block multiple) -> ``(codes, scales [L, 2, B, Hkv, Smax//block])``."""
-    L, two, B, hkv, smax, dh = cache.shape
-    nb = smax // block
-    tiles = cache.reshape(L, two, B, hkv, nb, block, dh)
-    codes, scales = quantize_tiles(tiles, kv_dtype)
-    return codes.reshape(cache.shape), scales
-
-
 def quantize_pool_blocks(blocks: jnp.ndarray, kv_dtype: str):
     """Quantize pool-layout blocks ``[..., Hkv, block, Dh]`` -> codes of
     the same shape + scales ``[..., Hkv]`` (one per (block, kv-head))."""
@@ -172,7 +162,6 @@ __all__ = [
     "kv_cache_dtype",
     "kv_dtype_bytes",
     "quantize_pool_blocks",
-    "quantize_seq_cache",
     "quantize_tiles",
     "roundtrip_error_bound",
 ]

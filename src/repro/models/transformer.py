@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from repro.attention.flash_scan import flash_scan_attention
 from repro.attention.worklist_jnp import (
     batched_worklist_attention,
-    worklist_attention,
     worklist_attention_paged,
 )
 from repro.attention.dense import attention_maps, decode_attention_ref
@@ -289,7 +288,9 @@ def loss_fn(params, batch, cfg: TransformerConfig, *, remat: bool = False):
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None):
-    """Contiguous KV cache [L, 2, B, Hkv, Smax, Dh]."""
+    """Contiguous KV cache [L, 2, B, Hkv, Smax, Dh] for
+    :func:`decode_step`; the pod dry-run (``launch/steps.py``) is its only
+    caller — the serving engine keeps its KV in the paged pool."""
     dtype = dtype or cfg.dtype
     return jnp.zeros(
         (cfg.num_layers, 2, batch, cfg.num_kv_heads, max_len, cfg.head_dim_),
@@ -324,7 +325,8 @@ def init_paged_scales(cfg: TransformerConfig, num_blocks: int):
 def init_cache_scales(cfg: TransformerConfig, batch: int, max_len: int,
                       block: int):
     """Dequant scales for a quantized contiguous cache:
-    ``[L, 2, B, Hkv, Smax/block]`` f32 (``max_len`` a block multiple)."""
+    ``[L, 2, B, Hkv, Smax/block]`` f32 (``max_len`` a block multiple).
+    Like :func:`init_cache`, only the pod dry-run calls it."""
     assert max_len % block == 0, "quantized contiguous cache needs " \
         "max_len % block == 0 (scales are per block tile)"
     return jnp.ones((cfg.num_layers, 2, batch, cfg.num_kv_heads,
@@ -334,14 +336,12 @@ def init_cache_scales(cfg: TransformerConfig, batch: int, max_len: int,
 def scatter_seq_cache_paged(pool, seq_cache, table, *, scales=None,
                             kv_dtype: str = "bf16"):
     """Land a whole prefilled sequence cache in the pool (monolithic
-    prefill's paged merge — the block-scatter twin of the contiguous
-    ``dynamic_update_slice`` slot insert).
+    prefill's merge: one block scatter through the table).
 
     ``seq_cache [L, 2, 1, Hkv, S, Dh]`` with ``S`` a block multiple;
     ``table [T]`` int32 logical -> pool block (-1 pad).  Blocks past the
     mapped prefix (bucket padding) scatter into the trash block (the
-    pool's last physical block) — the paged analogue of the stale padded
-    rows the contiguous layout masks by position.
+    pool's last physical block).
 
     Quantized pool (DESIGN.md §2.12): pass ``scales [L, 2, N, Hkv]`` and
     the storage ``kv_dtype`` — each block tile quantizes AT SCATTER TIME
@@ -445,7 +445,11 @@ def decode_step(params, cache, token, pos, cfg: TransformerConfig, *,
                 cache_len: int | jnp.ndarray | None = None,
                 active=None, attn_override=None,
                 scales=None, kv_dtype: str = "bf16"):
-    """One decode step.
+    """One decode step over a contiguous cache.
+
+    The serving engine decodes with :func:`decode_step_paged`; this step's
+    only remaining caller is the pod dry-run (``launch/steps.py``), whose
+    shard_map islands run the contiguous flash-decode kernel.
 
     token [B] int32; pos scalar OR [B] int32 (current position per
     sequence, 0-based — per-sequence positions enable continuous batching).
@@ -663,95 +667,6 @@ def _chunk_attend(q, k, v, valid, cfg: TransformerConfig):
     return o.reshape(B, H, C, dh).astype(q.dtype)
 
 
-def prefill_chunk(params, cache, tokens, slot, q_offset,
-                  cfg: TransformerConfig, *,
-                  kv_len=None, sparse_items=None, last_index=None):
-    """Partial prefill: attend one chunk of queries against the KV prefix
-    already resident in the slot cache, writing the chunk's K/V at a traced
-    offset (the chunked-prefill half of the serving tick, DESIGN.md §2.6).
-
-    tokens [1, C] int32 (one sequence; C is the chunk compile bucket);
-    cache [L, 2, B, Hkv, Smax, Dh] — the engine's FULL slot cache, threaded
-    through and updated in place (donation-friendly);
-    ``slot`` / ``q_offset`` / ``kv_len`` / ``last_index`` are traced scalars:
-    one compile serves every slot, chunk offset, and real chunk length.
-    ``kv_len`` = q_offset + real_chunk_len (cache positions >= kv_len are
-    masked; defaults to q_offset + C).  ``sparse_items``: [L, P, 7] chunk
-    work-lists (chunk-local q_blk, GLOBAL kv_blk — from
-    ``core.worklist.chunk_items``) entering as DATA, or None for dense
-    masked attention.  Returns (logits [1, V] read at chunk-local
-    ``last_index``, new cache).
-    """
-    B, C = tokens.shape
-    smax = cache.shape[4]
-    slot = jnp.asarray(slot, jnp.int32)
-    q_offset = jnp.asarray(q_offset, jnp.int32)
-    kv_len = (q_offset + C if kv_len is None
-              else jnp.asarray(kv_len, jnp.int32))
-    positions = q_offset + jnp.arange(C)
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = constrain(x, "batch", None, None)
-
-    def layer(x, lp, layer_cache, l, items_l):
-        h = common.rmsnorm(x, lp["ln1"])
-        q, k, v = _qkv(h, lp["attn"], cfg, positions)
-        q = constrain(q, "batch", "model", None, None)
-        upd = lambda c, new: jax.lax.dynamic_update_slice(
-            c, new.astype(c.dtype), (slot, 0, q_offset, 0))
-        kc = upd(layer_cache[0], k[0][None])
-        vc = upd(layer_cache[1], v[0][None])
-        ks = jax.lax.dynamic_slice_in_dim(kc, slot, 1, axis=0)
-        vs = jax.lax.dynamic_slice_in_dim(vc, slot, 1, axis=0)
-        window = _window_of(cfg, l)
-        if items_l is not None:
-            o = worklist_attention(
-                q[0], ks[0], vs[0], items_l,
-                block_q=cfg.block_q, block_kv=cfg.block_kv,
-                q_offset=q_offset, kv_len=kv_len)[None]
-        else:
-            kpos = jnp.arange(smax)
-            valid = ((kpos[None, :] <= positions[:, None])
-                     & (kpos[None, :] < kv_len))          # [C, Smax]
-            if window is not None:
-                valid = valid & (kpos[None, :] > positions[:, None] - window)
-            o = _chunk_attend(q, ks, vs, valid[None, None], cfg)
-        o = common.merge_heads(o)
-        x = x + jnp.einsum("bsf,fd->bsd", o, lp["attn"]["wo"])
-        h2 = common.rmsnorm(x, lp["ln2"])
-        x = x + _ffn(h2, lp, cfg)
-        return x, jnp.stack([kc, vc])
-
-    if cfg.loop_mode == "scan":
-        if sparse_items is None:
-            def body(x, scan_in):
-                lp, layer_cache = scan_in
-                x, new_c = layer(x, lp, layer_cache, 0, None)
-                return x, new_c
-            x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-        else:
-            def body(x, scan_in):
-                lp, layer_cache, items_l = scan_in
-                x, new_c = layer(x, lp, layer_cache, 0, items_l)
-                return x, new_c
-            x, new_cache = jax.lax.scan(
-                body, x, (params["layers"], cache, jnp.asarray(sparse_items)))
-    else:
-        new_layers = []
-        for l in range(cfg.num_layers):
-            items_l = (None if sparse_items is None
-                       else jnp.asarray(sparse_items[l]))
-            x, nc = layer(x, params["layers"][l], cache[l], l, items_l)
-            new_layers.append(nc)
-        new_cache = jnp.stack(new_layers)
-    if last_index is None:
-        x_last = x[:, -1:, :]
-    else:
-        x_last = jax.lax.dynamic_slice_in_dim(
-            x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
-    logits = _logits(x_last, params, cfg)[:, 0]
-    return logits, new_cache
-
-
 def _paged_layer_loop(layer, cfg: TransformerConfig, params, x, pool,
                       scales, sel):
     """Run ``layer(x, pool, scales, lp, l, window, items_l) -> (x, pool,
@@ -797,9 +712,9 @@ def prefill_chunk_paged(params, pool, tokens, table, q_offset,
     table is data, so one compile per chunk bucket serves every sequence,
     offset, and block placement.  Sparse items execute via
     ``worklist_attention_paged`` (each item's tile gathered from the pool);
-    dense chunks gather the table's blocks into a contiguous [Smax] view —
-    O(one sequence), exactly the staging traffic of the contiguous path.
-    Returns (logits [1, V] at chunk-local ``last_index``, new pool).
+    dense chunks gather the table's blocks into one [Smax] view — O(one
+    sequence).  Returns (logits [1, V] at chunk-local ``last_index``, new
+    pool).
 
     The whole pool is the layer loop's carry: layer ``l`` scatters its
     blocks into ``pool[l]`` and reads ``pool[l]`` back for its attention,
@@ -950,8 +865,8 @@ def decode_step_paged(params, pool, token, pos, table,
     ``packed_items`` (mutually exclusive): ``[L, Lb, DEC_FIELDS]``
     cost-packed ragged decode worklists per layer (DESIGN.md §2.8, kv
     blocks LOGICAL).  None for both = dense decode over the resident
-    prefix (a gathered contiguous view — the contiguous baseline's math
-    bit-for-bit).  Returns (logits [B, V], new pool).
+    prefix (gathered through the table into one per-sequence view).
+    Returns (logits [B, V], new pool).
 
     The decode kernel's operand follows head_dim: at a multiple of 128 it
     gets the whole carried pool and the layer index and copies its tiles
@@ -1152,8 +1067,8 @@ def permute_cache_kv_heads(cache, kv_perm):
     """Re-permute the kv-head axis of a resident KV cache for a plan-epoch
     swap.
 
-    ``cache``: contiguous ``[L, 2, B, Hkv, Smax, Dh]`` or paged pool
-    ``[L, 2, N, Hkv, block, Dh]`` — any layout with kv heads on axis 3.
+    ``cache``: the paged pool ``[L, 2, N, Hkv, block, Dh]`` (any tensor
+    with kv heads on axis 3).
     ``kv_perm [L, Hkv]``: per-layer delta shuffle from
     :meth:`repro.core.planner.PlanDelta.kv_perm_table` (new slot ->
     previous slot).  Weights permuted by the delta expect the cache's
@@ -1167,17 +1082,15 @@ def permute_cache_kv_heads(cache, kv_perm):
 def permute_cache_scales(scales, kv_perm):
     """Scales twin of :func:`permute_cache_kv_heads` (DESIGN.md §2.12):
     the same per-layer kv-head gather applied to the dequant-scales
-    tensor — paged ``[L, 2, N, Hkv]`` or contiguous ``[L, 2, B, Hkv,
-    Smax/block]``, kv heads on axis 3 in both — so an epoch swap moves
-    every block's scale with its codes in the same jit."""
+    tensor ``[L, 2, N, Hkv]`` (kv heads on axis 3) — so an epoch swap
+    moves every block's scale with its codes in the same jit."""
     idx = jnp.asarray(kv_perm, jnp.int32).reshape(
-        (scales.shape[0], 1, 1, scales.shape[3])
-        + (1,) * (scales.ndim - 4))
+        scales.shape[0], 1, 1, scales.shape[3])
     return jnp.take_along_axis(scales, idx, axis=3)
 
 
 def decode_telemetry(params, cache, token, pos, cfg: TransformerConfig, *,
-                     block_ids, cache_len, table=None, scales=None,
+                     block_ids, cache_len, table, scales=None,
                      with_health=False):
     """Quest-bound estimate of the recovery each head's selection realizes.
 
@@ -1199,16 +1112,15 @@ def decode_telemetry(params, cache, token, pos, cfg: TransformerConfig, *,
     probe is a separate un-donated jit the engine runs every
     ``telemetry_every`` ticks.
 
-    ``cache``: contiguous ``[L, 2, B, Hkv, Smax, Dh]``, or the paged pool
-    with ``table [B, T]`` (logical -> pool block, -1 pad).  ``block_ids``:
+    ``cache``: the paged pool ``[L, 2, N, Hkv, block, Dh]``, read through
+    ``table [B, T]`` (logical -> pool block, -1 pad).  ``block_ids``:
     ``[L, B, Hkv, nb]`` LOGICAL selections, -1 pad — exactly the engine's
     position-aware decode tables.  Returns ``(rec, frac)`` both
     ``[L, B, H]`` float32 (rows with ``cache_len == 0`` return garbage the
     caller must mask — the engine filters to active slots).
 
-    Quantized cache (DESIGN.md §2.12): pass ``scales`` (paged ``[L, 2, N,
-    Hkv]`` / contiguous ``[L, 2, B, Hkv, Smax/block]``) — the probe's
-    Quest summaries and its dense estimator forward both run on
+    Quantized cache (DESIGN.md §2.12): pass ``scales [L, 2, N, Hkv]`` —
+    the probe's Quest summaries and its dense estimator forward both run on
     DEQUANTIZED values, so realized-recovery estimates (and hence drift /
     replans) reflect what decode attention actually computes.
 
@@ -1221,17 +1133,13 @@ def decode_telemetry(params, cache, token, pos, cfg: TransformerConfig, *,
     B = token.shape[0]
     hkv, dh = cfg.num_kv_heads, cfg.head_dim_
     n_rep = cfg.num_heads // hkv
-    paged = table is not None
-    blk = cache.shape[4] if paged else cfg.block_kv
+    blk = cache.shape[4]
     x = jnp.take(params["embed"], token[:, None], axis=0)  # [B, 1, d]
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     clen = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (B,))
-    if paged:
-        tbl = jnp.asarray(table, jnp.int32)
-        skv = tbl.shape[1] * blk
-    else:
-        skv = cache.shape[4]
-    nkvb = -(-skv // blk)
+    tbl = jnp.asarray(table, jnp.int32)
+    nkvb = tbl.shape[1]
+    skv = nkvb * blk
     kpos = jnp.arange(nkvb * blk)
     valid = kpos[None] < clen[:, None]                    # [B, Skv]
     ntok = jnp.clip(clen[:, None] - jnp.arange(nkvb)[None] * blk,
@@ -1247,38 +1155,22 @@ def decode_telemetry(params, cache, token, pos, cfg: TransformerConfig, *,
                                cfg.num_heads)
         rope = lambda t, p: apply_rope(t, p[None], cfg.rope_theta)
         q = jax.vmap(rope)(q, pos_arr)                    # [B, H, 1, Dh]
-        if paged:
-            if layer_scales is None:
-                view = lambda c: jnp.moveaxis(
-                    jnp.take(c, jnp.maximum(tbl, 0), axis=0), 1, 2
-                ).reshape(B, hkv, skv, dh)
-                kc, vc = view(layer_cache[0]), view(layer_cache[1])
-            else:
-                # dequantized view: scales gather through the same table
-                # (the probe is un-donated and O(B x resident) already)
-                view = lambda c, s: (
-                    jnp.moveaxis(jnp.take(c, jnp.maximum(tbl, 0), axis=0),
-                                 1, 2).astype(jnp.float32)
-                    * jnp.moveaxis(jnp.take(s, jnp.maximum(tbl, 0), axis=0),
-                                   1, 2)[..., None, None]
-                ).reshape(B, hkv, skv, dh)
-                kc = view(layer_cache[0], layer_scales[0])
-                vc = view(layer_cache[1], layer_scales[1])
+        if layer_scales is None:
+            view = lambda c: jnp.moveaxis(
+                jnp.take(c, jnp.maximum(tbl, 0), axis=0), 1, 2
+            ).reshape(B, hkv, skv, dh)
+            kc, vc = view(layer_cache[0]), view(layer_cache[1])
         else:
-            # a contiguous cache's Smax need not be a block multiple: pad
-            # to the block grid (pads sit past every clen, so the valid
-            # mask — already sized nkvb*blk — excludes them everywhere)
-            if layer_scales is not None:
-                deq = lambda c, s: (
-                    c.reshape(B, hkv, -1, blk, dh).astype(jnp.float32)
-                    * s[..., None, None]).reshape(B, hkv, skv, dh)
-                layer_cache = jnp.stack(
-                    [deq(layer_cache[0], layer_scales[0]),
-                     deq(layer_cache[1], layer_scales[1])])
-            pad = nkvb * blk - skv
-            padkv = lambda c: (jnp.pad(c, ((0, 0), (0, 0), (0, pad),
-                                           (0, 0))) if pad else c)
-            kc, vc = padkv(layer_cache[0]), padkv(layer_cache[1])
+            # dequantized view: scales gather through the same table (the
+            # probe is un-donated and O(B x resident) already)
+            view = lambda c, s: (
+                jnp.moveaxis(jnp.take(c, jnp.maximum(tbl, 0), axis=0),
+                             1, 2).astype(jnp.float32)
+                * jnp.moveaxis(jnp.take(s, jnp.maximum(tbl, 0), axis=0),
+                               1, 2)[..., None, None]
+            ).reshape(B, hkv, skv, dh)
+            kc = view(layer_cache[0], layer_scales[0])
+            vc = view(layer_cache[1], layer_scales[1])
         # -- per-block Quest summaries over the RESIDENT prefix ------------
         kb = kc.reshape(B, hkv, nkvb, blk, dh)
         vmask = valid.reshape(B, 1, nkvb, blk, 1)

@@ -1,5 +1,5 @@
-"""Serving: S-HPLB engine, shard_map attention islands, paged/contiguous
-KV cache, continuous batching, sampling."""
+"""Serving: S-HPLB engine, shard_map attention islands, paged KV cache,
+continuous batching, sampling."""
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.faults import (
     EpochSwapError,
@@ -11,7 +11,7 @@ from repro.serving.faults import (
     IntegrityError,
     TransferError,
 )
-from repro.serving.kv_cache import BlockAllocator, PagedKVCache, SlotCache
+from repro.serving.kv_cache import BlockAllocator, PagedKVCache
 from repro.serving.sampler import SamplingParams, sample
 from repro.serving.snapshot import latest_snapshot, restore_serving, save_serving
 from repro.serving.scheduler import (

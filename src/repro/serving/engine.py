@@ -4,11 +4,9 @@ continuous batching, sampling.
 The engine owns:
 - the offline artifacts: sparsity profile -> HPLB plan (budgets +
   head permutation) -> per-layer work-lists / decode block budgets;
-- the device state: HPLB-permuted params, and the KV cache in one of two
-  layouts (``cache_layout``): the default PAGED block pool
-  [L, 2, N+1, Hkv, block, Dh] addressed through per-sequence block tables
-  (token-granular HBM — DESIGN.md §2.7), or the legacy CONTIGUOUS slot
-  cache [L, 2, B_slots, Hkv, Smax, Dh] kept as the parity baseline;
+- the device state: HPLB-permuted params, and the KV cache: a paged block
+  pool [L, 2, N+1, Hkv, block, Dh] addressed through per-sequence block
+  tables (token-granular HBM — DESIGN.md §2.7);
 - the jitted step functions (prefill with sparse work-lists; decode with
   budgeted block streams; per-sequence positions for continuous batching).
 
@@ -34,7 +32,6 @@ a production mesh the same engine code paths lower with shard_map islands
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from collections import OrderedDict
 
@@ -105,13 +102,12 @@ class EngineConfig:
     # benchmark baseline).
     prefill_mode: str = "chunked"    # "chunked" | "monolithic"
     prefill_chunk_tokens: int = 256  # per-tick token budget (chunk cap)
-    # device KV layout: "paged" (block pool + per-sequence block tables —
-    # HBM scales with resident tokens, admission is block-granular) or
-    # "contiguous" (every sequence reserves a max_seq_len slot; the parity
-    # baseline).  DESIGN.md §2.7.
+    # the KV cache is a paged block pool with per-sequence block tables
+    # (DESIGN.md §2.7); "paged" is the only accepted value.  The field
+    # stays because the benchmark configs still name it.
     cache_layout: str = "paged"
-    # paged pool size in blocks; None = num_slots * max_seq_len / block
-    # (byte-parity with the contiguous layout).  Smaller pools trade
+    # pool size in blocks; None = num_slots * max_seq_len / block (every
+    # slot can hold a max_seq_len sequence).  Smaller pools trade
     # worst-case capacity for HBM; admission guards via reservations.
     num_kv_blocks: int | None = None
     # KV storage dtype (DESIGN.md §2.12): "bf16" keeps the full-precision
@@ -160,7 +156,7 @@ class EngineConfig:
     # owns a contiguous block-id range, decode runs one partial attention
     # pass per stripe and merges (out, m, l) — the single-host emulation of
     # the 2D (model x seq) mesh's per-device islands.  1 = the 1D head-
-    # parallel path, bitwise-unchanged.  Requires cache_layout="paged".
+    # parallel path, bitwise-unchanged.
     seq_shards: int = 1
     # -- fault tolerance (DESIGN.md §2.13) --------------------------------
     # per-tick numerical sentinels: after every prefill/decode step the
@@ -186,8 +182,7 @@ class EngineConfig:
     # longest cached prefix for free (refcounted block sharing in the
     # paged pool; prefill starts at the divergence block) and unreferenced
     # subtrees LRU-evict under pool pressure BEFORE preemption kicks in.
-    # Requires cache_layout="paged".  Greedy decoding stays bitwise
-    # identical to prefix_cache=False.
+    # Greedy decoding stays bitwise identical to prefix_cache=False.
     prefix_cache: bool = False
 
 
@@ -197,6 +192,10 @@ class Engine:
     def __init__(self, cfg: TransformerConfig, params, engine_cfg: EngineConfig,
                  profile: HeadSparsityProfile | None = None,
                  injector: FaultInjector | None = None):
+        if engine_cfg.cache_layout != "paged":
+            raise ValueError(
+                f"cache_layout {engine_cfg.cache_layout!r}: the contiguous "
+                "slot cache was removed; the paged pool is the only layout")
         # attention tile sizes MUST match the work-list granularity: items
         # address (head, q_blk, kv_blk) tiles in units of ``engine_cfg.block``,
         # and a kernel running wider tiles slices/writes past the buffer —
@@ -271,72 +270,39 @@ class Engine:
             * quant.kv_dtype_bytes(engine_cfg.kv_dtype,
                                    block=engine_cfg.block,
                                    head_dim=cfg.head_dim_))
-        if engine_cfg.seq_shards > 1:
-            assert engine_cfg.cache_layout == "paged", \
-                "seq_shards > 1 needs cache_layout='paged' (stripes own " \
-                "contiguous ranges of the block pool)"
-        if engine_cfg.prefix_cache:
-            assert engine_cfg.cache_layout == "paged", \
-                "prefix_cache needs cache_layout='paged' (sharing is " \
-                "block-table aliasing in the pool)"
         # radix prefix cache (DESIGN.md §2.14): built by make_batcher so
         # it shares the batcher's allocator wiring; None = sharing off
         self.prefix = None
-        if engine_cfg.cache_layout == "paged":
-            assert engine_cfg.max_seq_len % engine_cfg.block == 0, \
-                "paged layout needs max_seq_len % block == 0"
-            nblocks = (engine_cfg.num_kv_blocks
-                       or engine_cfg.num_slots
-                       * (engine_cfg.max_seq_len // engine_cfg.block))
-            # stripes must tile the pool exactly: round the usable block
-            # count UP to a seq_shards multiple (never down — capacity is
-            # an admission guarantee)
-            ss = engine_cfg.seq_shards
-            nblocks = -(-nblocks // ss) * ss
-            self.kv = PagedKVCache(
-                lambda n: tfm.init_paged_cache(
-                    cfg, n, engine_cfg.block,
-                    dtype=quant.kv_cache_dtype(engine_cfg.kv_dtype)),
-                num_blocks=nblocks, block=engine_cfg.block,
-                table_width=engine_cfg.max_seq_len // engine_cfg.block,
-                host_blocks=engine_cfg.host_swap_blocks,
-                stripes=engine_cfg.seq_shards,
-                make_scales_fn=((lambda n: tfm.init_paged_scales(cfg, n))
-                                if self.quantized else None))
-            # the allocator fires the admission_alloc seam mid-_grow
-            self.kv.alloc.injector = injector
-            # self.cache is the LIVE pool threaded through the jitted
-            # steps (donated); self.kv keeps the allocator/tables and is
-            # re-pointed at the new buffer after every step.  Quantized:
-            # the donated unit is the (codes, scales) PAIR — every step
-            # that moves a block moves its scale in the same program.
-            self.cache = ((self.kv.pool, self.kv.scales) if self.quantized
-                          else self.kv.pool)
-        else:
-            assert engine_cfg.cache_layout == "contiguous", \
-                f"unknown cache_layout {engine_cfg.cache_layout!r}"
-            self.kv = None
-            if self.quantized:
-                # contiguous quantized: scale tiles are cfg.block_kv wide
-                # inside decode_step but ecfg.block wide at prefill
-                # quantize — the two grids must be THE SAME grid
-                assert engine_cfg.block == cfg.block_kv, \
-                    "quantized contiguous layout needs engine block == " \
-                    "model block_kv (one scale grid)"
-                assert engine_cfg.max_seq_len % engine_cfg.block == 0, \
-                    "quantized contiguous layout needs max_seq_len % " \
-                    "block == 0"
-                self.cache = (
-                    tfm.init_cache(
-                        cfg, engine_cfg.num_slots, engine_cfg.max_seq_len,
-                        dtype=quant.kv_cache_dtype(engine_cfg.kv_dtype)),
-                    tfm.init_cache_scales(cfg, engine_cfg.num_slots,
-                                          engine_cfg.max_seq_len,
-                                          engine_cfg.block))
-            else:
-                self.cache = tfm.init_cache(cfg, engine_cfg.num_slots,
-                                            engine_cfg.max_seq_len)
-        self._batcher = None   # bound by make_batcher (paged table lookups)
+        assert engine_cfg.max_seq_len % engine_cfg.block == 0, \
+            "paged layout needs max_seq_len % block == 0"
+        nblocks = (engine_cfg.num_kv_blocks
+                   or engine_cfg.num_slots
+                   * (engine_cfg.max_seq_len // engine_cfg.block))
+        # stripes must tile the pool exactly: round the usable block count
+        # UP to a seq_shards multiple (never down — capacity is an
+        # admission guarantee)
+        ss = engine_cfg.seq_shards
+        nblocks = -(-nblocks // ss) * ss
+        self.kv = PagedKVCache(
+            lambda n: tfm.init_paged_cache(
+                cfg, n, engine_cfg.block,
+                dtype=quant.kv_cache_dtype(engine_cfg.kv_dtype)),
+            num_blocks=nblocks, block=engine_cfg.block,
+            table_width=engine_cfg.max_seq_len // engine_cfg.block,
+            host_blocks=engine_cfg.host_swap_blocks,
+            stripes=engine_cfg.seq_shards,
+            make_scales_fn=((lambda n: tfm.init_paged_scales(cfg, n))
+                            if self.quantized else None))
+        # the allocator fires the admission_alloc seam mid-_grow
+        self.kv.alloc.injector = injector
+        # self.cache is the LIVE pool threaded through the jitted steps
+        # (donated); self.kv keeps the allocator/tables and is re-pointed
+        # at the new buffer after every step.  Quantized: the donated unit
+        # is the (codes, scales) PAIR — every step that moves a block
+        # moves its scale in the same program.
+        self.cache = ((self.kv.pool, self.kv.scales) if self.quantized
+                      else self.kv.pool)
+        self._batcher = None   # bound by make_batcher (table lookups)
         # prefill compiled-step memos: LRU-bounded OrderedDicts (PR-4's
         # packed-plan discipline) — monolithic prefill BAKES the epoch's
         # work-lists into the program, so its key carries the epoch and an
@@ -345,21 +311,11 @@ class Engine:
         # chunked prefill: one compile per chunk bucket (pow2 from block up
         # to prefill_chunk_tokens); chunk work-lists enter as DATA padded to
         # a per-bucket item cap, so chunk offsets (and epochs) never
-        # recompile.  Chunks accumulate into a single-sequence STAGING cache
-        # (the scheduler holds at most one partially-prefilled sequence)
-        # merged into the slot cache once at the final chunk — per-chunk
-        # cache traffic is O(staging), not O(all slots), and decode never
-        # sees a mid-prefill slot.
+        # recompile.  Each chunk scatters into its sequence's own pool
+        # blocks, which decode never reads before the final chunk.
         self._prefill_chunk_jit: OrderedDict = OrderedDict()
         self._chunk_cap: dict[tuple, int] = {}
         self._chunk_wl_cache: dict[tuple, np.ndarray] = {}
-        if engine_cfg.prefill_mode == "chunked":
-            # chunk geometry (offsets, buckets, work-list windows) counts
-            # in whole cache blocks; monolithic mode has no such constraint
-            assert engine_cfg.max_seq_len % engine_cfg.block == 0, \
-                "chunked prefill needs max_seq_len % block == 0"
-        self._staging = None  # allocated on first chunked prefill
-        self._merge_jit = None
         self._decode_jit = None
         # cost-packed ragged decode (DESIGN.md §2.8): plans (packed item
         # tables) are memoized by the tick's per-slot BLOCK-COUNT signature
@@ -395,8 +351,8 @@ class Engine:
         # slot h) so a host copy taken under one epoch can be re-arranged
         # EXACTLY ONCE at swap-in, however many epoch swaps passed.
         self._host_swaps: dict[int, dict] = {}
-        self._swap_gather_jit: dict[tuple, object] = {}
-        self._swap_scatter_jit: dict[tuple, object] = {}
+        self._swap_gather_jit: dict[int, object] = {}
+        self._swap_scatter_jit: dict[int, object] = {}
         self._kv_arrange = np.tile(np.arange(cfg.num_kv_heads),
                                    (cfg.num_layers, 1))
         self.swap_stats = {"swapped_out": 0, "swapped_in": 0,
@@ -408,7 +364,7 @@ class Engine:
         self._epoch_stats: dict[int, dict] = {0: self._fresh_epoch_stats()}
         self._last_drift: dict | None = None
         self.replans = 0
-        # the slot cache is exclusively engine-owned and threaded through
+        # the pool is exclusively engine-owned and threaded through
         # every jitted step, so it is always donated: XLA CPU aliases
         # donated buffers since jax 0.4.x (measured ~200x on the in-place
         # cache update), and backends without aliasing degrade to a copy
@@ -773,8 +729,7 @@ class Engine:
         pos_all[list(slots)] = positions
         sig = self._nb_sig(pos_all)
         stripe_of = table = None
-        if self.paged and (self.ecfg.seq_shards > 1
-                           or self.prefix is not None):
+        if self.ecfg.seq_shards > 1 or self.prefix is not None:
             # best-effort: if a slot maps a NEW block before the next tick
             # the stripe/sharing signature shifts and this plan simply
             # goes unused (the key carries the placement — never a wrong
@@ -881,39 +836,29 @@ class Engine:
         fn = self._telemetry_jit.get(nb_width)
         if fn is None:
             qz = self.quantized
-            if self.paged:
-                def telemetry_probe(params, cache, token, pos, table, bids,
-                                    clen):
-                    pool, scales = cache if qz else (cache, None)
-                    return tfm.decode_telemetry(
-                        params, pool, token, pos, self.cfg,
-                        block_ids=bids, cache_len=clen, table=table,
-                        scales=scales, with_health=True)
-            else:
-                def telemetry_probe(params, cache, token, pos, bids, clen):
-                    c, scales = cache if qz else (cache, None)
-                    return tfm.decode_telemetry(
-                        params, c, token, pos, self.cfg,
-                        block_ids=bids, cache_len=clen, scales=scales,
-                        with_health=True)
+
+            def telemetry_probe(params, cache, token, pos, table, bids,
+                                clen):
+                pool, scales = cache if qz else (cache, None)
+                return tfm.decode_telemetry(
+                    params, pool, token, pos, self.cfg,
+                    block_ids=bids, cache_len=clen, table=table,
+                    scales=scales, with_health=True)
             # reads the live cache: never donated
             fn = jax.jit(telemetry_probe)
             self._telemetry_jit[nb_width] = fn
         return fn
 
-    def _dispatch_telemetry(self, slots, tok_all, pos_all, bids,
-                            table=None):
+    def _dispatch_telemetry(self, slots, tok_all, pos_all, bids, table):
         """Dispatch the recovery probe against the PRE-STEP resident cache
         (before the donating decode step — stream order keeps the read
         safe) and return the pending (rec, frac, rows); the caller folds
         them in AFTER dispatching the decode step, so probe + step overlap
         host planning exactly like the packed-plan prefetch."""
         fn = self._telemetry_fn(bids.shape[-1])
-        args = (self.params, self.cache, jnp.asarray(tok_all),
-                jnp.asarray(pos_all))
-        if self.paged:
-            args += (jnp.asarray(table),)
-        rec, frac, fin = fn(*args, jnp.asarray(bids), jnp.asarray(pos_all))
+        rec, frac, fin = fn(self.params, self.cache, jnp.asarray(tok_all),
+                            jnp.asarray(pos_all), jnp.asarray(table),
+                            jnp.asarray(bids), jnp.asarray(pos_all))
         return rec, frac, fin, list(slots)
 
     def _fold_telemetry(self, pending) -> None:
@@ -1116,21 +1061,16 @@ class Engine:
                 kv_permute, donate_argnums=(0,) if self._donate else ())
         return self._kv_permute_jit
 
-    # -- paged-layout plumbing ----------------------------------------------
-    @property
-    def paged(self) -> bool:
-        return self.kv is not None
-
+    # -- pool plumbing -------------------------------------------------------
     def _set_cache(self, cache) -> None:
         """Adopt the buffer a jitted step returned; keep the PagedKVCache
         handle pointing at the live pool (and, quantized, the live
         scales — pool_bytes() charges both)."""
         self.cache = cache
-        if self.kv is not None:
-            if self.quantized:
-                self.kv.pool, self.kv.scales = cache
-            else:
-                self.kv.pool = cache
+        if self.quantized:
+            self.kv.pool, self.kv.scales = cache
+        else:
+            self.kv.pool = cache
 
     def _table_for_slot(self, slot: int) -> np.ndarray:
         """[T] int32 pool block ids (-1 pad) of the sequence in ``slot``."""
@@ -1152,72 +1092,41 @@ class Engine:
         return pow2_bucket(nblk, lo=1,
                            hi=self.ecfg.max_seq_len // self.ecfg.block)
 
-    def _swap_gather_fn(self, key):
-        fn = self._swap_gather_jit.get(key)
+    def _swap_gather_fn(self, bucket: int):
+        fn = self._swap_gather_jit.get(bucket)
         if fn is None:
-            kind, width = key
             qz = self.quantized
-            if kind == "paged":
-                # quantized: the scales gather rides the SAME ids — the
-                # host copy is (codes, scales), ~cache_dtype_bytes/2 the
-                # bf16 swap volume per block
-                def swap_gather(cache, ids):
-                    pool, scales = cache if qz else (cache, None)
-                    blocks = jnp.take(pool, ids, axis=2)
-                    if qz:
-                        return (pool, scales), (
-                            blocks, jnp.take(scales, ids, axis=2))
-                    return pool, blocks
-            else:
-                pool0 = self.cache[0] if qz else self.cache
-                L, _, _, Hkv, _, Dh = pool0.shape
-                nb = width // self.ecfg.block
-                def swap_gather(cache, slot):
-                    c, scales = cache if qz else (cache, None)
-                    seq = jax.lax.dynamic_slice(
-                        c, (0, 0, slot, 0, 0, 0),
-                        (L, 2, 1, Hkv, width, Dh))
-                    if qz:
-                        ssc = jax.lax.dynamic_slice(
-                            scales, (0, 0, slot, 0, 0), (L, 2, 1, Hkv, nb))
-                        return (c, scales), (seq, ssc)
-                    return c, seq
+
+            # quantized: the scales gather rides the SAME ids — the host
+            # copy is (codes, scales), ~cache_dtype_bytes/2 the bf16 swap
+            # volume per block
+            def swap_gather(cache, ids):
+                pool, scales = cache if qz else (cache, None)
+                blocks = jnp.take(pool, ids, axis=2)
+                if qz:
+                    return (pool, scales), (
+                        blocks, jnp.take(scales, ids, axis=2))
+                return pool, blocks
             fn = jax.jit(swap_gather,
                          donate_argnums=(0,) if self._donate else ())
-            self._swap_gather_jit[key] = fn
+            self._swap_gather_jit[bucket] = fn
         return fn
 
-    def _swap_scatter_fn(self, key):
-        fn = self._swap_scatter_jit.get(key)
+    def _swap_scatter_fn(self, bucket: int):
+        fn = self._swap_scatter_jit.get(bucket)
         if fn is None:
-            kind = key[0]
             qz = self.quantized
-            if kind == "paged":
-                def swap_scatter(cache, blocks, ids):
-                    if qz:
-                        pool, scales = cache
-                        blk, sc = blocks
-                        return (pool.at[:, :, ids].set(
-                                    blk.astype(pool.dtype)),
-                                scales.at[:, :, ids].set(sc))
-                    return cache.at[:, :, ids].set(
-                        blocks.astype(cache.dtype))
-            else:
-                def swap_scatter(cache, seq, slot):
-                    if qz:
-                        c, scales = cache
-                        sq, sc = seq
-                        return (jax.lax.dynamic_update_slice(
-                                    c, sq.astype(c.dtype),
-                                    (0, 0, slot, 0, 0, 0)),
-                                jax.lax.dynamic_update_slice(
-                                    scales, sc, (0, 0, slot, 0, 0)))
-                    return jax.lax.dynamic_update_slice(
-                        cache, seq.astype(cache.dtype),
-                        (0, 0, slot, 0, 0, 0))
+
+            def swap_scatter(cache, blocks, ids):
+                if qz:
+                    pool, scales = cache
+                    blk, sc = blocks
+                    return (pool.at[:, :, ids].set(blk.astype(pool.dtype)),
+                            scales.at[:, :, ids].set(sc))
+                return cache.at[:, :, ids].set(blocks.astype(cache.dtype))
             fn = jax.jit(swap_scatter,
                          donate_argnums=(0,) if self._donate else ())
-            self._swap_scatter_jit[key] = fn
+            self._swap_scatter_jit[bucket] = fn
         return fn
 
     def _transfer_gate(self, seam: str, rid: int) -> None:
@@ -1254,52 +1163,37 @@ class Engine:
             seam, f"transfer failed after {retries + 1} attempts", rid=rid)
 
     def _swap_out_seq(self, rid: int, slot: int, resident: int) -> None:
-        """Batcher swap-out hook: copy the sequence's resident KV state to
-        host BEFORE the allocator recycles its blocks.  Paged: gather its
-        mapped pool blocks; contiguous: slice its slot rows (the tokens
-        past ``resident`` ride along as junk — decode masks by length)."""
+        """Batcher swap-out hook: copy the sequence's mapped pool blocks
+        to host BEFORE the allocator recycles them."""
         self._transfer_gate("swap_out_transfer", rid)
         sdata = None
-        shared_n = 0
-        if self.paged:
-            # prefix sharing (§2.14): tree-cached / multiply-referenced
-            # prefix blocks STAY RESIDENT (their payload serves every
-            # other holder already) — only the private tail transfers
-            retained, private = self.kv.alloc.swap_split(rid)
-            assert len(retained) + len(private) == \
-                self.kv.alloc.blocks_needed(resident)
-            shared_n = len(retained)
-            nblk = len(private)
-            if nblk:
-                bucket = self._swap_bucket(nblk)
-                row = np.full((bucket,), self.kv.trash_block, np.int32)
-                row[:nblk] = private
-                pool, blocks = self._swap_gather_fn(("paged", bucket))(
-                    self.cache, jnp.asarray(row))
-                self._set_cache(pool)
-                if self.quantized:
-                    blocks, sc = blocks
-                    sdata = np.array(jax.device_get(sc)[:, :, :nblk])
-                data = np.array(jax.device_get(blocks)[:, :, :nblk])
-            else:
-                # fully shared: zero transfer; keep an empty host payload
-                # so swap-in's shape bookkeeping stays uniform
-                pool0 = self.cache[0] if self.quantized else self.cache
-                L, two, _, Hkv, blk, Dh = pool0.shape
-                data = np.zeros((L, two, 0, Hkv, blk, Dh), pool0.dtype)
-                if self.quantized:
-                    sdata = np.zeros((L, two, 0, Hkv), np.float32)
-        else:
-            nblk = -(-resident // self.ecfg.block)
+        # prefix sharing (§2.14): tree-cached / multiply-referenced prefix
+        # blocks STAY RESIDENT (their payload serves every other holder
+        # already) — only the private tail transfers
+        retained, private = self.kv.alloc.swap_split(rid)
+        assert len(retained) + len(private) == \
+            self.kv.alloc.blocks_needed(resident)
+        shared_n = len(retained)
+        nblk = len(private)
+        if nblk:
             bucket = self._swap_bucket(nblk)
-            width = bucket * self.ecfg.block
-            cache, seq = self._swap_gather_fn(("slot", width))(
-                self.cache, slot)
-            self._set_cache(cache)
+            row = np.full((bucket,), self.kv.trash_block, np.int32)
+            row[:nblk] = private
+            pool, blocks = self._swap_gather_fn(bucket)(
+                self.cache, jnp.asarray(row))
+            self._set_cache(pool)
             if self.quantized:
-                seq, ssc = seq
-                sdata = np.asarray(jax.device_get(ssc))
-            data = np.asarray(jax.device_get(seq))
+                blocks, sc = blocks
+                sdata = np.array(jax.device_get(sc)[:, :, :nblk])
+            data = np.array(jax.device_get(blocks)[:, :, :nblk])
+        else:
+            # fully shared: zero transfer; keep an empty host payload so
+            # swap-in's shape bookkeeping stays uniform
+            pool0 = self.cache[0] if self.quantized else self.cache
+            L, two, _, Hkv, blk, Dh = pool0.shape
+            data = np.zeros((L, two, 0, Hkv, blk, Dh), pool0.dtype)
+            if self.quantized:
+                sdata = np.zeros((L, two, 0, Hkv), np.float32)
         self._host_swaps[rid] = {"data": data, "scales": sdata,
                                  "tokens": resident,
                                  "shared_blocks": shared_n,
@@ -1312,11 +1206,11 @@ class Engine:
 
     def _swap_in_seq(self, rid: int, slot: int, resident: int) -> None:
         """Batcher swap-in hook: restore the host copy into the freshly
-        mapped blocks (paged) or the newly claimed slot (contiguous).  If
-        plan epochs re-permuted the resident cache's kv-head axis while
-        the sequence was out, the host copy is re-arranged here — exactly
-        once, against the cumulative arrangement, no matter how many
-        epoch swaps passed (the §2.9 cache gather composed them)."""
+        mapped blocks.  If plan epochs re-permuted the resident cache's
+        kv-head axis while the sequence was out, the host copy is
+        re-arranged here — exactly once, against the cumulative
+        arrangement, no matter how many epoch swaps passed (the §2.9 cache
+        gather composed them)."""
         # gate BEFORE popping the host record: a failed (given-up) swap-in
         # leaves the copy intact for the scheduler's fallback to discard
         self._transfer_gate("swap_in_transfer", rid)
@@ -1333,46 +1227,34 @@ class Engine:
             data = np.take_along_axis(
                 data, rel[:, None, None, :, None, None], axis=3)
             if sdata is not None:
-                # scales have kv heads on axis 3 too: paged [L, 2, nblk,
-                # Hkv] (4D), contiguous [L, 2, 1, Hkv, nb] (5D)
-                data_rel = rel.reshape(
-                    (rel.shape[0], 1, 1, rel.shape[1])
-                    + (1,) * (sdata.ndim - 4))
-                sdata = np.take_along_axis(sdata, data_rel, axis=3)
+                # scales [L, 2, nblk, Hkv] have kv heads on axis 3 too
+                sdata = np.take_along_axis(sdata, rel[:, None, None, :],
+                                           axis=3)
             self.swap_stats["epoch_remaps"] += 1
-        if self.paged:
-            # alloc.swap_in re-mapped only the PRIVATE tail: the leading
-            # shared_n table entries are the retained resident prefix
-            # (§2.14) and never left the device, so the host copy scatters
-            # past them — into the fresh ids only
-            shared_n = rec.get("shared_blocks", 0)
-            ids = self.kv.alloc.table(rid)[shared_n:]
-            nblk = len(ids)
-            assert nblk == data.shape[2], \
-                f"swap-in block mismatch: {nblk} != {data.shape[2]}"
-            if nblk:
-                bucket = self._swap_bucket(nblk)
-                row = np.full((bucket,), self.kv.trash_block, np.int32)
-                row[:nblk] = ids
-                L, two, _, Hkv, blk, Dh = data.shape
-                buf = np.zeros((L, two, bucket, Hkv, blk, Dh), data.dtype)
-                buf[:, :, :nblk] = data
-                payload = jnp.asarray(buf)
-                if self.quantized:
-                    sbuf = np.ones((L, two, bucket, Hkv), np.float32)
-                    sbuf[:, :, :nblk] = sdata
-                    payload = (payload, jnp.asarray(sbuf))
-                pool = self._swap_scatter_fn(("paged", bucket))(
-                    self.cache, payload, jnp.asarray(row))
-                self._set_cache(pool)
-        else:
-            nblk = -(-resident // self.ecfg.block)
-            payload = jnp.asarray(data)
+        # alloc.swap_in re-mapped only the PRIVATE tail: the leading
+        # shared_n table entries are the retained resident prefix (§2.14)
+        # and never left the device, so the host copy scatters past them —
+        # into the fresh ids only
+        shared_n = rec.get("shared_blocks", 0)
+        ids = self.kv.alloc.table(rid)[shared_n:]
+        nblk = len(ids)
+        assert nblk == data.shape[2], \
+            f"swap-in block mismatch: {nblk} != {data.shape[2]}"
+        if nblk:
+            bucket = self._swap_bucket(nblk)
+            row = np.full((bucket,), self.kv.trash_block, np.int32)
+            row[:nblk] = ids
+            L, two, _, Hkv, blk, Dh = data.shape
+            buf = np.zeros((L, two, bucket, Hkv, blk, Dh), data.dtype)
+            buf[:, :, :nblk] = data
+            payload = jnp.asarray(buf)
             if self.quantized:
-                payload = (payload, jnp.asarray(sdata))
-            cache = self._swap_scatter_fn(("slot",))(
-                self.cache, payload, slot)
-            self._set_cache(cache)
+                sbuf = np.ones((L, two, bucket, Hkv), np.float32)
+                sbuf[:, :, :nblk] = sdata
+                payload = (payload, jnp.asarray(sbuf))
+            pool = self._swap_scatter_fn(bucket)(
+                self.cache, payload, jnp.asarray(row))
+            self._set_cache(pool)
         st = self.swap_stats
         st["swapped_in"] += 1
         st["blocks_in"] += nblk
@@ -1461,24 +1343,17 @@ class Engine:
                     victim = s
                     break
         bad = jnp.inf if spec.mode == "inf" else jnp.nan
-        if self.paged:
-            rid = (self._batcher.rid_of_slot(victim)
-                   if self._batcher is not None else None)
-            ids = self.kv.alloc.table(rid) if rid is not None else []
-            if not ids:
-                return
-            bid = int(ids[0])
-            if self.quantized:
-                pool, scales = self.cache
-                self._set_cache((pool, scales.at[:, 1, bid].set(bad)))
-            else:
-                self._set_cache(self.cache.at[:, 1, bid].set(bad))
+        rid = (self._batcher.rid_of_slot(victim)
+               if self._batcher is not None else None)
+        ids = self.kv.alloc.table(rid) if rid is not None else []
+        if not ids:
+            return
+        bid = int(ids[0])
+        if self.quantized:
+            pool, scales = self.cache
+            self._set_cache((pool, scales.at[:, 1, bid].set(bad)))
         else:
-            if self.quantized:
-                c, scales = self.cache
-                self._set_cache((c, scales.at[:, 1, victim].set(bad)))
-            else:
-                self._set_cache(self.cache.at[:, 1, victim].set(bad))
+            self._set_cache(self.cache.at[:, 1, bid].set(bad))
         self.fault_stats["corruptions_injected"] += 1
         log.warning("injected kv_corrupt (%s) into slot %d", spec.mode,
                     victim)
@@ -1498,32 +1373,21 @@ class Engine:
         quarantined sequence's corrupted blocks are uncached by now and
         scrub as soon as their last reference drops.)"""
         self._host_swaps.pop(rid, None)
-        if self.paged:
-            alloc = self.kv.alloc
-            ids = [b for b in alloc.table(rid)
-                   if alloc.refcount(b) == 1 and not alloc.is_cached(b)]
-            if not ids:
-                return
-            idx = jnp.asarray(np.asarray(ids, np.int32))
-            if self.quantized:
-                pool, scales = self.cache
-                self._set_cache((
-                    pool.at[:, :, idx].set(jnp.zeros((), pool.dtype)),
-                    scales.at[:, :, idx].set(1.0)))
-            else:
-                self._set_cache(
-                    self.cache.at[:, :, idx].set(
-                        jnp.zeros((), self.cache.dtype)))
-        elif slot is not None:
-            if self.quantized:
-                c, scales = self.cache
-                self._set_cache((
-                    c.at[:, :, slot].set(jnp.zeros((), c.dtype)),
-                    scales.at[:, :, slot].set(1.0)))
-            else:
-                self._set_cache(
-                    self.cache.at[:, :, slot].set(
-                        jnp.zeros((), self.cache.dtype)))
+        alloc = self.kv.alloc
+        ids = [b for b in alloc.table(rid)
+               if alloc.refcount(b) == 1 and not alloc.is_cached(b)]
+        if not ids:
+            return
+        idx = jnp.asarray(np.asarray(ids, np.int32))
+        if self.quantized:
+            pool, scales = self.cache
+            self._set_cache((
+                pool.at[:, :, idx].set(jnp.zeros((), pool.dtype)),
+                scales.at[:, :, idx].set(1.0)))
+        else:
+            self._set_cache(
+                self.cache.at[:, :, idx].set(
+                    jnp.zeros((), self.cache.dtype)))
 
     def audit(self, strict: bool = True) -> list[str]:
         """Engine-level invariant audit (DESIGN.md §2.13): the allocator's
@@ -1532,51 +1396,35 @@ class Engine:
         (every allocator-swapped sequence has exactly one host copy whose
         token count matches).  Returns the violations (empty = healthy);
         ``strict`` raises :class:`IntegrityError` on any."""
-        if self.paged:
-            fails = self.kv.audit(strict=False)
-            alloc = self.kv.alloc
-        else:
-            alloc = (self._batcher.alloc if self._batcher is not None
-                     else None)
-            fails = alloc.audit(strict=False) if alloc is not None else []
-            if self.quantized:
-                c, scales = self.cache
-                if tuple(scales.shape[:4]) != tuple(c.shape[:4]):
-                    fails.append(
-                        f"contiguous scales shape {tuple(scales.shape)} "
-                        f"disagrees with cache {tuple(c.shape)}")
-        if alloc is not None:
-            swapped = set(alloc.swapped_seqs)
-            held = set(self._host_swaps)
-            for rid in sorted(swapped - held):
-                fails.append(f"seq {rid} swapped-out in allocator but has "
-                             "no host copy")
-            for rid in sorted(held - swapped):
-                fails.append(f"seq {rid} has a host copy but is not "
-                             "swapped-out in the allocator")
-            for rid in sorted(swapped & held):
-                rec = self._host_swaps[rid]
-                if alloc.host_tokens(rid) != rec["tokens"]:
-                    fails.append(
-                        f"seq {rid} host tokens disagree: allocator "
-                        f"{alloc.host_tokens(rid)} vs copy "
-                        f"{rec['tokens']}")
-                if self.paged:
-                    # prefix sharing (§2.14): the host payload must hold
-                    # exactly the PRIVATE tail — total blocks minus the
-                    # retained resident prefix both sides agree on
-                    shn = rec.get("shared_blocks", 0)
-                    if shn != alloc.host_shared_blocks(rid):
-                        fails.append(
-                            f"seq {rid} retained-prefix disagree: "
-                            f"allocator {alloc.host_shared_blocks(rid)} "
-                            f"vs copy {shn}")
-                    want = alloc.blocks_needed(rec["tokens"]) - shn
-                    if rec["data"].shape[2] != want:
-                        fails.append(
-                            f"seq {rid} host payload holds "
-                            f"{rec['data'].shape[2]} blocks, expected "
-                            f"{want}")
+        fails = self.kv.audit(strict=False)
+        alloc = self.kv.alloc
+        swapped = set(alloc.swapped_seqs)
+        held = set(self._host_swaps)
+        for rid in sorted(swapped - held):
+            fails.append(f"seq {rid} swapped-out in allocator but has no "
+                         "host copy")
+        for rid in sorted(held - swapped):
+            fails.append(f"seq {rid} has a host copy but is not "
+                         "swapped-out in the allocator")
+        for rid in sorted(swapped & held):
+            rec = self._host_swaps[rid]
+            if alloc.host_tokens(rid) != rec["tokens"]:
+                fails.append(
+                    f"seq {rid} host tokens disagree: allocator "
+                    f"{alloc.host_tokens(rid)} vs copy {rec['tokens']}")
+            # prefix sharing (§2.14): the host payload must hold exactly
+            # the PRIVATE tail — total blocks minus the retained resident
+            # prefix both sides agree on
+            shn = rec.get("shared_blocks", 0)
+            if shn != alloc.host_shared_blocks(rid):
+                fails.append(
+                    f"seq {rid} retained-prefix disagree: allocator "
+                    f"{alloc.host_shared_blocks(rid)} vs copy {shn}")
+            want = alloc.blocks_needed(rec["tokens"]) - shn
+            if rec["data"].shape[2] != want:
+                fails.append(
+                    f"seq {rid} host payload holds {rec['data'].shape[2]} "
+                    f"blocks, expected {want}")
         if self.prefix is not None:
             tree_ids = self.prefix.block_ids()
             pinned = self.kv.alloc.cached_ids()
@@ -1642,66 +1490,16 @@ class Engine:
             b *= 2
         return min(b, self.ecfg.max_seq_len)
 
-    def _prefill_fn(self, bucket: int):
-        """Jitted prefill step for one compile bucket.
-
-        The slot cache is threaded THROUGH the jit and donated: the
-        sequence cache lands in the slot via an in-jit dynamic_update_slice
-        instead of the old out-of-jit whole-cache copy, so the hot path
-        never materializes a second [L, 2, slots, Hkv, Smax, Dh] buffer.
-        ``slot`` and ``last_idx`` are traced scalars — one compile serves
-        every slot and every real length within the bucket.  The epoch's
-        work-lists are BAKED into the program (compile-time constants), so
-        the memo key carries the epoch and the LRU cap retires old-epoch
-        programs.
-        """
-        def build():
-            if self.ecfg.attention == "sparse":
-                wls = self.worklists_for(bucket)
-                items = [jnp.asarray(w.items.reshape(-1, w.items.shape[-1]))
-                         for w in wls]
-            else:
-                items = None
-
-            qz = self.quantized
-
-            def prefill(params, cache, tokens, slot, last_idx):
-                logits, seq_cache = tfm.prefill(
-                    params, tokens, self.cfg,
-                    cache_len=self.ecfg.max_seq_len,
-                    sparse_items=items, last_index=last_idx)
-                if qz:
-                    # quantize the full-precision sequence cache ONCE at
-                    # slot insert: codes land next to their scales in the
-                    # same donated program (§2.12)
-                    c, scales = cache
-                    codes, sc = quant.quantize_seq_cache(
-                        seq_cache, self.ecfg.block, self.ecfg.kv_dtype)
-                    c = jax.lax.dynamic_update_slice(
-                        c, codes.astype(c.dtype), (0, 0, slot, 0, 0, 0))
-                    scales = jax.lax.dynamic_update_slice(
-                        scales, sc, (0, 0, slot, 0, 0))
-                    return logits, (c, scales)
-                cache = jax.lax.dynamic_update_slice(
-                    cache, seq_cache.astype(cache.dtype),
-                    (0, 0, slot, 0, 0, 0))
-                return logits, cache
-
-            return jax.jit(prefill,
-                           donate_argnums=(1,) if self._donate else ())
-
-        return self._lru_get(self._prefill_jit, (self.epoch, bucket),
-                             build, self.ecfg.prefill_jit_cap)
-
     def _prefill_paged_fn(self, bucket: int):
         """Paged monolithic prefill for one compile bucket: the sequence
         cache is computed at the bucket length (not max_seq_len — the
         paged layout never materializes a max-length row) and lands in the
         pool with one block scatter through the table
         (``tfm.scatter_seq_cache_paged``).  The pool is donated; the table
-        is data, so one compile serves every block placement.  Work-lists
-        are compile-time constants — epoch-keyed + LRU like
-        :meth:`_prefill_fn`."""
+        is data, so one compile serves every block placement and every
+        real length within the bucket.  The epoch's work-lists are BAKED
+        into the program (compile-time constants), so the memo key carries
+        the epoch and the LRU cap retires old-epoch programs."""
         def build():
             blk = self.ecfg.block
             bucket_pad = -(-bucket // blk) * blk
@@ -1735,16 +1533,16 @@ class Engine:
 
     def _chunk_bucket(self, chunk_len: int, q_offset: int) -> int:
         """Compile bucket for one prefill chunk: next power of two (floored
-        at one block), capped at the cache rows LEFT after ``q_offset`` —
-        an uncapped bucket would make the K/V dynamic_update_slice clamp
-        its start index and silently overwrite earlier rows.  The cap is a
-        block multiple (max_seq_len and q_offset both are), so the bucket
+        at one block), capped at the table rows LEFT after ``q_offset`` —
+        an uncapped bucket would make the chunk's table dynamic_slice clamp
+        its start index and silently overwrite earlier blocks.  The cap is
+        a block multiple (max_seq_len and q_offset both are), so the bucket
         always spans whole q-blocks for the work-list slicing."""
         b = self.ecfg.block
         while b < chunk_len:
             b *= 2
         room = self.ecfg.max_seq_len - q_offset
-        assert chunk_len <= room, "chunk overruns the slot cache"
+        assert chunk_len <= room, "chunk overruns the sequence's table"
         return min(b, room)
 
     def _chunk_item_cap(self, nqc: int) -> int:
@@ -1795,58 +1593,41 @@ class Engine:
         """Jitted chunked-prefill step for one chunk compile bucket, named
         ``prefill_chunk_b<bucket>`` (``prefill_chunk_dense_b<bucket>``).
 
-        The slot cache threads through and is donated (same zero-copy
-        contract as monolithic prefill); ``slot`` / ``q_offset`` / ``kv_len``
-        / ``last_idx`` are traced scalars and sparse work-lists enter as
-        data, so one compile serves every slot, offset, selection — and
-        every plan EPOCH (no epoch in the key; the memo is LRU-bounded
-        anyway so bucket churn cannot leak compiled entries)."""
+        The chunk scatters straight into the sequence's pool blocks via the
+        table; the pool is donated.  ``q_offset`` / ``kv_len`` /
+        ``last_idx`` are traced scalars and the table and sparse work-lists
+        enter as data, so one compile serves every sequence, offset,
+        selection — and every plan EPOCH (no epoch in the key; the memo is
+        LRU-bounded anyway so bucket churn cannot leak compiled entries).
+        Quantized: each chunk's K/V quantize AT THE SCATTER (chunks are
+        block-aligned so every block is quantized exactly once), and later
+        chunks attend the earlier blocks through the dequant-fused
+        worklist path."""
         def build():
             sparse = self.ecfg.attention == "sparse"
-            if self.paged:
-                # paged: no staging cache, no slot — the chunk scatters
-                # straight into the sequence's pool blocks via the table.
-                # Quantized: each chunk's K/V quantize AT THE SCATTER
-                # (chunks are block-aligned so every block is quantized
-                # exactly once), and later chunks attend the earlier
-                # blocks through the dequant-fused worklist path.
-                qz = self.quantized
-                kvd = self.ecfg.kv_dtype
+            qz = self.quantized
+            kvd = self.ecfg.kv_dtype
 
-                def step(params, cache, tokens, table, off, kv_len,
-                         last_idx, items):
-                    pool, scales = cache if qz else (cache, None)
-                    out = tfm.prefill_chunk_paged(
-                        params, pool, tokens, table, off, self.cfg,
-                        kv_len=kv_len, sparse_items=items,
-                        last_index=last_idx, scales=scales, kv_dtype=kvd)
-                    if qz:
-                        return out[0], (out[1], out[2])
-                    return out
+            def step(params, cache, tokens, table, off, kv_len, last_idx,
+                     items):
+                pool, scales = cache if qz else (cache, None)
+                out = tfm.prefill_chunk_paged(
+                    params, pool, tokens, table, off, self.cfg,
+                    kv_len=kv_len, sparse_items=items, last_index=last_idx,
+                    scales=scales, kv_dtype=kvd)
+                if qz:
+                    return out[0], (out[1], out[2])
+                return out
 
-                def prefill_chunk(params, pool, tokens, table, off, kv_len,
-                                  last_idx, items):
-                    return step(params, pool, tokens, table, off, kv_len,
-                                last_idx, items)
+            def prefill_chunk(params, pool, tokens, table, off, kv_len,
+                              last_idx, items):
+                return step(params, pool, tokens, table, off, kv_len,
+                            last_idx, items)
 
-                def prefill_chunk_dense(params, pool, tokens, table, off,
-                                        kv_len, last_idx):
-                    return step(params, pool, tokens, table, off, kv_len,
-                                last_idx, None)
-            else:
-                def prefill_chunk(params, cache, tokens, slot, off, kv_len,
-                                  last_idx, items):
-                    return tfm.prefill_chunk(
-                        params, cache, tokens, slot, off, self.cfg,
-                        kv_len=kv_len, sparse_items=items,
-                        last_index=last_idx)
-
-                def prefill_chunk_dense(params, cache, tokens, slot, off,
-                                        kv_len, last_idx):
-                    return tfm.prefill_chunk(
-                        params, cache, tokens, slot, off, self.cfg,
-                        kv_len=kv_len, sparse_items=None,
-                        last_index=last_idx)
+            def prefill_chunk_dense(params, pool, tokens, table, off,
+                                    kv_len, last_idx):
+                return step(params, pool, tokens, table, off, kv_len,
+                            last_idx, None)
 
             # a name per bucket, so a trace tells the buckets apart
             fn = prefill_chunk if sparse else prefill_chunk_dense
@@ -1864,42 +1645,25 @@ class Engine:
             sparse = self.ecfg.attention == "sparse"
             qz = self.quantized
             kvd = self.ecfg.kv_dtype
-            if self.paged:
-                S = self.ecfg.seq_shards
-                ss = self.kv.stripe_size if S > 1 else None
+            S = self.ecfg.seq_shards
+            ss = self.kv.stripe_size if S > 1 else None
 
-                def step(params, cache, token, pos, table, bids, act):
-                    pool, scales = cache if qz else (cache, None)
-                    out = tfm.decode_step_paged(
-                        params, pool, token, pos, table, self.cfg,
-                        block_ids=bids, cache_len=pos + 1, active=act,
-                        seq_stripes=S, stripe_size=ss, scales=scales,
-                        kv_dtype=kvd)
-                    if qz:
-                        return out[0], (out[1], out[2])
-                    return out
+            def step(params, cache, token, pos, table, bids, act):
+                pool, scales = cache if qz else (cache, None)
+                out = tfm.decode_step_paged(
+                    params, pool, token, pos, table, self.cfg,
+                    block_ids=bids, cache_len=pos + 1, active=act,
+                    seq_stripes=S, stripe_size=ss, scales=scales,
+                    kv_dtype=kvd)
+                if qz:
+                    return out[0], (out[1], out[2])
+                return out
 
-                def decode_padded(params, pool, token, pos, table, bids, act):
-                    return step(params, pool, token, pos, table, bids, act)
+            def decode_padded(params, pool, token, pos, table, bids, act):
+                return step(params, pool, token, pos, table, bids, act)
 
-                def decode_dense(params, pool, token, pos, table, act):
-                    return step(params, pool, token, pos, table, None, act)
-            else:
-                def step(params, cache, token, pos, bids, act):
-                    c, scales = cache if qz else (cache, None)
-                    out = tfm.decode_step(
-                        params, c, token, pos, self.cfg, block_ids=bids,
-                        cache_len=pos + 1, active=act, scales=scales,
-                        kv_dtype=kvd)
-                    if qz:
-                        return out[0], (out[1], out[2])
-                    return out
-
-                def decode_padded(params, cache, token, pos, bids, act):
-                    return step(params, cache, token, pos, bids, act)
-
-                def decode_dense(params, cache, token, pos, act):
-                    return step(params, cache, token, pos, None, act)
+            def decode_dense(params, pool, token, pos, table, act):
+                return step(params, pool, token, pos, table, None, act)
 
             donate = (1,) if self._donate else ()
             self._decode_jit = (
@@ -1919,31 +1683,19 @@ class Engine:
         if fn is None:
             qz = self.quantized
             kvd = self.ecfg.kv_dtype
-            if self.paged:
-                S = self.ecfg.seq_shards
-                ss = self.kv.stripe_size if S > 1 else None
+            S = self.ecfg.seq_shards
+            ss = self.kv.stripe_size if S > 1 else None
 
-                def decode_packed(params, cache, token, pos, table, items,
-                                  act):
-                    pool, scales = cache if qz else (cache, None)
-                    out = tfm.decode_step_paged(
-                        params, pool, token, pos, table, self.cfg,
-                        packed_items=items, cache_len=pos + 1, active=act,
-                        seq_stripes=S, stripe_size=ss, scales=scales,
-                        kv_dtype=kvd)
-                    if qz:
-                        return out[0], (out[1], out[2])
-                    return out
-            else:
-                def decode_packed(params, cache, token, pos, items, act):
-                    c, scales = cache if qz else (cache, None)
-                    out = tfm.decode_step(
-                        params, c, token, pos, self.cfg,
-                        packed_items=items, cache_len=pos + 1, active=act,
-                        scales=scales, kv_dtype=kvd)
-                    if qz:
-                        return out[0], (out[1], out[2])
-                    return out
+            def decode_packed(params, cache, token, pos, table, items, act):
+                pool, scales = cache if qz else (cache, None)
+                out = tfm.decode_step_paged(
+                    params, pool, token, pos, table, self.cfg,
+                    packed_items=items, cache_len=pos + 1, active=act,
+                    seq_stripes=S, stripe_size=ss, scales=scales,
+                    kv_dtype=kvd)
+                if qz:
+                    return out[0], (out[1], out[2])
+                return out
             fn = jax.jit(decode_packed,
                          donate_argnums=(1,) if self._donate else ())
             self._decode_packed_jit[flat_len] = fn
@@ -1952,26 +1704,19 @@ class Engine:
     # -- public API -----------------------------------------------------------
     def prefill_into_slot(self, tokens: np.ndarray, slot: int,
                           sampling: SamplingParams = SamplingParams()) -> int:
-        """Prefill one sequence into its cache (the slot's row under the
-        contiguous layout; the sequence's pool blocks under the paged
-        layout); returns the first sampled token."""
+        """Prefill one sequence into its pool blocks; returns the first
+        sampled token."""
         with TraceAnnotation("engine.prefill.inputs"):
             tokens = np.atleast_2d(np.asarray(tokens, np.int32))
             S = tokens.shape[-1]
             bucket = self._prefill_bucket(S)
             if bucket > S:
                 tokens = np.pad(tokens, ((0, 0), (0, bucket - S)))
-            table = self._table_for_slot(slot) if self.paged else None
+            table = self._table_for_slot(slot)
         with TraceAnnotation("engine.prefill.dispatch"):
-            if self.paged:
-                run = self._prefill_paged_fn(bucket)
-                logits, cache = run(self.params, self.cache,
-                                    jnp.asarray(tokens), jnp.asarray(table),
-                                    S - 1)
-            else:
-                run = self._prefill_fn(bucket)
-                logits, cache = run(self.params, self.cache,
-                                    jnp.asarray(tokens), slot, S - 1)
+            run = self._prefill_paged_fn(bucket)
+            logits, cache = run(self.params, self.cache, jnp.asarray(tokens),
+                                jnp.asarray(table), S - 1)
             self._set_cache(cache)
         return self._sample_first(logits, slot, sampling)
 
@@ -1979,10 +1724,10 @@ class Engine:
                                 q_offset: int, prompt_len: int,
                                 sampling: SamplingParams = SamplingParams(),
                                 is_final: bool = True) -> int | None:
-        """Prefill one chunk of a sequence into its cache slot.
+        """Prefill one chunk of a sequence into its pool blocks.
 
         ``tokens``: the chunk's real tokens [c]; ``q_offset``: tokens of
-        this sequence already resident in the slot (block-aligned — the
+        this sequence already resident in the pool (block-aligned — the
         scheduler only emits block-aligned non-final chunks).  Returns the
         first sampled token when ``is_final`` (logits read at the chunk's
         last real row), else None.
@@ -1996,27 +1741,17 @@ class Engine:
             toks[0, :c] = tokens
             data = ([self._chunk_worklists(prompt_len, q_offset, bucket)]
                     if sparse else [])
-            table = self._table_for_slot(slot) if self.paged else None
+            table = self._table_for_slot(slot)
         with TraceAnnotation("engine.prefill.dispatch"):
             run = self._prefill_chunk_fn(bucket)
             data = [jnp.asarray(d) for d in data]
-            if self.paged:
-                # chunks scatter straight into the sequence's pool blocks —
-                # no staging cache, no merge, and decode never observes a
-                # mid-prefill sequence because its blocks are disjoint
-                logits, cache = run(self.params, self.cache,
-                                    jnp.asarray(toks), jnp.asarray(table),
-                                    q_offset, q_offset + c, c - 1, *data)
-                self._set_cache(cache)
-            else:
-                if self._staging is None:
-                    self._staging = tfm.init_cache(self.cfg, 1,
-                                                   self.ecfg.max_seq_len)
-                logits, self._staging = run(
-                    self.params, self._staging, jnp.asarray(toks), 0,
-                    q_offset, q_offset + c, c - 1, *data)
-                if is_final:
-                    self._set_cache(self._merge_staging(slot))
+            # chunks scatter straight into the sequence's pool blocks, and
+            # decode never observes a mid-prefill sequence because its
+            # blocks are disjoint
+            logits, cache = run(self.params, self.cache, jnp.asarray(toks),
+                                jnp.asarray(table), q_offset, q_offset + c,
+                                c - 1, *data)
+            self._set_cache(cache)
         if not is_final:
             return None
         return self._sample_first(logits, slot, sampling)
@@ -2031,37 +1766,6 @@ class Engine:
             tok = int(sample(logits, sub, sampling)[0])
         self._sentinel_check(logits, [(0, slot)])
         return tok
-
-    def _merge_staging(self, slot: int):
-        """One donated dynamic_update_slice lands the staged sequence in
-        its slot — the same single-copy insert monolithic prefill does.
-        Stale staging rows past the new sequence ride along exactly like
-        monolithic bucket padding: masked by position everywhere."""
-        if self._merge_jit is None:
-            if self.quantized:
-                # contiguous chunked + quantized: the STAGING cache stays
-                # full-precision (chunks attend exact values while the
-                # sequence builds) and quantization happens exactly once,
-                # here at the merge — block tiles match what monolithic
-                # prefill's insert would have produced
-                blk, kvd = self.ecfg.block, self.ecfg.kv_dtype
-
-                def merge_staging(cache, staging, slot):
-                    c, scales = cache
-                    codes, sc = quant.quantize_seq_cache(staging, blk, kvd)
-                    c = jax.lax.dynamic_update_slice(
-                        c, codes.astype(c.dtype), (0, 0, slot, 0, 0, 0))
-                    scales = jax.lax.dynamic_update_slice(
-                        scales, sc, (0, 0, slot, 0, 0))
-                    return c, scales
-            else:
-                def merge_staging(cache, staging, slot):
-                    return jax.lax.dynamic_update_slice(
-                        cache, staging.astype(cache.dtype),
-                        (0, 0, slot, 0, 0, 0))
-            self._merge_jit = jax.jit(
-                merge_staging, donate_argnums=(0,) if self._donate else ())
-        return self._merge_jit(self.cache, self._staging, slot)
 
     def decode_slots(self, slots, tokens, positions,
                      sampling: SamplingParams = SamplingParams()):
@@ -2079,14 +1783,12 @@ class Engine:
             act_all[list(slots)] = True  # padded slots must not write KV
             self._decode_ticks += 1
             self._ticks_since_replan += 1
-            table = None
-            if self.paged:
-                # per-slot block tables (data): -1 rows for unbound slots
-                # route their writes into the trash block
-                table = np.full((self.ecfg.num_slots, self.kv.table_width),
-                                -1, np.int32)
-                for s in slots:
-                    table[s] = self._table_for_slot(s)
+            # per-slot block tables (data): -1 rows for unbound slots route
+            # their writes into the trash block
+            table = np.full((self.ecfg.num_slots, self.kv.table_width), -1,
+                            np.int32)
+            for s in slots:
+                table[s] = self._table_for_slot(s)
             # kv_corrupt seam fires BEFORE the probe dispatch and the step,
             # so both observe the corrupted block — detection is the test
             self._maybe_corrupt(slots)
@@ -2094,7 +1796,7 @@ class Engine:
             packed = sparse and self.ecfg.decode_worklist == "packed"
             probe = (sparse and self.ecfg.telemetry_every > 0
                      and self._decode_ticks % self.ecfg.telemetry_every == 0)
-            striped = self.paged and self.ecfg.seq_shards > 1
+            striped = self.ecfg.seq_shards > 1
         pending_probe = None
         if probe:
             # online recovery telemetry (DESIGN.md §2.9): probe the
@@ -2129,8 +1831,7 @@ class Engine:
                                              else shape[1])
             else:
                 run = self._decode_fn()
-            args = ([tok_all, pos_all] + ([table] if self.paged else [])
-                    + data + [act_all])
+            args = [tok_all, pos_all, table] + data + [act_all]
             logits, cache = run(self.params, self.cache,
                                 *map(jnp.asarray, args))
             self._set_cache(cache)
@@ -2186,18 +1887,15 @@ class Engine:
         """A ContinuousBatcher sized for this engine (chunked mixed ticks
         when ``prefill_mode == "chunked"``, else monolithic).
 
-        Paged layout: the batcher SHARES the PagedKVCache's allocator, so
-        admission control and the device pool count the very same blocks
-        — a request is admitted when its blocks fit, and ``num_slots``
-        only bounds the decode batch width.  ``classes`` overrides the
+        The batcher SHARES the PagedKVCache's allocator, so admission
+        control and the device pool count the very same blocks — a
+        request is admitted when its blocks fit, and ``num_slots`` only
+        bounds the decode batch width.  ``classes`` overrides the
         :data:`~repro.serving.scheduler.DEFAULT_CLASSES` table (the
         overload benchmark scales SLO targets to the measured tick time).
         """
         from repro.serving.scheduler import DEFAULT_CLASSES
         chunked = self.ecfg.prefill_mode == "chunked"
-        nblocks = (self.kv.num_blocks if self.paged
-                   else self.ecfg.num_slots
-                   * (self.ecfg.max_seq_len // self.ecfg.block))
         if self.ecfg.prefix_cache and self.prefix is None:
             # the tree OUTLIVES individual batchers (serve() builds one
             # per call; restores rebuild one) so cached prefixes stay
@@ -2207,11 +1905,11 @@ class Engine:
             self.kv.alloc.evict_fn = self.prefix.evict
         b = ContinuousBatcher(
             num_slots=self.ecfg.num_slots,
-            num_blocks=nblocks,
+            num_blocks=self.kv.num_blocks,
             max_seq_len=self.ecfg.max_seq_len,
             block=self.ecfg.block,
             token_budget=self.ecfg.prefill_chunk_tokens if chunked else None,
-            allocator=self.kv.alloc if self.paged else None,
+            allocator=self.kv.alloc,
             classes=classes if classes is not None else DEFAULT_CLASSES,
             admission=self.ecfg.admission,
             preemption=self.ecfg.preemption,
@@ -2221,10 +1919,6 @@ class Engine:
             sentinel_fn=self.take_quarantine,
             on_fail_fn=self._release_seq,
             prefix_cache=self.prefix)
-        if not self.paged:
-            # the contiguous layout's allocator is batcher-private
-            # accounting — wire the admission_alloc seam there too
-            b.alloc.injector = self.injector
         self._batcher = b
         return b
 

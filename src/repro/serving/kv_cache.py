@@ -1,6 +1,6 @@
 """KV-cache memory management for continuous batching (DESIGN.md §2.7).
 
-Three layers:
+Two layers:
 
 - :class:`BlockAllocator` — host-side bookkeeping of a fixed pool of
   ``block``-token cache blocks (vLLM-style) and the ONE source of truth for
@@ -44,11 +44,6 @@ Three layers:
   per-sequence block tables.  The allocator's table entries index the
   pool's block axis directly, so block ids are one namespace from the
   budget allocator down to the attention kernels.
-
-- :class:`SlotCache` — the legacy contiguous cache [L, 2, B_slots, Hkv,
-  Smax, Dh] with a free-slot map (``cache_layout="contiguous"``), kept as
-  the parity baseline: every sequence reserves ``max_seq_len`` tokens of
-  device memory, so capacity is slot-bound rather than token-bound.
 """
 from __future__ import annotations
 
@@ -801,35 +796,3 @@ class PagedKVCache:
         if self.scales is not None:
             total += self.scales.size * self.scales.dtype.itemsize
         return total
-
-
-class SlotCache:
-    """Fixed-slot contiguous device cache with host-side slot map (the
-    ``cache_layout="contiguous"`` baseline)."""
-
-    def __init__(self, make_cache_fn, num_slots: int):
-        """``make_cache_fn(num_slots) -> device cache pytree`` (batch dim =
-        slots)."""
-        self.cache = make_cache_fn(num_slots)
-        self.num_slots = num_slots
-        self._free = list(range(num_slots))
-        self._of_seq: dict[int, int] = {}
-
-    @property
-    def free_slots(self) -> int:
-        return len(self._free)
-
-    def claim(self, seq_id: int) -> int:
-        if not self._free:
-            raise MemoryError("no free cache slots")
-        s = self._free.pop()
-        self._of_seq[seq_id] = s
-        return s
-
-    def slot(self, seq_id: int) -> int:
-        return self._of_seq[seq_id]
-
-    def release(self, seq_id: int) -> None:
-        s = self._of_seq.pop(seq_id, None)
-        if s is not None:
-            self._free.append(s)

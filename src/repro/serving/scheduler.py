@@ -237,8 +237,7 @@ class ContinuousBatcher:
                  clock: Callable[[], float] = time.monotonic):
         # ``allocator``: share the engine's PagedKVCache allocator so the
         # scheduler's admission math and the device pool's block ids are the
-        # same object; None builds a private one (host-only tests, and the
-        # contiguous layout where blocks are pure accounting).
+        # same object; None builds a private one (host-only tests).
         if admission not in ("fifo", "slo"):
             raise ValueError(f"unknown admission policy {admission!r}")
         self.alloc = allocator or BlockAllocator(

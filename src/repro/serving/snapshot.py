@@ -30,6 +30,7 @@ import os
 import time
 from collections import deque
 
+import ml_dtypes
 import numpy as np
 import jax.numpy as jnp
 
@@ -42,29 +43,32 @@ log = get_logger("snapshot")
 
 FORMAT_VERSION = 2           # v2: prefix-tree state + retained-prefix counts
 _COMPAT_VERSIONS = (1, 2)    # v1 snapshots load (no sharing to restore)
-_BF16_SUFFIX = "#bf16"
 _META_KEY = "meta#json"
+# npz holds no ml_dtypes arrays: bf16 (and fp8 KV codes) are stored as
+# unsigned ints of the same width under a key suffix naming the dtype
+_VIEWS = {"#bf16": (ml_dtypes.bfloat16, np.uint16),
+          "#fp8": (ml_dtypes.float8_e4m3fn, np.uint8)}
 
 
 def _enc(arrays: dict, key: str, arr) -> None:
-    """Stash one array, viewing bfloat16 as uint16 (npz-safe)."""
-    import ml_dtypes
+    """Stash one array, viewing an ml_dtypes array as uints (npz-safe)."""
     a = np.asarray(arr)
-    if a.dtype == ml_dtypes.bfloat16:
-        arrays[key + _BF16_SUFFIX] = a.view(np.uint16)
-    else:
-        arrays[key] = a
+    for suffix, (dt, as_uint) in _VIEWS.items():
+        if a.dtype == dt:
+            arrays[key + suffix] = a.view(as_uint)
+            return
+    arrays[key] = a
 
 
 def _dec(z, key: str) -> np.ndarray:
-    import ml_dtypes
-    if key + _BF16_SUFFIX in z.files:
-        return z[key + _BF16_SUFFIX].view(ml_dtypes.bfloat16)
+    for suffix, (dt, _) in _VIEWS.items():
+        if key + suffix in z.files:
+            return z[key + suffix].view(dt)
     return z[key]
 
 
 def _has(z, key: str) -> bool:
-    return key in z.files or key + _BF16_SUFFIX in z.files
+    return any(key + suffix in z.files for suffix in ("", *_VIEWS))
 
 
 def _req_meta(req: Request) -> dict:

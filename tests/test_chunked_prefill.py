@@ -1,5 +1,6 @@
-"""Chunked prefill: model-layer partial-prefill equivalence, chunk
-work-list slicing, and the decode active-slot write mask."""
+"""Chunked prefill: model-layer partial-prefill equivalence through the
+paged pool, chunk work-list slicing, and the decode active-slot write
+mask."""
 import dataclasses
 
 import numpy as np
@@ -25,7 +26,8 @@ CFG = TransformerConfig(num_layers=2, d_model=64, num_heads=4,
 
 BLOCK = 16
 SMAX = 128
-SLOTS = 3
+NBLK = SMAX // BLOCK            # table width
+POOL = 12                       # usable pool blocks (+1 trash block)
 
 
 @pytest.fixture(scope="module")
@@ -39,33 +41,57 @@ def _full_causal_items(cfg, seq_len):
     return causal_items(cfg.num_heads, nq, kv_of)
 
 
-def _run_chunks(params, cfg, tokens, slot, chunk_lens, sparse=False):
-    """Drive tfm.prefill_chunk over a chunk split; returns (logits, cache)."""
-    cache = tfm.init_cache(cfg, SLOTS, SMAX)
+def _pool(cfg):
+    return tfm.init_paged_cache(cfg, POOL + 1, BLOCK)
+
+
+def _table(seq_len, seed=0):
+    """[NBLK] table mapping the sequence's blocks to scattered pool ids."""
+    nb = -(-seq_len // BLOCK)
+    row = np.full((NBLK,), -1, np.int32)
+    row[:nb] = np.random.default_rng(seed).permutation(POOL)[:nb]
+    return row
+
+
+def _run_chunks(params, cfg, tokens, table, chunk_lens, sparse=False,
+                pool=None):
+    """Drive tfm.prefill_chunk_paged over a chunk split, each chunk padded
+    to whole blocks as the engine pads it; returns (logits, pool)."""
+    pool = _pool(cfg) if pool is None else pool
     S = sum(chunk_lens)
     full = _full_causal_items(cfg, S) if sparse else None
     off = 0
     logits = None
     for c in chunk_lens:
-        toks = tokens[off:off + c][None]
+        nqc = -(-c // BLOCK)
+        toks = np.zeros((1, nqc * BLOCK), np.int32)
+        toks[0, :c] = tokens[off:off + c]
         items = None
         if sparse:
-            nqc = -(-c // BLOCK)
-            it = chunk_items(full, off // BLOCK, nqc,
-                             pad_to=len(full))
+            it = chunk_items(full, off // BLOCK, nqc, pad_to=len(full))
             items = np.stack([it] * cfg.num_layers)
-        logits, cache = tfm.prefill_chunk(
-            params, cache, jnp.asarray(toks), slot, off, cfg,
+        logits, pool = tfm.prefill_chunk_paged(
+            params, pool, jnp.asarray(toks), jnp.asarray(table), off, cfg,
             kv_len=off + c, sparse_items=items, last_index=c - 1)
         off += c
-    return logits, cache
+    return logits, pool
+
+
+def _seq_rows(pool, table, seq_len):
+    """[L, 2, Hkv, seq_len, Dh] rows of one sequence, read through its
+    table."""
+    nb = -(-seq_len // BLOCK)
+    blocks = np.asarray(pool)[:, :, table[:nb]]      # [L, 2, nb, Hkv, blk, D]
+    L, two, _, hkv, _, dh = blocks.shape
+    rows = np.moveaxis(blocks, 2, 3).reshape(L, two, hkv, nb * BLOCK, dh)
+    return rows[:, :, :, :seq_len]
 
 
 @pytest.mark.parametrize("sparse", [False, True])
 @pytest.mark.parametrize("chunk_lens", [(80,), (32, 48), (16, 32, 32), (32, 33)])
 def test_prefill_chunk_matches_monolithic(params, sparse, chunk_lens):
     """Any block-aligned chunk split reproduces the monolithic prefill:
-    same last-token logits, same cache rows."""
+    same last-token logits, same K/V rows in the sequence's blocks."""
     S = sum(chunk_lens)
     tokens = np.random.default_rng(0).integers(0, 256, size=(S,)).astype(
         np.int32)
@@ -73,34 +99,32 @@ def test_prefill_chunk_matches_monolithic(params, sparse, chunk_lens):
     ref_logits, ref_cache = tfm.prefill(
         params, jnp.asarray(tokens[None]), CFG, cache_len=SMAX,
         sparse_items=items)
-    slot = 1
-    logits, cache = _run_chunks(params, CFG, tokens, slot, chunk_lens,
-                                sparse=sparse)
+    table = _table(S)
+    logits, pool = _run_chunks(params, CFG, tokens, table, chunk_lens,
+                               sparse=sparse)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
                                rtol=2e-4, atol=2e-4)
-    got = np.asarray(cache)[:, :, slot, :, :S]
+    got = _seq_rows(pool, table, S)
     want = np.asarray(ref_cache)[:, :, 0, :, :S]
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
 def test_prefill_chunk_untouched_slots(params):
-    """Chunked prefill into one slot leaves every other slot's cache rows
-    exactly as they were."""
+    """Chunked prefill writes only its own blocks: every other pool block,
+    the trash block included, stays exactly as it was."""
     tokens = np.random.default_rng(1).integers(0, 256, size=(48,))
-    cache0 = tfm.init_cache(CFG, SLOTS, SMAX) + 3.0
-    cache = cache0
-    off = 0
-    for c in (16, 32):
-        _, cache = tfm.prefill_chunk(
-            params, cache, jnp.asarray(tokens[off:off + c][None]), 2, off,
-            CFG, kv_len=off + c, last_index=c - 1)
-        off += c
-    got = np.asarray(cache)
-    want = np.asarray(cache0)
-    for s in range(SLOTS):
-        if s == 2:
-            continue
-        np.testing.assert_array_equal(got[:, :, s], want[:, :, s])
+    pool0 = _pool(CFG) + 3.0
+    table = _table(48, seed=1)
+    _, pool = _run_chunks(params, CFG, tokens, table, (16, 32), pool=pool0)
+    got = np.asarray(pool)
+    want = np.asarray(pool0)
+    own = set(table[table >= 0].tolist())
+    assert len(own) == 3
+    for b in range(POOL + 1):
+        if b in own:
+            assert not np.array_equal(got[:, :, b], want[:, :, b])
+        else:
+            np.testing.assert_array_equal(got[:, :, b], want[:, :, b])
 
 
 def test_prefill_chunk_scan_loop_mode(params):
@@ -111,7 +135,8 @@ def test_prefill_chunk_scan_loop_mode(params):
         np.int32)
     ref_logits, _ = tfm.prefill(params_scan, jnp.asarray(tokens[None]),
                                 cfg_scan, cache_len=SMAX)
-    logits, _ = _run_chunks(params_scan, cfg_scan, tokens, 0, (32, 32))
+    logits, _ = _run_chunks(params_scan, cfg_scan, tokens, _table(64),
+                            (32, 32))
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
                                rtol=2e-4, atol=2e-4)
 
@@ -124,7 +149,7 @@ def test_prefill_chunk_local_window(params):
         np.int32)
     ref_logits, _ = tfm.prefill(params_l, jnp.asarray(tokens[None]), cfg_l,
                                 cache_len=SMAX)
-    logits, _ = _run_chunks(params_l, cfg_l, tokens, 0, (16, 48))
+    logits, _ = _run_chunks(params_l, cfg_l, tokens, _table(64), (16, 48))
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
                                rtol=2e-4, atol=2e-4)
 
@@ -179,9 +204,13 @@ class TestWorklistChunkView:
                                    rtol=1e-6, atol=1e-6)
 
 
+SLOTS = 3
+
+
 def test_decode_step_active_mask_protects_slots(params):
     """A batched decode step must not mutate cache rows of slots marked
-    inactive (freed, or mid-chunked-prefill in a mixed tick)."""
+    inactive (freed, or mid-chunked-prefill in a mixed tick).  Checks the
+    contiguous ``decode_step`` the pod dry-run still lowers."""
     cache = tfm.init_cache(CFG, SLOTS, SMAX) + 1.0
     token = jnp.asarray(np.arange(SLOTS), jnp.int32)
     pos = jnp.asarray([5, 0, 9], jnp.int32)

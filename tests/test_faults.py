@@ -3,7 +3,7 @@
 The load-bearing contracts:
 
 - a DISABLED injector is bitwise-invisible: greedy tokens identical to a
-  no-injector run across attention modes, cache layouts, and KV dtypes;
+  no-injector run across attention modes and KV dtypes;
 - any single injected fault is absorbed structurally: the victim surfaces
   as ``failed`` with a ``fail_reason`` (or transparently heals), every
   non-victim request completes with UNCHANGED greedy tokens, and the
@@ -73,11 +73,8 @@ def _mk(params, profile, *, layout="paged", kv_dtype="bf16",
                   prefill_chunk_tokens=128, cache_layout=layout,
                   kv_dtype=kv_dtype, admission="fifo",
                   preemption=preemption, num_model_shards=shards,
-                  audit_every=2)
-    if layout == "paged":
-        kwargs.update(num_slots=4, num_kv_blocks=5 if tight else None)
-    else:
-        kwargs.update(num_slots=2 if tight else 4)
+                  audit_every=2, num_slots=4,
+                  num_kv_blocks=5 if tight else None)
     kwargs.update(kw)
     return Engine(cfg, params, EngineConfig(**kwargs),
                   profile=profile if attention == "sparse" else None,
@@ -97,13 +94,13 @@ SP = SamplingParams(max_tokens=8)
 @pytest.mark.parametrize("policy,layout,kv_dtype", [
     ("sparse", "paged", "bf16"),
     ("sparse", "paged", "int8"),
-    ("sparse", "contiguous", "bf16"),
-    ("sparse", "contiguous", "int8"),
+    ("sparse", "paged", "fp8"),
     ("dense", "paged", "bf16"),
-    ("dense", "contiguous", "bf16"),
+    ("dense", "paged", "int8"),
+    ("dense", "paged", "fp8"),
     ("windowed", "paged", "bf16"),
     ("windowed", "paged", "int8"),
-    ("windowed", "contiguous", "bf16"),
+    ("windowed", "paged", "fp8"),
 ])
 def test_disabled_injector_bitwise_invisible(params, wparams, profile,
                                              policy, layout, kv_dtype):
@@ -223,8 +220,8 @@ def test_swap_transfer_delay_is_benign(params, profile):
     ("paged", "bf16", "nan"),
     ("paged", "int8", "nan"),
     ("paged", "bf16", "inf"),
-    ("contiguous", "bf16", "nan"),
-    ("contiguous", "int8", "nan"),
+    ("paged", "int8", "inf"),
+    ("paged", "fp8", "nan"),
 ])
 def test_kv_corruption_quarantines_only_victim(params, profile, layout,
                                                kv_dtype, mode):
@@ -384,7 +381,7 @@ def test_engine_periodic_audit_counts(params, profile):
 @pytest.mark.parametrize("layout,kv_dtype", [
     ("paged", "bf16"),
     ("paged", "int8"),
-    ("contiguous", "bf16"),
+    ("paged", "fp8"),
 ])
 def test_checkpoint_restore_resumes_bitwise(params, profile, tmp_path,
                                             layout, kv_dtype):

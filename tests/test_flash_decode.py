@@ -515,12 +515,15 @@ class TestWholePoolOperand:
 
 class TestPackedGreedyParity:
     """End-to-end: the engine's packed-ragged decode produces bitwise-
-    identical greedy tokens to the padded baseline across policy x layout
-    (the §2.8 acceptance matrix)."""
+    identical greedy tokens to the padded baseline across policy x pool
+    (the §2.8 acceptance matrix): a fresh pool, and a pool exactly as
+    large as the prompts need whose blocks were recycled out of order."""
 
-    @pytest.mark.parametrize("layout", ["contiguous", "paged"])
+    @pytest.mark.parametrize("recycled", [
+        pytest.param(False, id="paged"), pytest.param(True, id="recycled")])
     @pytest.mark.parametrize("policy", ["dense", "sparse", "windowed"])
-    def test_packed_tokens_equal_padded(self, policy, layout):
+    def test_packed_tokens_equal_padded(self, policy, recycled,
+                                        recycle_pool):
         from repro.core.sparsity import synthetic_head_curves
         from repro.models.transformer import TransformerConfig, init_params
         from repro.serving import Engine, EngineConfig, SamplingParams
@@ -541,9 +544,12 @@ class TestPackedGreedyParity:
             eng = Engine(cfg, params,
                          EngineConfig(attention=attention,
                                       budget_per_head=128, max_seq_len=512,
-                                      num_slots=4, cache_layout=layout,
-                                      decode_worklist=mode),
+                                      num_slots=4, decode_worklist=mode,
+                                      # the prompts' 1 + 1 + 2 blocks
+                                      num_kv_blocks=4 if recycled else None),
                          profile=profile if attention == "sparse" else None)
+            if recycled:
+                recycle_pool(eng)
             outs[mode] = [r.generated for r in eng.serve(prompts, sp)]
         assert outs["packed"] == outs["padded"]
 

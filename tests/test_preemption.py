@@ -4,9 +4,10 @@ continuation on resume.
 
 The load-bearing contract: a request that is preempted mid-decode, has its
 KV blocks swapped to the pinned-host tier, and is later resumed must emit
-EXACTLY the greedy tokens of an uninterrupted run — on both cache layouts,
-both prefill modes, and across a plan-epoch head move that lands between
-its swap-out and swap-in (the host copy must be re-arranged exactly once).
+EXACTLY the greedy tokens of an uninterrupted run — on a fresh pool and on
+one whose blocks were recycled out of order, in both prefill modes, and
+across a plan-epoch head move that lands between its swap-out and swap-in
+(the host copy must be re-arranged exactly once).
 """
 import dataclasses
 
@@ -19,6 +20,9 @@ from repro.core.sparsity import synthetic_head_curves
 from repro.models.transformer import TransformerConfig, init_params
 from repro.serving import Engine, EngineConfig, SamplingParams
 from repro.serving.scheduler import Request
+
+# a fresh pool, or one whose free list a warm-up serve left out of order
+POOLS = [pytest.param(False, id="paged"), pytest.param(True, id="recycled")]
 
 CFG = TransformerConfig(num_layers=2, d_model=64, num_heads=4,
                         num_kv_heads=2, d_ff=128, vocab_size=256,
@@ -40,27 +44,22 @@ def _prompts(lens=(100, 90, 80)):
     return [rng.integers(0, CFG.vocab_size, size=(n,)) for n in lens]
 
 
-def _mk(params, profile, layout, prefill_mode, *, preemption=True,
-        tight=True, shards=1):
+def _mk(params, profile, prefill_mode, *, preemption=True, tight=True,
+        shards=1):
     """Tight geometry forces preemption when the third request arrives;
     ample geometry (tight=False) is the uninterrupted baseline."""
     kw = dict(attention="sparse", budget_per_head=256, block=64, floor=64,
               max_seq_len=512, prefill_mode=prefill_mode,
-              prefill_chunk_tokens=128, cache_layout=layout,
-              admission="fifo", preemption=preemption,
-              num_model_shards=shards)
-    if layout == "paged":
-        kw.update(num_slots=4, num_kv_blocks=5 if tight else None)
-    else:
-        kw.update(num_slots=2 if tight else 4)
+              prefill_chunk_tokens=128, admission="fifo",
+              preemption=preemption, num_model_shards=shards, num_slots=4,
+              num_kv_blocks=5 if tight else None)
     return Engine(CFG, params, EngineConfig(**kw), profile=profile)
 
 
-def _baseline_tokens(params, profile, layout, prefill_mode, prompts, sp,
-                     shards=1):
+def _baseline_tokens(params, profile, prefill_mode, prompts, sp, shards=1):
     """Greedy tokens from an uninterrupted run on ample capacity."""
-    eng = _mk(params, profile, layout, prefill_mode, preemption=False,
-              tight=False, shards=shards)
+    eng = _mk(params, profile, prefill_mode, preemption=False, tight=False,
+              shards=shards)
     done = eng.serve(prompts, sp)
     return {r.rid: list(r.generated) for r in done}
 
@@ -117,16 +116,18 @@ def _drive_interrupt(eng, prompts, sp, *, interrupt_tick=6,
 
 class TestPreemptResumeParity:
     @pytest.mark.parametrize("prefill_mode", ["chunked", "monolithic"])
-    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
+    @pytest.mark.parametrize("recycled", POOLS)
     def test_bitwise_parity_after_swap_roundtrip(self, params, profile,
-                                                 layout, prefill_mode):
+                                                 recycled, prefill_mode,
+                                                 recycle_pool):
         """Preempt a decoding batch request, swap its KV to host, resume:
         every request's greedy tokens match an uninterrupted run."""
         prompts = _prompts()
         sp = SamplingParams(max_tokens=12)
-        frozen = _baseline_tokens(params, profile, layout, prefill_mode,
-                                  prompts, sp)
-        eng = _mk(params, profile, layout, prefill_mode)
+        frozen = _baseline_tokens(params, profile, prefill_mode, prompts, sp)
+        eng = _mk(params, profile, prefill_mode)
+        if recycled:
+            recycle_pool(eng)
         got, b = _drive_interrupt(eng, prompts, sp)
         assert b.stats.preempted >= 1, "tight pool never forced preemption"
         assert b.stats.resumed >= 1, "swapped victim never resumed"
@@ -156,9 +157,8 @@ class TestPreemptResumeParity:
         prompts = [rng.integers(0, CFG.vocab_size, size=(n,))
                    for n in (300, 80)]
         sp = SamplingParams(max_tokens=16)
-        frozen = _baseline_tokens(params, profile, "paged", "chunked",
-                                  prompts, sp)
-        eng = _mk(params, profile, "paged", "chunked")
+        frozen = _baseline_tokens(params, profile, "chunked", prompts, sp)
+        eng = _mk(params, profile, "chunked")
         b = eng.make_batcher()
         pf, df = eng.step_fns(sp)
         # 300-token prompt = 3 chunks, reserving the whole 5-block pool
@@ -186,9 +186,9 @@ class TestPreemptResumeParity:
         kv order exactly once, keeping resume bitwise-identical."""
         prompts = _prompts()
         sp = SamplingParams(max_tokens=12)
-        frozen = _baseline_tokens(params, profile, "paged", "chunked",
-                                  prompts, sp, shards=2)
-        eng = _mk(params, profile, "paged", "chunked", shards=2)
+        frozen = _baseline_tokens(params, profile, "chunked", prompts, sp,
+                                  shards=2)
+        eng = _mk(params, profile, "chunked", shards=2)
         got, b = _drive_interrupt(eng, prompts, sp,
                                   straddle_plan_fn=_swapped_plan)
         assert eng.epoch == 1 and eng.replans == 1
@@ -196,13 +196,16 @@ class TestPreemptResumeParity:
         assert b.stats.resumed >= 1
         assert got == frozen, "epoch-straddling swap diverged"
 
-    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
-    def test_no_epoch_change_means_no_remap(self, params, profile, layout):
+    @pytest.mark.parametrize("recycled", POOLS)
+    def test_no_epoch_change_means_no_remap(self, params, profile, recycled,
+                                            recycle_pool):
         """Without a replan in the residency window the host copy must be
         scattered back untouched (remap is not a no-op re-gather)."""
         prompts = _prompts()
         sp = SamplingParams(max_tokens=12)
-        eng = _mk(params, profile, layout, "chunked")
+        eng = _mk(params, profile, "chunked")
+        if recycled:
+            recycle_pool(eng)
         _, b = _drive_interrupt(eng, prompts, sp)
         assert b.stats.resumed >= 1
         assert eng.swap_stats["epoch_remaps"] == 0
@@ -241,6 +244,5 @@ class TestSchedulerOverloadPaths:
         got, b = _drive_interrupt(eng, prompts, sp)
         assert eng.swap_stats["swapped_out"] == 0
         assert b.stats.completed == 3
-        frozen = _baseline_tokens(params, profile, "paged", "chunked",
-                                  prompts, sp)
+        frozen = _baseline_tokens(params, profile, "chunked", prompts, sp)
         assert got == frozen

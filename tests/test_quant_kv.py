@@ -1,6 +1,6 @@
 """Quantized KV-cache pool (DESIGN.md §2.12): round-trip error bounds,
 strict bf16 opt-in, and greedy-token parity at int8 across every serving
-axis — dense/sparse/windowed x contiguous/paged x packed/padded — plus
+axis — dense/sparse/windowed x fresh/recycled pool x packed/padded — plus
 preempt/swap/resume and a plan-epoch head move straddling host residency
 (the scales must travel with their blocks through every gather).
 
@@ -29,9 +29,6 @@ from repro.models.transformer import TransformerConfig, init_params
 from repro.serving import Engine, EngineConfig, SamplingParams
 from repro.serving.scheduler import Request
 
-# block_kv == engine block (64) so the SAME config drives both layouts:
-# the contiguous quantized layout requires one scale grid (engine block ==
-# model block_kv); paged tiles scales at the engine block regardless.
 CFG = TransformerConfig(num_layers=2, d_model=64, num_heads=4,
                         num_kv_heads=2, d_ff=128, vocab_size=256,
                         layer_loop="unroll", block_kv=64)
@@ -176,7 +173,7 @@ class TestOptIn:
         sp = SamplingParams(max_tokens=8)  # greedy
         mk = lambda **kw: Engine(CFG, params, EngineConfig(
             attention="sparse", budget_per_head=128, block=64, floor=64,
-            max_seq_len=512, num_slots=4, cache_layout="paged", **kw),
+            max_seq_len=512, num_slots=4, **kw),
             profile=profile)
         a = [r.generated for r in mk().serve(prompts, sp)]
         b = [r.generated for r in mk(kv_dtype="bf16").serve(prompts, sp)]
@@ -190,12 +187,12 @@ class TestOptIn:
 class TestInt8ParityMatrix:
     @pytest.mark.parametrize("policy", ["dense", "sparse", "windowed"])
     def test_layout_and_worklist_invariance(self, params, wparams, profile,
-                                            policy):
-        """At kv_dtype="int8" all four (layout x worklist) engines emit
+                                            policy, recycle_pool):
+        """At kv_dtype="int8" all four (pool x worklist) engines emit
         IDENTICAL greedy tokens for a given policy: quantization error is
-        a property of the stored blocks, not of the path that reads them.
-        Monolithic prefill so contiguous/paged quantize identical blocks
-        (chunked contiguous stages full-precision within a chunk)."""
+        a property of the stored blocks, not of where they lie or of the
+        path that reads them.  One pool is fresh; the other holds exactly
+        the prompts' blocks, recycled out of order by a warm-up serve."""
         cfg = WCFG if policy == "windowed" else CFG
         p = wparams if policy == "windowed" else params
         attention = "dense" if policy == "dense" else "sparse"
@@ -203,17 +200,21 @@ class TestInt8ParityMatrix:
                    for i, n in enumerate((40, 300, 130, 70))]
         sp = SamplingParams(max_tokens=8)  # greedy
         outs = {}
-        for layout in ("contiguous", "paged"):
+        for pool in ("fresh", "recycled"):
             for wmode in ("packed", "padded"):
                 eng = Engine(cfg, p, EngineConfig(
                     attention=attention, budget_per_head=128,
                     block=64, floor=64, max_seq_len=512, num_slots=4,
-                    cache_layout=layout, decode_worklist=wmode,
-                    prefill_mode="monolithic", kv_dtype="int8"),
+                    # the prompts' 1 + 5 + 3 + 2 blocks
+                    num_kv_blocks=11 if pool == "recycled" else None,
+                    decode_worklist=wmode, prefill_mode="monolithic",
+                    kv_dtype="int8"),
                     profile=profile if attention == "sparse" else None)
-                outs[(layout, wmode)] = [r.generated
-                                         for r in eng.serve(prompts, sp)]
-        first = outs[("contiguous", "packed")]
+                if pool == "recycled":
+                    recycle_pool(eng)
+                outs[(pool, wmode)] = [r.generated
+                                       for r in eng.serve(prompts, sp)]
+        first = outs[("fresh", "packed")]
         assert all(len(t) == 8 for t in first)
         for key, got in outs.items():
             assert got == first, f"{policy}/{key} diverged at int8"
@@ -229,22 +230,18 @@ def _prompts(lens=(100, 90, 80)):
 
 
 def _mk(params, profile, kv_dtype, *, preemption=True, tight=True,
-        shards=1, layout="paged"):
+        shards=1):
     kw = dict(attention="sparse", budget_per_head=256, block=64, floor=64,
-              max_seq_len=512, prefill_mode="monolithic",
-              cache_layout=layout, admission="fifo", preemption=preemption,
-              num_model_shards=shards, kv_dtype=kv_dtype)
-    if layout == "paged":
-        kw.update(num_slots=4, num_kv_blocks=5 if tight else None)
-    else:
-        kw.update(num_slots=2 if tight else 4)
+              max_seq_len=512, prefill_mode="monolithic", admission="fifo",
+              preemption=preemption, num_model_shards=shards,
+              kv_dtype=kv_dtype, num_slots=4,
+              num_kv_blocks=5 if tight else None)
     return Engine(CFG, params, EngineConfig(**kw), profile=profile)
 
 
-def _baseline_tokens(params, profile, kv_dtype, prompts, sp, *, shards=1,
-                     layout="paged"):
+def _baseline_tokens(params, profile, kv_dtype, prompts, sp, *, shards=1):
     eng = _mk(params, profile, kv_dtype, preemption=False, tight=False,
-              shards=shards, layout=layout)
+              shards=shards)
     done = eng.serve(prompts, sp)
     return {r.rid: list(r.generated) for r in done}
 
@@ -297,21 +294,22 @@ def _drive_interrupt(eng, prompts, sp, *, interrupt_tick=6,
 
 
 class TestInt8PreemptResume:
-    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
-    def test_swap_roundtrip_parity_at_int8(self, params, profile, layout):
-        """Preempt a decoding int8 request, swap its CODES + SCALES to
-        host, resume: greedy tokens match an uninterrupted int8 run."""
+    @pytest.mark.parametrize("kv_dtype", [
+        pytest.param("int8", id="paged"), pytest.param("fp8", id="paged-fp8")])
+    def test_swap_roundtrip_parity_at_int8(self, params, profile, kv_dtype):
+        """Preempt a decoding int8 (or fp8) request, swap its CODES +
+        SCALES to host, resume: greedy tokens match an uninterrupted run at
+        the same dtype."""
         prompts = _prompts()
         sp = SamplingParams(max_tokens=12)
-        frozen = _baseline_tokens(params, profile, "int8", prompts, sp,
-                                  layout=layout)
-        eng = _mk(params, profile, "int8", layout=layout)
+        frozen = _baseline_tokens(params, profile, kv_dtype, prompts, sp)
+        eng = _mk(params, profile, kv_dtype)
         got, b = _drive_interrupt(eng, prompts, sp)
         assert b.stats.preempted >= 1 and b.stats.resumed >= 1
         st = eng.swap_stats
         assert st["swapped_out"] >= 1
         assert st["bytes_in"] == st["bytes_out"] > 0
-        assert got == frozen, "int8 preempt/resume diverged"
+        assert got == frozen, f"{kv_dtype} preempt/resume diverged"
         assert b.alloc.conserves()
         assert eng._host_swaps == {}
 

@@ -17,6 +17,10 @@ from repro.models.transformer import TransformerConfig, init_params
 from repro.serving import Engine, EngineConfig, SamplingParams
 from repro.serving.scheduler import ContinuousBatcher, Request
 
+# the epoch swap re-gathers the pool's codes and, quantized, its scales
+KV_DTYPES = [pytest.param("bf16", id="paged"),
+             pytest.param("int8", id="paged-int8")]
+
 CFG = TransformerConfig(num_layers=2, d_model=64, num_heads=4,
                         num_kv_heads=2, d_ff=128, vocab_size=256,
                         layer_loop="unroll")
@@ -174,9 +178,9 @@ class TestEngineEpochSwap:
         assert not b.busy
         return {r.rid: r.generated for r in done}, tokens_before_swap
 
-    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
+    @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
     def test_head_move_swap_is_bitwise_invisible(self, params, profile,
-                                                 layout):
+                                                 kv_dtype):
         """Full budgets + a forced head MOVE mid-run: params re-permute,
         the resident cache's kv-head axis re-gathers, and greedy tokens
         stay bitwise identical to the frozen engine — the swap machinery
@@ -186,7 +190,7 @@ class TestEngineEpochSwap:
             CFG, params,
             EngineConfig(attention="sparse", budget_per_head=512,
                          max_seq_len=512, num_slots=4, num_model_shards=2,
-                         cache_layout=layout), profile=profile)
+                         kv_dtype=kv_dtype), profile=profile)
         frozen, _ = self._drive(mk(), sp, (50, 90, 130))
         eng = mk()
         swapped, _ = self._drive(eng, sp, (50, 90, 130), swap_tick=5,
@@ -195,8 +199,8 @@ class TestEngineEpochSwap:
         assert eng.epoch == 1 and eng.replans == 1
         assert eng.decode_stats["last"]["epoch"] == 1
 
-    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
-    def test_budget_swap_mid_batch(self, params, profile, layout):
+    @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
+    def test_budget_swap_mid_batch(self, params, profile, kv_dtype):
         """The acceptance path: a mixed batch swaps onto NEW BUDGETS mid
         run.  Tokens sampled before the swap are bitwise identical to the
         frozen engine's; afterwards decode runs under the new epoch
@@ -207,7 +211,7 @@ class TestEngineEpochSwap:
             CFG, params,
             EngineConfig(attention="sparse", budget_per_head=256,
                          max_seq_len=512, num_slots=4,
-                         cache_layout=layout), profile=profile)
+                         kv_dtype=kv_dtype), profile=profile)
         frozen, _ = self._drive(mk(), sp, lens)
         eng = mk()
         out, before = self._drive(eng, sp, lens, swap_tick=6,
@@ -324,24 +328,6 @@ class TestEngineEpochSwap:
                 for b in range(CFG.num_heads):
                     if sel_blocks[a] < sel_blocks[b]:
                         assert f[a] < f[b] + 1e-6, (l, a, b, f, sel_blocks)
-
-    def test_telemetry_contiguous_non_block_multiple_seq(self, params,
-                                                         profile):
-        """Regression: a contiguous cache with max_seq_len not a block
-        multiple used to crash the probe's block reshape."""
-        eng = Engine(CFG, params,
-                     EngineConfig(attention="sparse", budget_per_head=128,
-                                  max_seq_len=320, num_slots=2,
-                                  cache_layout="contiguous",
-                                  prefill_mode="monolithic",
-                                  telemetry_every=1),
-                     profile=profile)
-        done = eng.serve([np.arange(200) % 256],
-                         SamplingParams(max_tokens=6))
-        assert len(done[0].generated) == 6
-        assert eng.telemetry.total_samples > 0
-        assert np.isfinite(eng.telemetry.rec_ema[eng.telemetry.count > 0]
-                           ).all()
 
     def test_drift_threshold_gate(self, params, profile):
         """drift_threshold=inf never replans; the drift reading is still

@@ -197,9 +197,12 @@ class TestStripedEngineConfig:
         assert eng.kv.num_blocks == 12
         assert eng.kv.stripes == 3 and eng.kv.stripe_size == 4
 
-    def test_contiguous_layout_rejects_striping(self, params, profile):
-        with pytest.raises(AssertionError):
-            _mk(params, profile, seq_shards=2, cache_layout="contiguous")
+    def test_contiguous_layout_is_rejected(self, params, profile):
+        """The paged pool is the only KV layout: naming the removed
+        contiguous one fails at construction, striped or not."""
+        for ss in (1, 2):
+            with pytest.raises(ValueError, match="removed"):
+                _mk(params, profile, seq_shards=ss, cache_layout="contiguous")
 
     def test_stats_expose_per_axis_imbalance(self, params, profile):
         eng = _mk(params, profile, seq_shards=2)

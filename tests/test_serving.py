@@ -198,9 +198,22 @@ class TestEngineHotPath:
         assert (ids == 512 // 128 - 1).any(-1).all()
 
 
+# greedy tokens of the four prompts below, recorded when a contiguous
+# slot cache still existed and served them identically to the pool
+SERVE_TOKENS = {
+    "G": [[19, 112, 19, 112, 19, 112, 19, 239],
+          [52, 69, 69, 69, 142, 142, 142, 142],
+          [222, 212, 217, 155, 33, 36, 36, 36],
+          [52, 52, 110, 110, 110, 110, 110, 110]],
+    "GL": [[19, 112, 19, 112, 19, 112, 19, 239],
+           [69, 80, 40, 80, 32, 52, 69, 69],
+           [222, 212, 217, 155, 33, 36, 36, 36],
+           [52, 52, 110, 110, 110, 110, 110, 110]],
+}
+
+
 class TestPagedLayout:
-    """cache_layout="paged" (the default) vs the contiguous parity
-    baseline: identical greedy tokens on the chunked-prefill + decode
+    """The paged pool's greedy tokens on the chunked-prefill + decode
     serving path, and token-granular admission."""
 
     @pytest.mark.parametrize("attn,pattern,mode", [
@@ -209,8 +222,8 @@ class TestPagedLayout:
         ("dense", "GL", "chunked"),      # windowed (local) layers
         ("sparse", "G", "monolithic"),   # whole-prompt scatter merge
     ])
-    def test_paged_matches_contiguous_serve(self, params, profile, attn,
-                                            pattern, mode):
+    def test_paged_serve_matches_recorded_tokens(self, params, profile, attn,
+                                                 pattern, mode):
         cfg = (CFG if pattern == "G"
                else TransformerConfig(
                    num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
@@ -220,17 +233,14 @@ class TestPagedLayout:
             jax.random.PRNGKey(0), cfg)
         prompts = [np.random.default_rng(i).integers(0, 256, size=(n,))
                    for i, n in enumerate((40, 300, 130, 70))]
-        sp = SamplingParams(max_tokens=8)  # greedy
-        outs = {}
-        for layout in ("contiguous", "paged"):
-            eng = Engine(
-                cfg, p,
-                EngineConfig(attention=attn, budget_per_head=512,
-                             max_seq_len=512, num_slots=4,
-                             prefill_mode=mode, cache_layout=layout),
-                profile=profile if attn == "sparse" else None)
-            outs[layout] = [r.generated for r in eng.serve(prompts, sp)]
-        assert outs["paged"] == outs["contiguous"]
+        eng = Engine(
+            cfg, p,
+            EngineConfig(attention=attn, budget_per_head=512,
+                         max_seq_len=512, num_slots=4, prefill_mode=mode),
+            profile=profile if attn == "sparse" else None)
+        got = [r.generated for r in eng.serve(prompts,
+                                              SamplingParams(max_tokens=8))]
+        assert got == SERVE_TOKENS[pattern]
 
     def test_paged_admission_is_block_granular(self, params, profile):
         """With a pool smaller than num_slots * max_seq_len, admission is
@@ -250,11 +260,10 @@ class TestPagedLayout:
         assert alloc.conserves()
 
     def test_paged_pool_is_token_not_slot_bound(self, params, profile):
-        """The same pool bytes hold MORE short sequences than the
-        contiguous layout's slot count — the capacity headline, at engine
-        granularity (benchmarks/serving.py measures the full curve)."""
-        # contiguous: 2 slots x 512 tokens = 8 blocks of HBM, 2 sequences
-        # paged: the same 8 blocks hold 4 x (70 + 8) -> 4 x 1 block
+        """Eight blocks (two max_seq_len sequences' worth of HBM) hold
+        four short sequences at once: capacity counts tokens, not
+        max-length slots."""
+        # the 8 blocks hold 4 x (70 + 8) -> 4 x 1 block
         eng = Engine(CFG, params,
                      EngineConfig(attention="sparse", budget_per_head=256,
                                   max_seq_len=512, num_slots=4,
@@ -271,7 +280,7 @@ class TestPagedLayout:
             b.tick(pf, df)
             peak = max(peak, len(b._slot_of))   # sequences resident at once
         assert b.stats.completed == 4
-        # all four were resident at once on 2-contiguous-slots' bytes
+        # all four were resident at once on two max-length rows' bytes
         assert peak == 4
 
 

@@ -19,7 +19,6 @@ import pytest
 
 from repro.core.sparsity import synthetic_head_curves
 from repro.core.worklist import DEC_FIELDS
-from repro.models import transformer as tfm
 from repro.models.transformer import TransformerConfig, init_params
 from repro.serving import Engine, EngineConfig, SamplingParams
 
@@ -171,12 +170,6 @@ def decode_dense(eng):
                               _table(eng), act)
 
 
-def prefill(eng):
-    b = eng.ecfg.block
-    return eng._prefill_fn(b), (eng.params, eng.cache,
-                                jnp.zeros((1, b), jnp.int32), 0, b - 1)
-
-
 def prefill_paged(eng):
     b = eng.ecfg.block
     row = jnp.full((eng.kv.table_width,), -1, jnp.int32)
@@ -200,35 +193,20 @@ def prefill_chunk_dense(eng):
     return eng._prefill_chunk_fn(eng.ecfg.block), _chunk_args(eng)
 
 
-def prefill_chunk_contiguous(eng):
-    b = eng.ecfg.block
-    items = jnp.asarray(eng._chunk_worklists(b, 0, b))
-    return eng._prefill_chunk_fn(b), (eng.params, eng.cache,
-                                      jnp.zeros((1, b), jnp.int32), 0, 0, b,
-                                      b - 1, items)
-
-
-def merge_staging(eng):
-    eng._staging = tfm.init_cache(CFG, 1, eng.ecfg.max_seq_len)
-    eng._set_cache(eng._merge_staging(0))
-    return eng._merge_jit, (eng.cache, eng._staging, 0)
-
-
 def kv_permute(eng):
     tbl = jnp.zeros((CFG.num_layers, CFG.num_kv_heads), jnp.int32)
     return eng._kv_permute_fn(), (eng.cache, tbl)
 
 
 def swap_gather(eng):
-    return eng._swap_gather_fn(("paged", 1)), (eng.cache,
-                                               jnp.zeros((1,), jnp.int32))
+    return eng._swap_gather_fn(1), (eng.cache, jnp.zeros((1,), jnp.int32))
 
 
 def swap_scatter(eng):
     L, two, _, hkv, blk, dh = eng.cache.shape
     blocks = jnp.zeros((L, two, 1, hkv, blk, dh), eng.cache.dtype)
-    return eng._swap_scatter_fn(("paged", 1)), (eng.cache, blocks,
-                                                jnp.zeros((1,), jnp.int32))
+    return eng._swap_scatter_fn(1), (eng.cache, blocks,
+                                     jnp.zeros((1,), jnp.int32))
 
 
 def telemetry_probe(eng):
@@ -244,13 +222,10 @@ STEPS = {
     decode_packed: {},
     decode_padded: {},
     decode_dense: {"attention": "dense"},
-    prefill: {"cache_layout": "contiguous"},
     prefill_paged: {},
     prefill_chunk: {},
     prefill_chunk_b256: {},
     prefill_chunk_dense: {"attention": "dense"},
-    prefill_chunk_contiguous: {"cache_layout": "contiguous"},
-    merge_staging: {"cache_layout": "contiguous"},
     kv_permute: {},
     swap_gather: {},
     swap_scatter: {},
@@ -275,7 +250,6 @@ PROGRAM = {
     prefill_chunk: "jit_prefill_chunk_b128",
     prefill_chunk_b256: "jit_prefill_chunk_b256",
     prefill_chunk_dense: "jit_prefill_chunk_dense_b128",
-    prefill_chunk_contiguous: "jit_prefill_chunk_b128",
 }
 
 
