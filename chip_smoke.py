@@ -12,7 +12,8 @@ seed.  Phases, in order; any failure exits non-zero:
    ever served on the CPU.
 2. kernel check: the Pallas paged decode kernel (the engine's default
    executor) against the float32 jnp reference, both on the chip, at
-   SmolLM widths over a 4096-block pool, for bf16 and int8 pools.
+   SmolLM widths over a 4096-block pool, for bf16 and int8 pools, in its
+   per-layer form and in the whole-pool form the decode step takes.
 3. serve, sparse: ``Engine`` + ``ContinuousBatcher`` (paged pool, chunked
    prefill, packed decode) on 16 seeded requests of 1k-6k prompt tokens
    and 32 new tokens each; every request must complete, and one decode
@@ -157,32 +158,44 @@ def kernel_check(kv_dtype: str, *, cfg, num_blocks: int, slots: int,
     wl = pack_decode_items(ids, num_shards=1, block=BLOCK)
     items = jnp.asarray(wl.items[0])
 
-    def run_kernel(q, k_pool, v_pool, items, table, pos, ks, vs):
+    def run_kernel(q, k_pool, v_pool, items, table, pos, ks, vs,
+                   layer=None):
         return ops.flash_decode_packed_paged(
             q, k_pool, v_pool, items, table, pos, block_kv=BLOCK,
-            partials=True, k_scales=ks, v_scales=vs)[0]
+            partials=True, k_scales=ks, v_scales=vs, layer=layer)[0]
 
-    args = (q, k_pool, v_pool, items, jnp.asarray(table), jnp.asarray(pos),
-            ks, vs)
+    rest = (items, jnp.asarray(table), jnp.asarray(pos), ks, vs)
+    # the decode step's form: layer 1 of a whole two-layer pool, whose
+    # layer 0 holds V and K the other way round
+    whole = (q, jnp.stack([jnp.stack([v_pool, k_pool]),
+                           jnp.stack([k_pool, v_pool])]), None, *rest,
+             jnp.int32(1))
     f = jax.jit(run_kernel)
-    n_kern = lowered_has_kernel(f, *args)
-    require_kernel(n_kern, f"kernel check {kv_dtype}: packed paged decode")
-    out = f(*args)
+    outs = []
+    for form, args in (("per-layer", (q, k_pool, v_pool, *rest)),
+                       ("whole-pool", whole)):
+        n_kern = lowered_has_kernel(f, *args)
+        require_kernel(n_kern, f"kernel check {kv_dtype}: packed paged "
+                       f"decode, {form} form")
+        outs.append(f(*args))
     with jax.default_matmul_precision("highest"):
         ref = flash_decode_paged_reference(
             q.reshape(slots, hkv, H // hkv, D), k_pool, v_pool,
             jnp.asarray(ids), jnp.asarray(table), jnp.asarray(pos),
             block_kv=BLOCK, k_scales=ks, v_scales=vs)[0]
-    out = np.asarray(out, np.float32).reshape(slots, H, D)
     ref = np.asarray(ref, np.float32).reshape(slots, H, D)
-    check(bool(np.isfinite(out).all()), f"kernel check {kv_dtype}: "
-          "non-finite output")
-    err = float(np.abs(out - ref).max())
     bound = decode_err_bound(v_abs)
-    check(err <= bound, f"kernel check {kv_dtype}: max abs err {err!r} > "
-          f"bound {bound!r}")
-    return {"kv_dtype": kv_dtype, "err": err, "bound": bound,
-            "items": int(wl.total_real_items), "kernels": n_kern}
+    errs = []
+    for form, out in zip(("per-layer", "whole-pool"), outs):
+        out = np.asarray(out, np.float32).reshape(slots, H, D)
+        check(bool(np.isfinite(out).all()), f"kernel check {kv_dtype}, "
+              f"{form} form: non-finite output")
+        errs.append(float(np.abs(out - ref).max()))
+        check(errs[-1] <= bound, f"kernel check {kv_dtype}, {form} form: "
+              f"max abs err {errs[-1]!r} > bound {bound!r}")
+    return {"kv_dtype": kv_dtype, "err": max(errs), "errs": errs,
+            "bound": bound, "items": int(wl.total_real_items),
+            "kernels": n_kern}
 
 
 # ---------------------------------------------------------------------------
@@ -294,8 +307,9 @@ def one_chip(meter: CompileMeter) -> None:
         r = kernel_check(kvd, cfg=cfg, num_blocks=4096, slots=8,
                          max_seq=SERVE_KW["max_seq_len"])
         say(f"[kernel {kvd}] packed paged decode, {r['items']} tiles over a "
-            f"4096-block pool: max abs err {r['err']!r} <= bound "
-            f"{r['bound']!r} (Pallas kernels in lowering: {r['kernels']})")
+            f"4096-block pool: max abs err per-layer / whole-pool "
+            f"{r['errs'][0]!r} / {r['errs'][1]!r} <= bound {r['bound']!r} "
+            f"(Pallas kernels in lowering: {r['kernels']})")
     say(f"[kernel] wall {time.perf_counter() - t0:.2f} s, "
         f"{meter.since(snap)}")
 

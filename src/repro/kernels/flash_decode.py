@@ -454,6 +454,7 @@ def _flash_decode_paged_kernel(
     num_kv_heads: int = 0,
     table_width: int = 0,
     layered: bool = False,
+    transposed: bool = False,
 ):
     """Same online-softmax body as :func:`_flash_decode_kernel`, but the
     K/V tiles come from the block POOL, copied by hand at the
@@ -463,7 +464,9 @@ def _flash_decode_paged_kernel(
     shard that does not own the block under a block-sharded pool); invalid
     items copy nothing.  ``layered``: one whole ``[L, 2, N, Hkv, block,
     Dh]`` pool in HBM, and the tiles are copied from layer
-    ``layer_ref[0]`` of it (a fourth scalar-prefetch argument)."""
+    ``layer_ref[0]`` of it (a fourth scalar-prefetch argument).
+    ``transposed``: the pool's tiles are ``[Dh, block]`` (token on lanes),
+    and the two dots contract their other dimension."""
     if layered:
         layer_ref, q_ref, pool_hbm, *rest = rest
         kv_src = lambda phys, head: [pool_hbm.at[layer_ref[0], c, phys, head]
@@ -532,10 +535,11 @@ def _flash_decode_paged_kernel(
         for c in copies:
             c.wait()
         qt = q_ref[0, 0].astype(jnp.float32)   # [G, d]
-        kt = k_buf[i % 2].astype(jnp.float32)  # [block_kv, d]
+        kt = k_buf[i % 2].astype(jnp.float32)  # [block_kv, d] / [d, block_kv]
         vt = v_buf[i % 2].astype(jnp.float32)
+        d_of_tile = 0 if transposed else 1     # the tile's head_dim axis
         s = jax.lax.dot_general(
-            qt, kt, (((1,), (1,)), ((), ())),
+            qt, kt, (((1,), (d_of_tile,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [G, block_kv]
         if quantized:
             # post-dot dequant: per-(physical block, kv head) scale,
@@ -555,7 +559,7 @@ def _flash_decode_paged_kernel(
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, vt, (((1,), (0,)), ((), ())),
+            p, vt, (((1,), (1 - d_of_tile,)), ((), ())),
             preferred_element_type=jnp.float32)
         if quantized:
             pv = pv * vs_ref[sidx]
@@ -600,37 +604,50 @@ def flash_decode_paged_kernel(
     HBM->VMEM — same roofline as the
     contiguous kernel, token-granular memory.
 
-    Two operand forms.  Without ``layer``: one layer's K and V pools
-    ``[N, Hkv, block_kv, D]``, head_dim padded to 128 lanes here.  With
-    ``layer`` (an int32 scalar, traced or not): ``k_pool`` is the whole
-    ``[L, 2, N, Hkv, block_kv, D]`` pool, ``v_pool`` None, and the tiles
-    are copied from ``k_pool[layer, 0 / 1]`` as it lies — no per-layer
-    slice of the pool is made, which requires whole 128-lane tiles
-    (``D % 128 == 0``).  The scales stay one layer's ``[N, Hkv]``."""
+    Three operand forms, picked by shape:
+
+    - without ``layer``: one layer's K and V pools ``[N, Hkv, block_kv,
+      D]``, head_dim padded to 128 lanes here (the sharded islands, the
+      head-parallel path);
+    - with ``layer`` (an int32 scalar, traced or not) ``k_pool`` is the
+      whole ``[L, 2, N, Hkv, block_kv, D]`` pool, ``v_pool`` None, and the
+      tiles are copied from ``k_pool[layer, 0 / 1]`` as it lies: no
+      per-layer slice of the pool is made.  At ``D % 128 == 0`` the tiles
+      are ``[block_kv, D]`` rows;
+    - otherwise (``D`` 64, say) the TPU stores the pool's tiles token on
+      lanes, ``[D, block_kv]``, so the kernel reads the pool's
+      ``[..., D, block_kv]`` transposed view, which is the same bytes,
+      and needs ``block_kv % 128 == 0``.  Neither pool nor query is
+      padded.
+
+    The scales stay one layer's ``[N, Hkv]``."""
     B, hkv, G, dh = q.shape
     assert k_pool.shape[-2] == block_kv, "pool block size != block_kv"
     scale_v = float(dh ** -0.5) if scale is None else float(scale)
     quantized = k_scales is not None
     layered = layer is not None
+    transposed = layered and dh % 128 != 0
 
     pad_g = (-G) % 8        # sublane alignment
-    dh_pad = (-dh) % 128    # lane alignment
+    dh_pad = 0 if transposed else (-dh) % 128    # lane alignment
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad_g), (0, dh_pad)))
     if layered:
         assert v_pool is None and k_pool.ndim == 6, "whole pool, V in it"
-        assert dh_pad == 0, "the whole-pool form needs head_dim % 128 == 0"
-        pools = [k_pool]
+        assert not transposed or block_kv % 128 == 0, \
+            "the whole-pool form needs head_dim or block_kv % 128 == 0"
+        pools = [jnp.swapaxes(k_pool, -1, -2) if transposed else k_pool]
     else:
         pools = [jnp.pad(p, ((0, 0), (0, 0), (0, 0), (0, dh_pad)))
                  for p in (k_pool, v_pool)]
     Gp, dp = G + pad_g, dh + dh_pad
     L = items.shape[0]
     T = table.shape[1]
+    tile_shape = (dp, block_kv) if transposed else (block_kv, dp)
 
     kernel = functools.partial(
         _flash_decode_paged_kernel, scale=scale_v, block_kv=block_kv,
         window=window, quantized=quantized, num_kv_heads=hkv,
-        table_width=T, layered=layered)
+        table_width=T, layered=layered, transposed=transposed)
 
     def bh_index(i, it, *_):
         return (_field(it, i, D_BATCH), _field(it, i, D_KVHEAD), 0, 0)
@@ -669,8 +686,8 @@ def flash_decode_paged_kernel(
             pltpu.VMEM((Gp, dp), jnp.float32),
             pltpu.VMEM((Gp, 1), jnp.float32),
             pltpu.VMEM((Gp, 1), jnp.float32),
-            pltpu.VMEM((2, block_kv, dp), pools[0].dtype),   # K buffers
-            pltpu.VMEM((2, block_kv, dp), pools[-1].dtype),  # V buffers
+            pltpu.VMEM((2, *tile_shape), pools[0].dtype),    # K buffers
+            pltpu.VMEM((2, *tile_shape), pools[-1].dtype),   # V buffers
             pltpu.SemaphoreType.DMA((2, 2)),           # [K/V, buffer]
         ],
     )

@@ -121,7 +121,7 @@ def flash_decode_paged(q, k_pool, v_pool, block_ids, table, pos, *,
     pools ``[N, Hkv, block_kv, D]``, or with ``layer`` the whole ``[L, 2,
     N, Hkv, block_kv, D]`` pool as ``k_pool`` and ``v_pool`` None (the
     kernel then reads layer ``layer``'s tiles from it in place; head_dim
-    a multiple of 128); ``block_ids [B, Hkv, nb]`` int32
+    or block_kv a multiple of 128); ``block_ids [B, Hkv, nb]`` int32
     LOGICAL selected blocks (-1 pad, trailing); ``table [B, T]`` int32
     logical -> pool-global translation (-1 = unmapped, masked); ``pos [B]``
     per-slot last position.  With a quantized pool pass ``k_scales``/

@@ -868,11 +868,14 @@ def decode_step_paged(params, pool, token, pos, table,
     prefix (gathered through the table into one per-sequence view).
     Returns (logits [B, V], new pool).
 
-    The decode kernel's operand follows head_dim: at a multiple of 128 it
-    gets the whole carried pool and the layer index and copies its tiles
-    from ``pool[l]`` as it lies; at 64 it gets ``pool[l, 0]`` and
-    ``pool[l, 1]``, sliced out per layer and padded to 128 lanes (ROADMAP
-    S7b).  The two forms compute the same tiles bit for bit.
+    The decode kernel's operand follows the pool's shape.  At a head_dim
+    of whole 128-lane tiles, or at 128-token blocks, it gets the whole
+    carried pool and the layer index and copies its tiles from ``pool[l]``
+    as it lies: ``[block, Dh]`` rows at head_dim 128, and at head_dim 64
+    the ``[Dh, block]`` tiles the TPU stores (token on lanes).  Any other
+    shape gets ``pool[l, 0]`` and ``pool[l, 1]``, sliced out per layer
+    and padded to 128 lanes.  The forms compute the same tiles bit for
+    bit.
 
     Sequence striping (DESIGN.md §2.11): ``seq_stripes > 1`` emulates the
     2D head x sequence mesh on one device — the pool's usable blocks are
@@ -966,10 +969,12 @@ def decode_step_paged(params, pool, token, pos, table,
 
             pool, scales = rmw(*rmw(pool, scales, 0, k), 1, v)
             ks, vs = scales[li, 0], scales[li, 1]
-        # the kernel's pool operand: at a head_dim of whole 128-lane tiles
-        # it copies layer li's tiles from the carried pool itself; at 64 it
-        # takes the layer's K and V, padded (ROADMAP S7b)
-        if dh % 128 == 0:
+        # the kernel's pool operand: the whole carried pool and the layer
+        # index, from which the kernel copies layer li's tiles itself, as
+        # [block, dh] rows at a head_dim of whole 128-lane tiles, or as the
+        # TPU stores them, [dh, block] token on lanes, at 128-token blocks;
+        # any other shape takes the layer's K and V, padded to 128 lanes
+        if dh % 128 == 0 or block % 128 == 0:
             kc, vc, at = pool, None, {"layer": li}
         else:
             kc, vc, at = pool[li, 0], pool[li, 1], {}

@@ -427,12 +427,12 @@ class TestPackedExecutor:
                                    rtol=2e-5)
 
 
-def _whole_pool_case(dtype, L=3, seed=61):
-    """A ``[L, 2, N, Hkv, BLK, 128]`` pool (int8 codes with ``[L, 2, N,
+def _whole_pool_case(dtype, L=3, seed=61, D=128):
+    """A ``[L, 2, N, Hkv, BLK, D]`` pool (int8 codes with ``[L, 2, N,
     Hkv]`` scales, or bf16), queries, selections and a table in which
     logical block 1 is unmapped and slot 1's last block is the trash block
     N-1, as an inactive row's is."""
-    B, Hkv, G, Smax, D = 2, 2, 4, 384, 128
+    B, Hkv, G, Smax = 2, 2, 4, 384
     q, _, _, ids, pos = _rand_case(B, Hkv, G, Smax, D, jnp.bfloat16,
                                     seed=seed)
     T = Smax // BLK
@@ -484,9 +484,11 @@ class TestWholePoolOperand:
     @pytest.mark.parametrize("head_dim,whole", [(64, False), (128, True)])
     def test_decode_step_operand_follows_head_dim(self, monkeypatch,
                                                   head_dim, whole):
-        """``decode_step_paged`` hands the kernel the whole pool and the
-        layer index only where head_dim is whole 128-lane tiles; at 64 it
-        keeps the per-layer K and V operands."""
+        """At 8-token blocks ``decode_step_paged`` hands the kernel the
+        whole pool and the layer index only where head_dim is whole
+        128-lane tiles; at 64 it keeps the per-layer K and V operands
+        (at 128-token blocks head_dim 64 takes the whole pool too:
+        ``test_decode_step_transposed_tiles_match_per_layer``)."""
         from repro.models import transformer as tfm
 
         seen = []
@@ -511,6 +513,49 @@ class TestWholePoolOperand:
             packed_items=jnp.asarray(np.stack([items, items])))
         assert np.isfinite(np.asarray(logits)).all()
         assert seen == [(6, True, True) if whole else (4, False, False)]
+
+
+class TestTransposedTiles:
+    """At head_dim 64 and 128-token blocks the kernel reads layer ``l``'s
+    tiles from the whole pool as the TPU stores them, ``[Dh, block]``
+    (token on lanes): it computes bit for bit what the per-layer form
+    computes from the layer's K and V padded to 128 lanes (the same
+    tiles, the same f32 products in the same order), and the reference's
+    numbers to within ``RTOL`` / ``ATOL`` (the reference takes its QK dot
+    in bf16 operands, the kernel in f32)."""
+
+    RTOL = ATOL = 2e-5
+
+    @pytest.mark.parametrize("window", [None, 192])
+    @pytest.mark.parametrize("entry", ["paged", "packed"])
+    @pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "mid", "last"])
+    @pytest.mark.parametrize("dtype", ["bf16", "int8"])
+    def test_whole_pool_matches_per_layer_and_reference(self, dtype, layer,
+                                                        entry, window):
+        q, pool, scales, ids, tbl, pos = _whole_pool_case(dtype, D=64)
+        kw = dict(block_kv=BLK, partials=True, window=window)
+        if scales is not None:
+            kw.update(k_scales=scales[layer, 0], v_scales=scales[layer, 1])
+        if entry == "paged":
+            fn, sel = ops.flash_decode_paged, jnp.asarray(ids)
+        else:
+            # a 64-row bucket: the real runs, then valid=0 padding rows
+            fn = ops.flash_decode_packed_paged
+            wl = pack_decode_items(ids, block=BLK, bucket=64)
+            assert wl.total_real_items < wl.padded_length
+            sel = jnp.asarray(wl.flat())
+        args = (sel, jnp.asarray(tbl), jnp.asarray(pos))
+        k_l, v_l = pool[layer, 0], pool[layer, 1]
+        per_layer = fn(q, k_l, v_l, *args, use_kernel=True, interpret=True,
+                       **kw)
+        whole = fn(q, pool, None, *args, layer=jnp.int32(layer),
+                   use_kernel=True, interpret=True, **kw)
+        ref = fn(q, k_l, v_l, *args, use_kernel=False, **kw)
+        assert float(jnp.abs(whole[2]).max()) > 0.0
+        for a, b, r in zip(per_layer, whole, ref):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_allclose(np.asarray(b), np.asarray(r),
+                                       rtol=self.RTOL, atol=self.ATOL)
 
 
 class TestPackedGreedyParity:

@@ -37,6 +37,7 @@ from repro.core.worklist import (
     pack_decode_items_2d,
     pow2_bucket,
 )
+from repro.kernels import ops
 from repro.models import transformer as tfm
 
 DATA = Path(__file__).parent / "data" / "paged_step_bitwise.npz"
@@ -244,6 +245,95 @@ def test_paged_step_matches_recording_and_writes_only_its_positions(
         np.testing.assert_array_equal(scales[~smask], scales_in[~smask],
                                       err_msg=f"{case}: scales outside "
                                               "the writes")
+
+
+# SmolLM's attention shape (9 query heads over 3 KV heads of 64) at
+# 128-token blocks, where the step hands the kernel the whole pool and the
+# kernel reads its [Dh, block] tiles; a small model around it
+SMOL = tfm.TransformerConfig(num_layers=3, d_model=64, num_heads=9,
+                             num_kv_heads=3, head_dim=64, d_ff=128,
+                             vocab_size=128, block_q=128, block_kv=128,
+                             layer_loop="scan")
+SMOL_TABLE = np.array([[3, 0, -1],
+                       [5, 1, 2],
+                       [4, -1, -1]], np.int32)
+SMOL_POS = np.array([200, 300, 60], np.int32)
+SMOL_ACTIVE = np.array([True, True, False])
+PAGED_ENTRIES = {name: getattr(ops, name)
+                 for name in ("flash_decode_paged", "flash_decode_packed_paged")}
+
+
+def _route_to_kernel(monkeypatch, per_layer):
+    """Send the step's paged decode calls to the Pallas kernel in
+    interpret mode.  ``per_layer``: hand it layer ``l``'s K and V (the
+    padded per-layer form) where the step passes the whole pool.  Returns
+    the list of the forms the step asked for (True: whole pool)."""
+    asked = []
+    for name, real in PAGED_ENTRIES.items():
+        def call(q, k_pool, v_pool, *a, layer=None, _real=real, **k):
+            asked.append(layer is not None)
+            if per_layer and layer is not None:
+                k_pool, v_pool, layer = k_pool[layer, 0], k_pool[layer, 1], None
+            return _real(q, k_pool, v_pool, *a, layer=layer, use_kernel=True,
+                         interpret=True, **k)
+        monkeypatch.setattr(ops, name, call)
+    return asked
+
+
+@pytest.mark.parametrize("kvd", ["bf16", "int8"])
+@pytest.mark.parametrize("sel", ["packed", "padded"])
+def test_decode_step_transposed_tiles_match_per_layer(monkeypatch, sel, kvd):
+    """Three greedy decode steps at SmolLM's head_dim: the kernel reading
+    the whole pool's token-on-lanes tiles gives the same tokens, logits
+    and pool, bit for bit, as the per-layer padded form."""
+    rng = np.random.default_rng(19)
+    shapes = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0),
+                                                    SMOL))
+    params = jax.tree.map(lambda a: jnp.asarray(
+        0.1 * rng.standard_normal(a.shape), a.dtype), shapes)
+    L, hkv, n = SMOL.num_layers, SMOL.num_kv_heads, 6
+    shape = (L, 2, n, hkv, 128, SMOL.head_dim_)
+    qkw = {}
+    if kvd == "bf16":
+        pool = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    else:
+        pool = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        qkw = dict(scales=jnp.asarray(rng.uniform(0.01, 0.05, shape[:4]),
+                                      jnp.float32), kv_dtype=kvd)
+    # each layer's selection: block 0 and the newest block of each row
+    ids = np.zeros((L, 3, hkv, 2), np.int32)
+    ids[..., 1] = (SMOL_POS // 128)[None, :, None]
+    ids[..., 1] = np.where(ids[..., 1] == 0, -1, ids[..., 1])
+    if sel == "packed":
+        items = np.stack([pack_decode_items(ids[l], block=128, bucket=32)
+                          .flat() for l in range(L)])
+        kw = {"packed_items": jnp.asarray(items)}
+    else:
+        kw = {"block_ids": jnp.asarray(ids)}
+    token = jnp.asarray(rng.integers(0, SMOL.vocab_size, 3), jnp.int32)
+
+    def run(per_layer):
+        asked = _route_to_kernel(monkeypatch, per_layer)
+        c, q, t, pos, outs = pool, dict(qkw), token, jnp.asarray(SMOL_POS), []
+        for _ in range(3):
+            out = tfm.decode_step_paged(
+                params, c, t, pos, jnp.asarray(SMOL_TABLE), SMOL, **kw,
+                cache_len=pos + 1, active=jnp.asarray(SMOL_ACTIVE), **q)
+            logits, c = out[0], out[1]
+            if len(out) > 2:
+                q["scales"] = out[2]
+            t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            outs.append((np.asarray(logits), np.asarray(t)))
+            pos = pos + 1
+        return asked, outs, np.asarray(c)
+
+    asked, whole, pool_whole = run(per_layer=False)
+    assert asked and all(asked), "the step did not pass the whole pool"
+    _, per_layer, pool_per_layer = run(per_layer=True)
+    for (lw, tw), (lp, tp) in zip(whole, per_layer):
+        np.testing.assert_array_equal(_bits(lw), _bits(lp))
+        np.testing.assert_array_equal(tw, tp)
+    np.testing.assert_array_equal(_bits(pool_whole), _bits(pool_per_layer))
 
 
 if __name__ == "__main__":

@@ -70,19 +70,25 @@ def _shape(sharding, shape, dtype):
 
 
 def _compile_paged(sharding, *, num_blocks, heads, kv_heads, head_dim,
-                   block, kv_dtype, items=ITEMS):
+                   block, kv_dtype, items=ITEMS, layers=None):
+    """The paged kernel over one layer's K and V pools, or with ``layers``
+    over a whole ``[layers, 2, N, Hkv, block, Dh]`` pool at a traced
+    layer index."""
     pool_dtype = quant.kv_cache_dtype(kv_dtype, jnp.bfloat16)
     s = lambda shape, dt: _shape(sharding, shape, dt)
     pool = s((num_blocks, kv_heads, block, head_dim), pool_dtype)
-    kw = {}
+    pools, kw = (pool, pool), {}
+    if layers is not None:
+        pools = (s((layers, 2) + pool.shape, pool_dtype), None)
+        kw["layer"] = s((), jnp.int32)
     if quant.is_quantized(kv_dtype):
-        kw = dict(k_scales=s((num_blocks, kv_heads), jnp.float32),
+        kw.update(k_scales=s((num_blocks, kv_heads), jnp.float32),
                   v_scales=s((num_blocks, kv_heads), jnp.float32))
     fn = jax.jit(lambda *a, **k: flash_decode_paged_kernel(
         *a, block_kv=block, **k))
     return fn.lower(
         s((SLOTS, kv_heads, heads // kv_heads, head_dim), jnp.bfloat16),
-        pool, pool, s((items, DEC_FIELDS), jnp.int32),
+        *pools, s((items, DEC_FIELDS), jnp.int32),
         s((SLOTS, MAX_SEQ // block), jnp.int32), s((SLOTS,), jnp.int32),
         **kw).compile()
 
@@ -113,6 +119,22 @@ def test_paged_quantized_compiles(one_chip, kv_dtype, num_blocks):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
+def test_paged_whole_pool_transposed_tiles_compile(one_chip, kv_dtype):
+    """At SmolLM widths (head_dim 64, 128-token blocks) the kernel reads
+    the whole pool's ``[Dh, block]`` tiles: the TPU stores the pool token
+    on lanes, so the transposed view it takes is a bitcast, never a
+    relayout or a lane pad of the pool."""
+    c = _compile_paged(one_chip, num_blocks=1025, heads=9, kv_heads=3,
+                       head_dim=64, block=BLOCK, kv_dtype=kv_dtype,
+                       layers=4)
+    assert "tpu_custom_call" in c.as_text()
+    assert re.search(r"\[4,2,1025,3,64,128\]\S* bitcast\(", c.as_text())
+    assert not _pool_tile_ops(c, 1025, 3, "copy", "copy-start", "transpose",
+                              "pad", "dynamic-slice")
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_paged_long_item_table_compiles(one_chip):
     """A tick's item table of 8192 rows: 2D in SMEM it would take 4 MiB
     (rows padded to 128 words), flat it takes 192 KiB."""
@@ -135,8 +157,8 @@ def test_contiguous_int8_compiles(one_chip):
 
 
 # The steps at two widths, each with a 1024-block pool plus the trash
-# block: SmolLM-135M at 4 layers (head_dim 64, the kernel's padded path)
-# and Yi-6B at 2 layers (head_dim 128 unpadded, 32 query heads over 4 KV
+# block: SmolLM-135M at 4 layers (head_dim 64, the kernel's token-on-lanes
+# tiles) and Yi-6B at 2 layers (head_dim 128 unpadded, 32 query heads over 4 KV
 # heads, untied head), where XLA may lay the pool out either way round
 STEP_CFGS = {
     "smollm": tfm.TransformerConfig(num_layers=4, d_model=576, num_heads=9,
@@ -204,13 +226,24 @@ def _pool_copies(compiled, shape):
             if re.search(rf"= bf16\[{dims}\]\S* copy(-start)?\(", ln)]
 
 
+def _pool_tile_ops(compiled, num_blocks, kv_heads, *ops):
+    """HLO instructions of the kinds ``ops`` whose output is the pool, a
+    layer of it or its K or V (any dtype, either tile orientation, padded
+    or not): shapes ending in ``num_blocks, kv_heads, a, b``."""
+    kinds = "|".join(map(re.escape, ops))
+    pat = (rf"= \w+\[(\d+,)*{num_blocks},{kv_heads},\d+,\d+\]\S* "
+           rf"({kinds})\(")
+    return [ln.strip()[:200] for ln in compiled.as_text().splitlines()
+            if re.search(pat, ln)]
+
+
 @pytest.mark.parametrize("width", list(STEP_CFGS))
 def test_paged_decode_step_updates_pool_in_place(paged_step, width):
-    """The pool is the layer loop's carry: the step holds at most about
-    two layers of temporaries, not a second pool, and never copies the
-    pool whole.  Two layers is what head_dim 64 needs: its kernel still
-    takes layer ``l``'s K and V sliced out of the pool and padded to 128
-    lanes."""
+    """The pool is the layer loop's carry: the step holds well under two
+    layers of temporaries, not a second pool, and never copies the pool
+    whole.  At both widths the kernel reads layer ``l``'s tiles from the
+    carried pool itself (``test_paged_decode_step_reads_layer_in_place``
+    holds it to that)."""
     shape, layer_bytes, decode = paged_step(width, "decode")
     ma = decode.memory_analysis()
     assert ma.alias_size_in_bytes >= layer_bytes * shape[0]
@@ -220,15 +253,25 @@ def test_paged_decode_step_updates_pool_in_place(paged_step, width):
 
 
 def test_paged_decode_step_reads_layer_in_place(paged_step):
-    """At head_dim 128 the kernel copies its tiles from the carried pool
-    at the layer index: the step slices no layer's K or V out of the pool
-    and holds well under one layer of temporaries."""
-    shape, layer_bytes, decode = paged_step("yi6b", "decode")
-    temp = decode.memory_analysis().temp_size_in_bytes
-    assert temp < layer_bytes / 64, temp
-    layer_of_pool = rf"= bf16\[1,1,{shape[2]},[^\]]*\]\S* dynamic-slice\("
-    assert not [ln for ln in decode.as_text().splitlines()
-                if re.search(layer_of_pool, ln)]
+    """At both widths the kernel copies its tiles from the carried pool at
+    the layer index (``[block, Dh]`` rows at head_dim 128, the pool's own
+    token-on-lanes ``[Dh, block]`` tiles at 64): the step slices no
+    layer's K or V out of the pool, pads none to 128 lanes, re-lays out
+    neither pool nor layer, and holds well under one layer of
+    temporaries.  A tied head's embedding table is copied row-major for
+    the token gather in every step, whatever the pool, and is counted
+    apart."""
+    for width, cfg in STEP_CFGS.items():
+        shape, layer_bytes, decode = paged_step(width, "decode")
+        n, hkv = shape[2], shape[3]
+        assert not _pool_tile_ops(decode, n, hkv, "dynamic-slice"), width
+        assert not _pool_tile_ops(decode, n, hkv, "pad"), width
+        assert not _pool_tile_ops(decode, n, hkv, "copy", "copy-start",
+                                  "transpose"), width
+        head_copy = (cfg.vocab_size * -(-cfg.d_model // 128) * 128 * 2
+                     if cfg.tie_embeddings else 0)
+        temp = decode.memory_analysis().temp_size_in_bytes
+        assert temp < layer_bytes / 64 + head_copy, (width, temp)
 
 
 @pytest.mark.parametrize("width", list(STEP_CFGS))
